@@ -49,8 +49,11 @@ COPY models /models
 # keys include the target platform and the build host has no TPU, so this
 # bakes the cpu programs; TPU pods pre-fill their shared cache volume at
 # init instead (KDLT_AOT_WARM=1, model-server-deployment.yaml).  Fail-soft:
-# a warm failure costs cold-start time, never the image build.
-RUN kdlt-warm --models /models --compile-cache-dir /var/cache/kdlt-xla --platform cpu || \
+# a warm failure costs cold-start time, never the image build.  The ENV makes
+# the bake and every process the image later starts agree on one directory
+# (a JAX_COMPILATION_CACHE_DIR set at deploy time still wins over it).
+ENV KDLT_COMPILE_CACHE_DIR=/var/cache/kdlt-xla
+RUN kdlt-warm --models /models --platform cpu || \
     echo "kdlt-warm: bake failed; pods will compile at first warmup" >&2
 
 # 8500 = msgpack/JSON HTTP (probes, gateway); 8501 = the reference's

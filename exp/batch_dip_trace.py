@@ -1,6 +1,6 @@
 """Where does the batch-32/48 throughput dip come from?
 
-The round-4 sweep (BENCH.md) shows per-image device time of the FUSED
+The round-4 sweep showed per-image device time of the FUSED
 serving path is non-monotonic in batch: 0.205 ms/img at batch 16 but
 0.257 at 32 and 0.254 at 48, recovering to 0.226 at 64 and 0.215 at 128.
 This probe traces the fast forward at several batches and aggregates

@@ -141,7 +141,7 @@ def fused_block_v2(x, dw, pw, s, b, *, bt=4, interpret=False):
     except Exception:  # older API name
         from jax.experimental.pallas import tpu as pltpu
 
-        compiler_params = pltpu.TPUCompilerParams(
+        compiler_params = pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024
         )
 

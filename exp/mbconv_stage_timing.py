@@ -10,12 +10,12 @@ low-resolution stages cannot move the headline, and B3's 12% MFU is
 structural under this design.
 
 CAVEAT (recorded after the fact): burst timing on this box is floored at
-~2-5 ms/iteration for light programs (BENCH.md "Measurement floor"), so
+~2-5 ms/iteration for light programs (a measurement floor), so
 the SHORT prefixes here (stem, first stages) read the floor, not their
 true sub-millisecond device time, and the first segments absorb that
 offset.  The authoritative early-stage attribution for the fused-MBConv
-verdict is therefore the per-fusion device-trace table in BENCH.md
-(trace spans have no floor); this script remains useful for the LONG
+verdict is therefore a per-fusion device trace (exp/trace_profile.py;
+trace spans have no floor); this script remains useful for the LONG
 prefixes, where successive differences sit well above the floor.
 
 Usage (TPU): python exp/mbconv_stage_timing.py --batch 64

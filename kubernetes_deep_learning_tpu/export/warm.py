@@ -34,10 +34,6 @@ import time
 
 from kubernetes_deep_learning_tpu.utils import compilecache
 
-# The model-server image's cache mount (deploy/k8s +
-# deploy/model-server.dockerfile agree on this path).
-DEFAULT_CACHE_DIR = "/var/cache/kdlt-xla"
-
 
 def warm_decode(engine_factory=None) -> dict:
     """Warm the generative lane's decode ladder; returns its report dict.
@@ -93,9 +89,7 @@ def warm_models(
         iter_latest_versions,
     )
 
-    resolved = compilecache.enable_compile_cache(
-        cache_dir, default_dir=DEFAULT_CACHE_DIR
-    )
+    resolved = compilecache.enable_compile_cache(cache_dir)
     factory = engine_factory or _default_factory
     report: dict = {
         "cache_dir": resolved,
@@ -184,8 +178,9 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument(
         "--compile-cache-dir",
         default=None,
-        help="persistent compile cache directory (default "
-        f"$KDLT_COMPILE_CACHE_DIR or {DEFAULT_CACHE_DIR})",
+        help="persistent compile cache directory ($JAX_COMPILATION_CACHE_DIR "
+        "wins over this flag; default $KDLT_COMPILE_CACHE_DIR or "
+        f"{compilecache.DEFAULT_CACHE_DIR})",
     )
     p.add_argument(
         "--workers", type=int, default=4,
@@ -194,7 +189,7 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument(
         "--platform",
         default=None,
-        help="force a JAX platform (e.g. cpu) via JAX_PLATFORMS -- an "
+        help="force a JAX platform (e.g. cpu; default $KDLT_PLATFORM) -- an "
         "image BUILD host usually has no TPU; note cache keys include "
         "the target platform, so warming on cpu only serves cpu pods",
     )
@@ -209,8 +204,9 @@ def main(argv: list[str] | None = None) -> int:
         help="print the full warm report as JSON on stdout",
     )
     args = p.parse_args(argv)
-    if args.platform:
-        os.environ["JAX_PLATFORMS"] = args.platform
+    from kubernetes_deep_learning_tpu.utils.platform import force_platform
+
+    force_platform(args.platform)
     buckets = None
     if args.buckets:
         buckets = tuple(

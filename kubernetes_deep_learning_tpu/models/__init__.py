@@ -104,7 +104,7 @@ def build_forward(
 
     ``fast``: "auto" uses the fused-Pallas fast path (models.xception_fast)
     when the family has one and the default backend is TPU -- same variable
-    tree, bf16-noise-level logit difference, ~20% faster (BENCH.md).  True
+    tree, bf16-noise-level logit difference, ~20% faster in round 2.  True
     forces it (tests use interpret mode via the module directly); False
     keeps the flax graph (exact parity; the exporter uses this so artifacts
     stay portable across platforms).

@@ -5,7 +5,7 @@ Same design as models.xception_fast: a pure function over the SAME variable
 tree the flax module owns (init/import/export/training unchanged); only how
 serving COMPUTES the forward changes.  Round-3 context: B3 served at 12%
 MFU with the whole block graph on XLA fusions, the 6x-expanded activation
-round-tripping HBM between them (BENCH.md; VERDICT r3 #4).
+round-tripping HBM between them (VERDICT r3 #4).
 
 Layout strategy: the network alternates XLA segments (stem, expand-ratio-1
 stage 1, stride-2 stage openers) with runs of fusible stride-1 blocks.

@@ -5,7 +5,7 @@ A pure function over the SAME variable tree the flax module owns -- the
 module stays the single source of structure (init, .h5 import, export,
 training all unchanged); this path only changes how serving COMPUTES the
 forward.  Measured on a v5e chip at batch 256: 83 -> 69 ms per forward
-(+20% throughput, BENCH.md).  Entry/exit flows mirror flax.linen numerics
+(+20% throughput).  Entry/exit flows mirror flax.linen numerics
 op for op (bf16 compute, Keras BN epsilon); the middle flow runs the fused
 kernel in the (H, W, B, C) layout, paying one transpose in and one out.
 

@@ -114,6 +114,19 @@ CLOTHING_MODEL = register_spec(
     )
 )
 
+# The flagship at the CPU rehearsal size: ``chip_smoke.py --rehearse-on-cpu``
+# drives the same export -> model server -> gateway flow with this spec, so
+# the control flow is debugged on the CPU before chip time is spent.  Never
+# a benchmark cell: at 96x96 a measurement is a measurement of overheads.
+CLOTHING_MODEL_96 = register_spec(
+    dataclasses.replace(
+        CLOTHING_MODEL,
+        name="clothing-model-96",
+        input_shape=(96, 96, 3),
+        description="clothing classifier at 96x96 (CPU rehearsal of chip_smoke.py)",
+    )
+)
+
 _IMAGENET_LABELS = tuple(f"class_{i}" for i in range(1000))
 
 # BASELINE.json config 3: ResNet50/ImageNet served via the same gateway path.

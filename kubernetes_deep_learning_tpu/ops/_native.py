@@ -1,10 +1,14 @@
 """ctypes binding for the native host ops (native/hostops.cc).
 
-Importing this module either finds a prebuilt ``libkdlthostops.so`` (env
-``KDLT_NATIVE_LIB``, the package directory, or ``native/build/``) or compiles
-one with g++ into a per-user cache.  Any failure raises ImportError, which
-``ops.preprocess`` treats as "no native path" and falls back to PIL -- the
-package must keep working on machines without a toolchain.
+Importing this module loads ``libkdlthostops.so`` from, in order: the
+explicit ``KDLT_NATIVE_LIB`` (images that ship a prebuilt library); a build
+of the checkout's own ``native/*.cc`` with g++, cached per source content
+under the user cache dir; a library packaged next to this module (installed
+wheels with no source tree).  The source build comes before any prebuilt
+file so that what runs is what git tracks -- an untracked ``native/build/``
+left in a working tree is never picked up.  Any failure raises ImportError,
+which ``ops.preprocess`` treats as "no native path" and falls back to PIL --
+the package must keep working on machines without a toolchain.
 
 The resize kernels are bit-exact with PIL's (see hostops.cc), verified by
 tests/test_native.py, so the gateway can use whichever is available without
@@ -14,9 +18,9 @@ perturbing golden logits.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-import sysconfig
 
 import numpy as np
 
@@ -34,20 +38,26 @@ _SOURCES = ("hostops.cc", "batchqueue.cc")
 
 
 def _build(source_dir: str) -> str:
+    """Compile the sources into the user cache, keyed by their content (a
+    fresh checkout has fresh mtimes, so mtimes cannot say "unchanged")."""
+    srcs = [os.path.join(source_dir, s) for s in _SOURCES]
+    digest = hashlib.sha256()
+    for src in srcs:
+        with open(src, "rb") as f:
+            digest.update(f.read())
     cache = os.path.join(
         os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
         "kdlt",
     )
     os.makedirs(cache, exist_ok=True)
-    srcs = [os.path.join(source_dir, s) for s in _SOURCES]
-    out = os.path.join(cache, _LIB_NAME)
-    if os.path.isfile(out) and all(
-        os.path.getmtime(out) >= os.path.getmtime(s) for s in srcs
-    ):
+    out = os.path.join(cache, f"{digest.hexdigest()[:16]}-{_LIB_NAME}")
+    if os.path.isfile(out):
         return out
     cxx = os.environ.get("CXX", "g++")
-    cmd = [cxx, "-O3", "-std=c++17", "-fPIC", "-shared", "-o", out, *srcs, "-pthread"]
+    tmp = f"{out}.{os.getpid()}.tmp"  # concurrent first imports must not load a half-written file
+    cmd = [cxx, "-O3", "-std=c++17", "-fPIC", "-shared", "-o", tmp, *srcs, "-pthread"]
     subprocess.run(cmd, check=True, capture_output=True, text=True)
+    os.replace(tmp, out)
     return out
 
 
@@ -56,23 +66,15 @@ def _find_or_build() -> str:
     if explicit:
         return explicit
     native_dir = _repo_native_dir()
-    newest_src = max(
-        (os.path.getmtime(os.path.join(native_dir, s)) for s in _SOURCES
-         if os.path.isfile(os.path.join(native_dir, s))),
-        default=0.0,
-    ) if native_dir else 0.0
-    here = os.path.dirname(os.path.abspath(__file__))
-    for candidate in (
-        os.path.join(here, _LIB_NAME),
-        os.path.join(os.path.dirname(os.path.dirname(here)), "native", "build", _LIB_NAME),
-    ):
-        # A prebuilt older than the sources may lack newly added symbols
-        # (binding would fail below); prefer rebuilding when we can.
-        if os.path.isfile(candidate) and os.path.getmtime(candidate) >= newest_src:
-            return candidate
-    if native_dir is None:
-        raise ImportError("no prebuilt libkdlthostops.so and no source tree")
-    return _build(native_dir)
+    if native_dir is not None:
+        try:
+            return _build(native_dir)
+        except (OSError, subprocess.CalledProcessError):
+            pass  # no compiler here: a packaged library may still exist
+    packaged = os.path.join(os.path.dirname(os.path.abspath(__file__)), _LIB_NAME)
+    if os.path.isfile(packaged):
+        return packaged
+    raise ImportError("no compiler for native/*.cc and no packaged libkdlthostops.so")
 
 
 try:

@@ -18,8 +18,9 @@ Design (TPU-first):
   (acc, m, l) so callers (ring attention) can combine partial attentions
   over KV shards with the standard log-sum-exp merge; ``flash_attention``
   is the fused single-shot form.
-- ``interpret=True`` (auto on CPU) runs the same kernel through the Pallas
-  interpreter, so tests exercise the real kernel logic without a TPU.
+- ``interpret=True`` (passed explicitly by CPU tests, never inferred) runs
+  the same kernel through the Pallas interpreter, so tests exercise the
+  real kernel logic without a TPU.
 """
 
 from __future__ import annotations
@@ -29,6 +30,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 NEG_INF = -1e30  # large-but-finite: -inf breaks exp(m - m_new) when a row is fully masked
 
@@ -226,15 +228,6 @@ def _flash_kernel_partials(
     l_ref[0] = l
 
 
-try:  # pallas needs a recent jaxlib; keep the module importable without it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
 def flash_attention(
     q,
     k,
@@ -244,7 +237,7 @@ def flash_attention(
     k_offset: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
     return_partials: bool = False,
     kv_len: int | None = None,
 ):
@@ -267,11 +260,10 @@ def flash_attention(
     attentions over KV shards while keeping O(S*D) memory -- attend_block's
     einsum would materialize the (S_local, S_local) score matrix per shard.
 
-    ``interpret`` defaults to True off-TPU so the identical kernel logic is
-    testable on CPU.
+    ``interpret`` is never inferred from the device: serving passes nothing
+    and compiles through Mosaic; CPU tests pass ``interpret=True`` to run
+    the identical kernel logic in the Pallas interpreter.
     """
-    if not _HAVE_PALLAS:
-        raise NotImplementedError("pallas unavailable; use mha_reference")
     b, h, sq, d = q.shape
     sk = k.shape[2]
     if sq % block_q or sk % block_k:
@@ -279,23 +271,14 @@ def flash_attention(
             f"seq lengths ({sq}, {sk}) must be multiples of blocks "
             f"({block_q}, {block_k}); pad the sequence"
         )
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-
     qf = q.reshape(b * h, sq, d)
     kf = k.reshape(b * h, sk, d)
     vf = v.reshape(b * h, sk, d)
 
     # Inside shard_map, outputs must declare which mesh axes they vary over
     # (check_vma); propagate the query's vma so the kernel composes with
-    # parallel.ring.  Outside shard_map (or on a pre-vma JAX) this is the
-    # empty set / None.
-    from kubernetes_deep_learning_tpu.utils.jaxcompat import (
-        shape_dtype_struct,
-        typeof,
-    )
-
-    vma = getattr(typeof(qf), "vma", None)
+    # parallel.ring.  Outside shard_map this is the empty set.
+    vma = jax.typeof(qf).vma
 
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda g, i: (g, i, 0)),
@@ -323,9 +306,9 @@ def flash_attention(
                 row_spec,
             ],
             out_shape=[
-                shape_dtype_struct((b * h, sq, d), jnp.float32, vma=vma),
-                shape_dtype_struct((b * h, sq, 1), jnp.float32, vma=vma),
-                shape_dtype_struct((b * h, sq, 1), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((b * h, sq, d), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32, vma=vma),
             ],
             interpret=interpret,
         )(qf, kf, vf)
@@ -344,14 +327,14 @@ def flash_attention(
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda g, i: (g, i, 0)),
-        out_shape=shape_dtype_struct((b * h, sq, d), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype, vma=vma),
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)
 
 
 def flash_attention_padded(q, k, v, *, causal: bool = False,
-                           interpret: bool | None = None):
+                           interpret: bool = False):
     """Flash attention for ANY sequence length: pads S up to the nearest
     block multiple, masks the pad keys via ``kv_len``, slices the output.
 
@@ -417,11 +400,9 @@ def attention_serving(q, k, v, *, causal: bool = False):
     platform-portable as-is.
     """
     sq, sk = q.shape[2], k.shape[2]
-    if use_einsum_attention(sq, sk) or not _HAVE_PALLAS:
+    if use_einsum_attention(sq, sk):
         return mha_reference(q, k, v, causal=causal)
-    from kubernetes_deep_learning_tpu.utils.jaxcompat import platform_dependent
-
-    return platform_dependent(
+    return jax.lax.platform_dependent(
         q, k, v,
         tpu=functools.partial(
             flash_attention_padded, causal=causal, interpret=False
@@ -465,11 +446,9 @@ def _forward_with_lse(q, k, v, causal: bool):
     def via_reference(q, k, v):
         return _finalize_with_lse(attend_block(q, k, v, causal=causal), q.dtype)
 
-    if block_q is None or block_k is None or not _HAVE_PALLAS:
+    if block_q is None or block_k is None:
         return via_reference(q, k, v)
-    from kubernetes_deep_learning_tpu.utils.jaxcompat import platform_dependent
-
-    return platform_dependent(
+    return jax.lax.platform_dependent(
         q, k, v, tpu=via_flash, default=via_reference
     )
 

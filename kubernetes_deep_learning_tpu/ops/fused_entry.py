@@ -1,6 +1,6 @@
 """Fused Xception entry segment: conv2 + block2 in one Pallas kernel.
 
-The entry flow is the fast path's remaining bottleneck (BENCH.md round 2:
+The entry flow is the fast path's remaining bottleneck (round-2 trace:
 ~30 ms of the batch-256 forward; 4.4 ms of the 16.6 ms batch-64 forward,
 running at only 10-28% MFU in XLA's fusions).  This kernel fuses the
 segment the trace attributes most of that to:
@@ -50,10 +50,9 @@ from kubernetes_deep_learning_tpu.ops.fused_sepconv import _legal_bt
 def _entry_compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
     # The physical cap is 128 MiB on v5e; rt=13/bt=8 at the Xception shape
     # peaks just under 110 MiB.
-    return params_cls(vmem_limit_bytes=110 * 1024 * 1024)
+    return pltpu.CompilerParams(vmem_limit_bytes=110 * 1024 * 1024)
 
 
 def entry_block_reference(a, w):

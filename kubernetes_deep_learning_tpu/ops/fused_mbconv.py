@@ -2,7 +2,7 @@
 stride-1 block (expand 1x1 -> depthwise kxk -> squeeze-excite -> project
 1x1 -> +residual).
 
-Why: EfficientNet-B3 served at 12% MFU (BENCH.md round 3) -- the MBConv
+Why: EfficientNet-B3 served at 12% MFU (round-3 sweep) -- the MBConv
 block is the Xception sepconv pattern (ops.fused_sepconv) plus an expand
 GEMM, an SE gate, and silu epilogues, and XLA runs it as 4+ fusions with
 the 6x-expanded activation round-tripping HBM between them.  This kernel
@@ -162,12 +162,10 @@ def pick_mbconv_bt(h: int, w: int, batch: int, c_mid: int) -> int:
 def _compiler_params(limit_bytes: int):
     from jax.experimental.pallas import tpu as pltpu
 
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    # Same 96 MiB default as fused_sepconv (since round 4, via
-    # VMEM_LIMIT_BYTES): the largest fused B3 tile under the default
-    # budget peaks well under 64 MiB, and the recurring TPU worker fault
-    # made VMEM headroom cheap insurance.
-    return params_cls(vmem_limit_bytes=limit_bytes)
+    # Same 96 MiB default as fused_sepconv (via VMEM_LIMIT_BYTES): the
+    # largest fused B3 tile under the default budget peaks well under
+    # 64 MiB, so the limit leaves headroom below the 128 MiB physical cap.
+    return pltpu.CompilerParams(vmem_limit_bytes=limit_bytes)
 
 
 def fused_mbconv_block_t(xt, w, *, bt: int = 0, residual: bool = True,

@@ -2,7 +2,7 @@
 middle block.
 
 What the XLA graph does for one middle block is 3 sepconv fusions, each a
-round trip through HBM (trace evidence in BENCH.md): relu -> depthwise 3x3
+round trip through HBM (seen in the device trace): relu -> depthwise 3x3
 -> pointwise GEMM -> BN affine, x3, + residual.  This kernel keeps the whole
 (H, W) extent of a tile of images resident in VMEM across all three
 sepconvs, eliminating the intermediate HBM traffic, and arranges the data
@@ -213,14 +213,10 @@ def _compiler_params(limit_bytes: int = 96 * 1024 * 1024) -> Any:
 
     # The default 16 MiB scoped-vmem cap rejects the bt=16 tile; v5e has
     # 128 MiB physical VMEM.  Default 96 MiB: the serving path's largest
-    # tile needs far less, the measured speed at 96 vs 110 MiB is
-    # identical (exp/worker_fault_probe.py scan-long-96m), and round 3-4's
-    # recurring TPU worker faults make VMEM headroom cheap insurance.
+    # tile needs far less, and it leaves headroom below the physical cap.
     # Only the experimental entry path's block3 chain (74x74, 128->256
     # channels, peaks ~107 MiB at bt=8) requests 110 explicitly.
-    # (CompilerParams was TPUCompilerParams in older jax releases.)
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return params_cls(vmem_limit_bytes=limit_bytes)
+    return pltpu.CompilerParams(vmem_limit_bytes=limit_bytes)
 
 
 def fused_sepconv_block(x, dw, pw, scale, shift, *, bt: int = 0, interpret: bool = False):

@@ -24,10 +24,14 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-try:  # optional C++ fast path (native/preprocess.cc)
+try:  # optional C++ fast path (native/hostops.cc)
     from kubernetes_deep_learning_tpu.ops import _native
-except Exception:  # pragma: no cover - native lib not built
+except ImportError:  # no toolchain and no KDLT_NATIVE_LIB: PIL resize
     _native = None
+
+# Which resize implementation this process runs (bit-exact with each
+# other, tests/test_native.py); surfaced on the model server's status page.
+RESIZE_IMPL = "native" if _native is not None else "pil"
 
 # Normalization constants, index-aligned with `modelspec.ModelSpec.preprocessing`.
 #   tf    : x / 127.5 - 1            (Keras "tf" mode; Xception, reference

@@ -103,14 +103,12 @@ def build_sharded_jit(
     host side stays fully asynchronous -- only device EXECUTION serializes,
     and the device runs one program at a time anyway.
     """
-    from kubernetes_deep_learning_tpu.utils.jaxcompat import shard_map
-
     out_spec = P() if replicate_out else P(DATA_AXIS)
     if fast:
         inner = build_forward(spec, dtype=dtype, fast=True)
         # check_vma=False: pallas_call out_shapes do not declare varying
         # mesh axes, and the data flow here is trivially per-shard.
-        forward = shard_map(
+        forward = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(), P(DATA_AXIS)),  # params replicated; batch sharded
@@ -162,15 +160,13 @@ def build_mesh_serving_jit(
     build_sharded_jit does.  ``donate=True`` donates the batch argument
     (argnum 1), composing PR 9's buffer donation with the GSPMD layout.
     """
-    from kubernetes_deep_learning_tpu.utils.jaxcompat import shard_map
-
     inner = forward
     if inner is None:
         inner = build_forward(spec, dtype=dtype, fast=fast)
     if fast:
         # check_vma=False: pallas_call out_shapes do not declare varying
         # mesh axes, and the data flow here is trivially per-shard.
-        inner = shard_map(
+        inner = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(), P(DATA_AXIS)),

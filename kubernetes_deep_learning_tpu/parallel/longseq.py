@@ -30,7 +30,6 @@ import numpy as np
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubernetes_deep_learning_tpu.utils.jaxcompat import shard_map
 
 from kubernetes_deep_learning_tpu.models.vit import VIT_CONFIGS, ViTConfig
 from kubernetes_deep_learning_tpu.modelspec import ModelSpec
@@ -50,7 +49,8 @@ def _layer_norm(x, scale, bias):
 
 
 def _block_shard(
-    x, params, *, cfg: ViTConfig, axis_name: str, n: int, dtype, use_flash
+    x, params, *, cfg: ViTConfig, axis_name: str, n: int, dtype, use_flash,
+    interpret: bool = False,
 ):
     """One transformer block on a (B, S_local, C) token shard.
 
@@ -67,7 +67,8 @@ def _block_shard(
     )
     q, k, v = proj("query"), proj("key"), proj("value")
     o = _ring_shard(
-        q, k, v, axis_name=axis_name, n=n, causal=False, use_flash=use_flash
+        q, k, v, axis_name=axis_name, n=n, causal=False, use_flash=use_flash,
+        interpret=interpret,
     )
     o = jnp.einsum(
         "bhsd,hdc->bsc", o.astype(dtype), params["attn"]["out"]["kernel"].astype(dtype)
@@ -83,13 +84,14 @@ def _block_shard(
 
 
 def _stack_shard(
-    x, params, *, cfg: ViTConfig, axis_name: str, n: int, dtype, seq: int, use_flash
+    x, params, *, cfg: ViTConfig, axis_name: str, n: int, dtype, seq: int,
+    use_flash, interpret: bool = False,
 ):
     """All blocks + final LN + the LOCAL half of the mean pool."""
     for i in range(cfg.depth):
         x = _block_shard(
             x, params[f"block_{i}"], cfg=cfg, axis_name=axis_name, n=n,
-            dtype=dtype, use_flash=use_flash,
+            dtype=dtype, use_flash=use_flash, interpret=interpret,
         )
     x = _layer_norm(x, params["ln_final"]["scale"], params["ln_final"]["bias"])
     pooled = x.sum(axis=1) / seq            # local partial of the token mean
@@ -103,6 +105,7 @@ def build_sequence_parallel_forward(
     dtype=jnp.bfloat16,
     axis_name: str = DATA_AXIS,
     differentiable: bool = False,
+    interpret: bool = False,
 ):
     """Jitted ``f(variables, uint8_images) -> f32 logits`` with the token
     sequence sharded over ``axis_name``.  ViT families only; the patch-grid
@@ -112,7 +115,8 @@ def build_sequence_parallel_forward(
     kernel has no VJP), making the whole forward grad-able through
     shard_map/ppermute -- context-parallel FINE-TUNING: per-device
     activations stay O(S/n), gradients ride the same ring.  Serving keeps
-    the default (flash attend where it tiles)."""
+    the default (flash attend where it tiles, compiled through Mosaic);
+    ``interpret=True`` is for CPU tests only (see parallel.ring)."""
     cfg = VIT_CONFIGS.get(spec.family)
     if cfg is None:
         raise ValueError(
@@ -133,16 +137,17 @@ def build_sequence_parallel_forward(
         raise ValueError(f"token count {seq} not divisible by mesh axis {n}")
 
     token_sharding = NamedSharding(mesh, P(None, axis_name, None))
-    stack = shard_map(
+    stack = jax.shard_map(
         functools.partial(
             _stack_shard, cfg=cfg, axis_name=axis_name, n=n, dtype=dtype,
             seq=seq, use_flash=False if differentiable else None,
+            interpret=interpret,
         ),
         mesh=mesh,
         in_specs=(P(None, axis_name, None), P()),
         out_specs=P(),
-        # Same jax-0.9 pallas-interpreter vma caveat as parallel.ring.
-        check_vma=all(d.platform == "tpu" for d in mesh.devices.flat),
+        # Same pallas-interpreter vma caveat as parallel.ring.
+        check_vma=not interpret,
     )
 
     def forward(variables, images):

@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubernetes_deep_learning_tpu.utils.jaxcompat import shard_map
-
 from kubernetes_deep_learning_tpu.ops.attention import (
     NEG_INF,
     attend_block,
@@ -55,11 +53,12 @@ def build_ring_attention(
     causal: bool = False,
     axis_name: str = DATA_AXIS,
     use_flash: bool | None = None,
+    interpret: bool = False,
 ):
     """Build the jitted ring-attention fn for a mesh (compile-once factory).
 
-    Cached per (mesh, causal, axis_name, use_flash) so repeated calls reuse
-    one jit cache (same convention as parallel.dataparallel.
+    Cached per (mesh, causal, axis_name, use_flash, interpret) so repeated
+    calls reuse one jit cache (same convention as parallel.dataparallel.
     build_sharded_forward).
 
     ``use_flash`` selects the per-shard attend: the fused Pallas kernel in
@@ -67,23 +66,28 @@ def build_ring_attention(
     contexts) vs the reference einsum path (materializes the
     (S_local, S_local) score matrix; fine for short shards, used as the
     fallback when S_local does not tile).  None = auto by shape.
+
+    ``interpret`` runs the flash attend in the Pallas interpreter (CPU
+    tests pass True); it is never inferred from the mesh's devices.
     """
     n = mesh.shape[axis_name]
     seq_spec = P(None, None, axis_name, None)
-    inner = shard_map(
+    inner = jax.shard_map(
         functools.partial(
-            _ring_shard, axis_name=axis_name, n=n, causal=causal, use_flash=use_flash
+            _ring_shard, axis_name=axis_name, n=n, causal=causal,
+            use_flash=use_flash, interpret=interpret,
         ),
         mesh=mesh,
         in_specs=(seq_spec,) * 3,
         out_specs=seq_spec,
-        # jax 0.9's pallas interpreter (CPU tests) loses vma tracking on its
-        # internal dynamic_slice when a pallas_call sits under shard_map; jax
-        # itself prescribes check_vma=False as the workaround.  Keep the
-        # trace-time vma validation on the real-TPU path (non-interpret);
-        # off-TPU, sharding correctness is still covered by test_ring_output_
-        # keeps_sequence_sharding and the vs-reference exactness tests.
-        check_vma=all(d.platform == "tpu" for d in mesh.devices.flat),
+        # The pallas interpreter loses vma tracking on its internal
+        # dynamic_slice when a pallas_call sits under shard_map; jax itself
+        # prescribes check_vma=False as the workaround.  The compiled
+        # (non-interpret) path keeps the trace-time vma validation; in
+        # interpret mode sharding correctness is still covered by
+        # test_ring_output_keeps_sequence_sharding and the vs-reference
+        # exactness tests.
+        check_vma=not interpret,
     )
     return jax.jit(inner)
 
@@ -97,6 +101,7 @@ def ring_attention(
     causal: bool = False,
     axis_name: str = DATA_AXIS,
     use_flash: bool | None = None,
+    interpret: bool = False,
 ):
     """Exact attention with S sharded over ``axis_name``.  (B,H,S,D) in/out.
 
@@ -109,12 +114,14 @@ def ring_attention(
     seq_sharding = NamedSharding(mesh, P(None, None, axis_name, None))
     q, k, v = (jax.device_put(x, seq_sharding) for x in (q, k, v))
     return build_ring_attention(
-        mesh, causal=causal, axis_name=axis_name, use_flash=use_flash
+        mesh, causal=causal, axis_name=axis_name, use_flash=use_flash,
+        interpret=interpret,
     )(q, k, v)
 
 
 def _ring_shard(
-    q_blk, k_blk, v_blk, *, axis_name: str, n: int, causal: bool, use_flash: bool | None
+    q_blk, k_blk, v_blk, *, axis_name: str, n: int, causal: bool,
+    use_flash: bool | None, interpret: bool = False,
 ):
     """Per-device body: local q vs rotating KV shards, merged partials.
 
@@ -124,7 +131,7 @@ def _ring_shard(
     """
     out, _ = _ring_shard_with_lse(
         q_blk, k_blk, v_blk, axis_name=axis_name, n=n, causal=causal,
-        use_flash=use_flash,
+        use_flash=use_flash, interpret=interpret,
     )
     return out
 
@@ -177,9 +184,10 @@ def _pair_grads(q32, k_j, v_j, lse, delta, do32, *, causal: bool, scale: float):
         )
         return dq_acc + dq_b, (dk_b, dv_b)
 
-    dq, (dks, dvs) = jax.lax.scan(
-        body, jnp.zeros(q32.shape, jnp.float32), jnp.arange(nk)
-    )
+    # zeros_like, not zeros(shape): under shard_map's check_vma the carry
+    # must vary over the same mesh axes as the dq the body adds to it (a
+    # bare zeros is unvarying and the scan's carry types would not match).
+    dq, (dks, dvs) = jax.lax.scan(body, jnp.zeros_like(q32), jnp.arange(nk))
     b, h = q32.shape[:2]
     dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, sk, -1)
     dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, sk, -1)
@@ -187,7 +195,7 @@ def _pair_grads(q32, k_j, v_j, lse, delta, do32, *, causal: bool, scale: float):
 
 
 def _ring_shard_with_lse(
-    q_blk, k_blk, v_blk, *, axis_name, n, causal, use_flash
+    q_blk, k_blk, v_blk, *, axis_name, n, causal, use_flash, interpret=False
 ):
     """The ring schedule, returning (out, lse).
 
@@ -212,6 +220,7 @@ def _ring_shard_with_lse(
             return flash_attention(
                 q_blk, kv_pair[0], kv_pair[1], causal=causal, k_offset=k_offset,
                 block_q=block, block_k=block, return_partials=True,
+                interpret=interpret,
             )
         return attend_block(
             q_blk, kv_pair[0], kv_pair[1], causal=causal, k_offset=k_offset
@@ -316,6 +325,7 @@ def build_ring_attention_trainable(
     causal: bool = False,
     axis_name: str = DATA_AXIS,
     use_flash: bool | None = None,
+    interpret: bool = False,
 ):
     """Differentiable ring attention over ``mesh`` (compile-once factory).
 
@@ -326,19 +336,19 @@ def build_ring_attention_trainable(
     """
     n = mesh.shape[axis_name]
     seq_spec = P(None, None, axis_name, None)
-    check = all(d.platform == "tpu" for d in mesh.devices.flat)
+    check = not interpret  # same interpreter vma caveat as build_ring_attention
 
-    fwd_inner = shard_map(
+    fwd_inner = jax.shard_map(
         functools.partial(
             _ring_shard_with_lse, axis_name=axis_name, n=n, causal=causal,
-            use_flash=use_flash,
+            use_flash=use_flash, interpret=interpret,
         ),
         mesh=mesh,
         in_specs=(seq_spec,) * 3,
         out_specs=(seq_spec, P(None, None, axis_name)),
         check_vma=check,
     )
-    bwd_inner = shard_map(
+    bwd_inner = jax.shard_map(
         functools.partial(_ring_bwd_shard, axis_name=axis_name, n=n, causal=causal),
         mesh=mesh,
         in_specs=(seq_spec,) * 4 + (P(None, None, axis_name), seq_spec),

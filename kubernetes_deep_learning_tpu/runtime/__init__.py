@@ -25,8 +25,8 @@ def create_batcher(engine, impl: str = "auto", dispatcher=None, **kwargs):
     batcher (the native queue pipelines in its own dispatch loop instead,
     so the kwarg is dropped for it).
 
-    The core check is measured, not theoretical (bench.py --batcher-sweep,
-    BENCH.md round 3): the native batcher's multi-in-flight pipeline
+    The core check is measured, not theoretical (bench.py
+    --batcher-sweep): the native batcher's multi-in-flight pipeline
     spreads dispatch across threads (dispatcher, device sync, C++
     completion), and on a single-core host the GIL convoys those handoffs
     -- the Python batcher's one-thread dispatch loop beats it at every

@@ -940,17 +940,17 @@ class InferenceEngine:
         (round-2's failure mode: the default TPU config could not boot).
         """
         if self.mesh is not None:
-            from kubernetes_deep_learning_tpu.parallel import mesh as mesh_par
-
-            if int(dict(self.mesh.shape).get(mesh_par.MODEL_AXIS, 1)) > 1:
-                # Model-axis programs carry cross-device collectives, and
-                # warm_one EXECUTES each bucket program: two executions
-                # racing from different threads can enqueue in different
-                # per-device orders and deadlock the collective rendezvous
-                # (observed wedging the host-platform CPU backend; the
-                # same interleaving hazard exists on any backend).  Serial
-                # warmup costs boot time only, never serving latency.
-                workers = 1
+            # Every mesh program carries a cross-device collective (tensor-
+            # parallel reductions on a model axis; on a data-only mesh the
+            # all-gather that replicates the logits), and warm_one EXECUTES
+            # each bucket program: two executions racing from different
+            # threads can enqueue in different per-device orders and
+            # deadlock the collective rendezvous (observed wedging the
+            # host-platform CPU backend with a model axis; jax documents
+            # the same hazard for any multi-device program launched from
+            # several threads).  Serial warmup costs boot time only, never
+            # serving latency -- serving dispatch is already serialized.
+            workers = 1
         t0 = time.perf_counter()
         while True:
             failure = self._warm_buckets(max(1, workers))
@@ -1283,7 +1283,52 @@ class InferenceEngine:
             info["param_bytes_per_device"] = mesh_par.param_bytes_per_device(
                 self._variables
             )
+            if self.mesh_mode == "data":
+                info["batch_rows_per_device"] = self._batch_rows_per_device()
         return info
+
+    _batch_rows: dict[str, int] | None = None
+
+    def _batch_rows_per_device(self) -> dict[str, int]:
+        """Rows of a max-bucket batch each device really holds, observed
+        from ``addressable_shards`` of a batch placed with the sharding the
+        serving jit declares for its input (parallel.mesh.batch_sharding):
+        the proof that a batch is split over the chips rather than sitting
+        whole on the first.  Placed once, then cached."""
+        if self._batch_rows is None:
+            import jax
+
+            from kubernetes_deep_learning_tpu.parallel import mesh as mesh_par
+
+            placed = jax.device_put(
+                np.zeros((self.max_batch, *self.spec.input_shape), np.uint8),
+                mesh_par.batch_sharding(self.mesh),
+            )
+            self._batch_rows = {
+                str(shard.device.id): int(shard.data.shape[0])
+                for shard in placed.addressable_shards
+            }
+        return self._batch_rows
+
+    def device_info(self) -> dict[str, Any]:
+        """The status surface that keeps a green boot honest (GET
+        /v1/models): the device as JAX reports it, the peak the MFU gauges
+        divide by (None = a device_kind the table does not know), whether
+        the fused path is in the served programs or was degraded away, and
+        each bucket's warm-up seconds."""
+        import jax
+
+        return {
+            "platform": self._device.platform,
+            "device_kind": self._device.device_kind,
+            "device_count": len(jax.local_devices()),
+            "peak_tflops": flops_lib.peak_tflops(
+                self._device, str(self._compute_dtype)
+            ),
+            "fast_engaged": bool(self._fast_engaged),
+            "fast_degraded": bool(self.fast_degraded),
+            "warm": dict(self.warm_report),
+        }
 
     def bucket_audit(self) -> dict[str, Any]:
         """Per-bucket padding-waste + FLOPs audit (/debug/profile?audit=

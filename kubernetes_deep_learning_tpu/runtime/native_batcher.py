@@ -113,8 +113,7 @@ class NativeBatcher:
         # assembles (into a free buffer), and DISPATCHES the next batch,
         # then syncs the OLDEST in-flight batch only when the depth limit is
         # reached (backpressure).  The device never idles between batches on
-        # dispatch/assembly time (on tunnel-attached dev chips that hides an
-        # entire round trip); completions stay FIFO in dispatch order.
+        # dispatch/assembly time; completions stay FIFO in dispatch order.
         use_async = hasattr(self._engine, "predict_async")
         pending: deque = deque()  # (tickets_copy, n, device_logits, dispatched_at)
         slot = 0

@@ -130,6 +130,11 @@ class ServedModel:
         # so device time is arbitrated ACROSS models (weight = this model's
         # share in that arbitration).
         engine_factory = engine_factory or InferenceEngine
+        from kubernetes_deep_learning_tpu.ops import preprocess as preprocess_lib
+
+        # Which resize kernel the decode stage runs (native C++ or PIL) is
+        # settled at import by the toolchain; the status page names it.
+        self.host_resize = preprocess_lib.RESIZE_IMPL
         self.artifact = artifact
         self.name = artifact.spec.name
         self.version = int(artifact.path.rstrip("/").rsplit("/", 1)[-1])
@@ -177,6 +182,7 @@ class ServedModel:
                 self._scheduler = scheduler
                 self.dispatcher = None
                 self.batcher = None
+                self.batcher_kind = "scheduler"
             else:
                 # Legacy per-model pipeline: ONE in-flight dispatch pipeline
                 # per model version, shared by the single-image batcher and
@@ -204,6 +210,11 @@ class ServedModel:
                     )
                     if use_batcher
                     else None
+                )
+                # create_batcher picks native vs python from the toolchain
+                # and core count; the status page says which one it was.
+                self.batcher_kind = (
+                    type(self.batcher).__name__ if self.batcher else "none"
                 )
         except BaseException:
             # with_labels already hooked the child into the shared registry;
@@ -380,14 +391,6 @@ class ModelServer:
         # status, duration) -- the model-tier half of the gateway's
         # X-Request-Id propagation.  Errors are always logged with the rid.
         self.request_log = request_log
-        # Env-gated persistent XLA compile cache (no-op unless
-        # $KDLT_COMPILE_CACHE_DIR / $JAX_COMPILATION_CACHE_DIR is set):
-        # covers library construction; the CLI also wires --compile-cache-dir.
-        from kubernetes_deep_learning_tpu.utils.compilecache import (
-            enable_compile_cache,
-        )
-
-        enable_compile_cache()
         # profile_base: directory for /debug/profile traces; "" means
         # $KDLT_PROFILE_DIR (or a default under the system temp dir), None
         # disables the endpoint.
@@ -416,6 +419,12 @@ class ModelServer:
         self._faults = faults_lib.from_env()
         if self._faults is not None:
             self._faults.attach(self.registry)
+        # XLA compile accounting (kdlt_xla_compile_*): installed before
+        # the first model loads so the boot's own compiles are counted;
+        # after /readyz the request counter must stay flat.
+        from kubernetes_deep_learning_tpu.utils.compilecache import CompileWatch
+
+        self._compile_watch = CompileWatch(self.registry)
         self._m_requests = self.registry.counter(
             "kdlt_server_requests_total", "predict requests"
         )
@@ -538,6 +547,7 @@ class ModelServer:
             )
         self._watcher: threading.Thread | None = None
         self._watcher_stop = threading.Event()
+        self._shutdown_done = threading.Event()
         self._profile_lock = threading.Lock()
         self.poll_versions()
         if not self.models:
@@ -549,6 +559,12 @@ class ModelServer:
 
     def warmup(self) -> None:
         for m in self.models.values():
+            if m.engine.ready:
+                # Loaded through the registry: warmed before activation
+                # (_load_model).  A second pass would find every program
+                # compiled and overwrite each bucket's warm-up seconds --
+                # the boot's compile record -- with a no-op's.
+                continue
             dt = m.engine.warmup()
             print(f"warmed {m.artifact.spec.name}: {dt:.1f}s", file=sys.stderr)
         if self.generate is not None:
@@ -563,6 +579,14 @@ class ModelServer:
     @property
     def ready(self) -> bool:
         return all(m.engine.ready for m in self.models.values())
+
+    @property
+    def fast_degraded(self) -> bool:
+        """True when any served engine fell off its fused path at warmup."""
+        return any(
+            getattr(m.engine, "fast_degraded", False)
+            for m in self.models.values()
+        )
 
     @property
     def models(self) -> dict[str, ServedModel]:
@@ -630,7 +654,8 @@ class ModelServer:
             scheduler=self.scheduler,
         )
         try:
-            fresh.engine.warmup()
+            warm_s = fresh.engine.warmup()
+            print(f"warmed {name} v{version}: {warm_s:.1f}s", file=sys.stderr)
         except Exception:
             # Warmup failed post-construction: the registry skips this
             # version (and retries next poll); the orphaned child registry
@@ -899,7 +924,11 @@ class ModelServer:
                         # this pod faster than the liveness restart lands.
                         return self._send(503, b"dispatch stalled", "text/plain")
                     if server.ready:
-                        return self._send(200, b"ready", "text/plain")
+                        # A replica whose fused path failed to compile still
+                        # serves (on the exact graph), but never as a plain
+                        # "ready": the body names the degrade.
+                        body = b"ready fast_degraded" if server.fast_degraded else b"ready"
+                        return self._send(200, body, "text/plain")
                     return self._send(503, b"warming up", "text/plain")
                 if self.path == "/metrics":
                     # Pull-model freshness: the SLO window gauges are
@@ -1592,22 +1621,37 @@ class ModelServer:
             self._profile_lock.release()
 
     def shutdown(self) -> None:
-        self._watcher_stop.set()
-        if self.generate is not None:
-            self.generate.close()
-        self.recorder.close()
-        if self._watcher is not None:
-            self._watcher.join(timeout=5)
-        # BaseServer.shutdown() blocks on serve_forever's exit event; only
-        # call it if serve_forever actually ran (a constructed-but-never-
-        # started server is a legitimate lifecycle, e.g. load-only tooling).
-        if getattr(self, "_serving", False):
-            self._httpd.shutdown()
-        self._httpd.server_close()
-        for m in self.models.values():
-            m.close(drain=False)
-        if self.scheduler is not None:
-            self.scheduler.close(drain=False)
+        try:
+            self._watcher_stop.set()
+            self._compile_watch.close()
+            if self.generate is not None:
+                self.generate.close()
+            self.recorder.close()
+            if self._watcher is not None:
+                self._watcher.join(timeout=5)
+            # BaseServer.shutdown() blocks on serve_forever's exit event;
+            # only call it if serve_forever actually ran (a constructed-but-
+            # never-started server is a legitimate lifecycle, e.g. load-only
+            # tooling).
+            if getattr(self, "_serving", False):
+                self._httpd.shutdown()
+            self._httpd.server_close()
+            for m in self.models.values():
+                m.close(drain=False)
+            if self.scheduler is not None:
+                self.scheduler.close(drain=False)
+        finally:
+            self._shutdown_done.set()
+
+    def wait_shutdown(self, timeout: float = 60.0) -> bool:
+        """Block until a shutdown() running on another thread has finished.
+
+        ``start(block=True)`` returns the moment shutdown() stops the
+        listener, while the thread that called it (the SIGTERM drain
+        thread) is still closing dispatchers and engines.  A main thread
+        that returned right away would finalize the interpreter under it;
+        on the TPU that killed the process with SIGABRT mid-teardown."""
+        return self._shutdown_done.wait(timeout)
 
 
 def _serve_cross_host(args) -> int:
@@ -1805,7 +1849,9 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument(
         "--platform",
         default=None,
-        help="jax platform override (e.g. cpu for dev); default $KDLT_PLATFORM",
+        help="the jax platform this server must run on (tpu, or cpu for "
+        "dev); default $KDLT_PLATFORM, else whatever JAX finds.  When "
+        "given, finding any other platform is fatal at start-up",
     )
     p.add_argument(
         "--no-request-log",
@@ -1864,11 +1910,11 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument(
         "--compile-cache-dir",
         default="",
-        help="persistent XLA compilation-cache directory; '' enables it only "
-        "when $KDLT_COMPILE_CACHE_DIR (or $JAX_COMPILATION_CACHE_DIR) is "
-        "set.  A pod restart then re-reads prior compiles from disk in "
-        "seconds instead of re-paying minutes of bucket warmup (the k8s "
-        "deployment mounts a cache volume for exactly this)",
+        help="persistent XLA compilation-cache directory.  Order: "
+        "$JAX_COMPILATION_CACHE_DIR, this flag, $KDLT_COMPILE_CACHE_DIR, "
+        "<checkout>/.jax_cache.  A restart then re-reads prior compiles "
+        "from disk in seconds instead of re-paying minutes of bucket warmup "
+        "(the k8s deployment mounts a cache volume for exactly this)",
     )
     p.add_argument(
         "--decode",
@@ -1897,15 +1943,17 @@ def main(argv: list[str] | None = None) -> int:
     )
     args = p.parse_args(argv)
 
-    from kubernetes_deep_learning_tpu.utils.platform import force_platform
+    from kubernetes_deep_learning_tpu.utils.platform import (
+        force_platform,
+        require_platform,
+    )
 
-    force_platform(args.platform)
+    requested_platform = force_platform(args.platform)
 
     from kubernetes_deep_learning_tpu.utils.compilecache import enable_compile_cache
 
     cache_path = enable_compile_cache(args.compile_cache_dir or None)
-    if cache_path:
-        print(f"persistent compile cache: {cache_path}", file=sys.stderr)
+    print(f"persistent compile cache: {cache_path or 'off'}", file=sys.stderr)
 
     aot_warm_env = os.environ.get(AOT_WARM_ENV, "").strip().lower() in (
         "1", "true", "yes",
@@ -1933,6 +1981,17 @@ def main(argv: list[str] | None = None) -> int:
             f"multi-host runtime: process {jax.process_index()} of "
             f"{jax.process_count()}, {len(jax.devices())} global devices"
         )
+
+    # Name the device this process serves from; a platform that was asked
+    # for and not found is fatal here, before any model loads.
+    found = require_platform(requested_platform)
+    print(
+        f"serving on platform={found['platform']} "
+        f"device_kind={found['device_kind']!r} "
+        f"devices={found['device_count']} "
+        f"(requested: {requested_platform or 'any'})",
+        file=sys.stderr,
+    )
 
     if args.cross_host:
         # One frontend, model sharded over every process: process 0 serves
@@ -2000,6 +2059,7 @@ def main(argv: list[str] | None = None) -> int:
     finally:
         if grpc_server is not None:
             grpc_server.stop(grace=5)
+    server.wait_shutdown()
     return 0
 
 

@@ -198,7 +198,20 @@ class ModelRegistry:
             # reload is the re-sharding proof, and {model_parallel,
             # mesh_shape} tell an operator what layout a replica runs.
             **self._sharding_status(engine),
+            # What the replica really runs on and through: platform,
+            # device_kind, device count, fused path engaged/degraded,
+            # per-bucket warm-up (engine.device_info), plus the host-path
+            # implementations that are chosen silently by toolchain and
+            # core count (batching queue, resize kernel).
+            **self._device_status(engine),
+            "batcher": getattr(served, "batcher_kind", None),
+            "host_resize": getattr(served, "host_resize", None),
         }
+
+    @staticmethod
+    def _device_status(engine) -> dict:
+        info_fn = getattr(engine, "device_info", None)
+        return info_fn() if callable(info_fn) else {}
 
     @staticmethod
     def _sharding_status(engine) -> dict:
@@ -208,4 +221,5 @@ class ModelRegistry:
             "sharding": info.get("sharding"),
             "model_parallel": info.get("model_parallel", 1),
             "mesh_shape": info.get("mesh_shape"),
+            "batch_rows_per_device": info.get("batch_rows_per_device"),
         }

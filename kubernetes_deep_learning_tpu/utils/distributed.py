@@ -78,14 +78,6 @@ def initialize(environ=None) -> bool:
     if spec is not None:
         import jax
 
-        from kubernetes_deep_learning_tpu.utils.jaxcompat import (
-            enable_cpu_collectives,
-        )
-
-        # CPU fleets (tests, dev boxes) need the Gloo collectives backend
-        # selected before the runtime boots on jax versions where it is
-        # not yet the default; no-op elsewhere.
-        enable_cpu_collectives()
         jax.distributed.initialize(**spec)
         return True
     # On a multi-host TPU slice the runtime self-coordinates; initialize()

@@ -916,6 +916,31 @@ def engine_warm_source_metrics(registry: "Registry") -> dict:
     }
 
 
+def compile_event_counters(registry: "Registry") -> dict:
+    """Process-wide XLA compile accounting, fed from jax.monitoring by
+    utils.compilecache.CompileWatch.  ``requests`` counts every program
+    that reached the backend (compiled live OR loaded from the persistent
+    cache): after /readyz it must stay flat, since every served shape was
+    warmed.  ``cache_hits`` / ``cache_writes`` split the persistent
+    cache's part of it: a restart against a filled cache is all hits and
+    no writes."""
+    return {
+        "requests": registry.counter(
+            "kdlt_xla_compile_requests_total",
+            "programs that reached the XLA backend (live compile or "
+            "persistent-cache load)",
+        ),
+        "cache_hits": registry.counter(
+            "kdlt_xla_compile_cache_hits_total",
+            "compile requests served from the persistent compile cache",
+        ),
+        "cache_writes": registry.counter(
+            "kdlt_xla_compile_cache_writes_total",
+            "live compiles written to the persistent compile cache",
+        ),
+    }
+
+
 def dispatch_stall_counter(registry: "Registry") -> "Counter":
     """In-flight dispatch handles the watchdog declared stuck and failed."""
     return registry.counter(

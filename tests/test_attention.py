@@ -174,7 +174,9 @@ def test_flash_attention_padded_ragged_seq(seq, causal):
     q = jnp.asarray(rng.normal(0, 1, shape), jnp.float32)
     k = jnp.asarray(rng.normal(0, 1, shape), jnp.float32)
     v = jnp.asarray(rng.normal(0, 1, shape), jnp.float32)
-    got = np.asarray(flash_attention_padded(q, k, v, causal=causal))
+    got = np.asarray(
+        flash_attention_padded(q, k, v, causal=causal, interpret=True)
+    )
     want = np.asarray(mha_reference(q, k, v, causal=causal))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 

@@ -551,8 +551,8 @@ else:
         got = xh.predict(images)
         want = np.asarray(ref(variables, images))
         # 2e-2: the pallas interpreter's bf16 accumulation rounds slightly
-        # differently across jax versions (same spread as
-        # tests/test_fused_sepconv.py; measured 1.57e-2 on 0.4.x).
+        # differently from the XLA graph (same bound as
+        # tests/test_fused_sepconv.py).
         rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-6)
         assert rel < 2e-2, f"fast cross-host round diverges from flax: {rel:.2e}"
     xh.shutdown()

@@ -4,7 +4,7 @@ End-to-end logits parity on a small B0 spec whose stages exercise BOTH
 paths at trace time: XLA segments (stem, expand-ratio-1 stage 1, stride-2
 openers) and fused runs (stride-1 repeats AND the stride-1 stage-5/7
 openers fused with residual=False).  Real-TPU speed is
-exp/mbconv_variants.py + BENCH.md's job.
+exp/mbconv_variants.py's job.
 """
 
 from __future__ import annotations

@@ -159,6 +159,12 @@ def test_fast_compile_failure_degrades_to_exact_graph(engine):
         assert eng.ready and dt >= 0
         assert eng.fast_degraded
         assert not eng._fast_engaged
+        # ...and the degrade can never be read as a clean boot: the status
+        # surface (GET /v1/models) and the gauge both say it.
+        info = eng.device_info()
+        assert info["fast_degraded"] is True and info["fast_engaged"] is False
+        assert info["platform"] == "cpu" and info["device_count"] >= 1
+        assert "kdlt_engine_fast_degraded 1.0" in eng.registry.render()
         # and it actually serves, matching the exact graph
         x = np.zeros((2, *spec.input_shape), np.uint8)
         got = eng.predict(x)

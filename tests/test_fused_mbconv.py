@@ -3,7 +3,7 @@
 Pinned here: kernel-vs-reference numerics (3x3 and 5x5 taps, sublane-padded
 batches), weight extraction + the whole fused block against the REAL
 flax.linen MBConvBlock on the same initialized variables.  The real-TPU
-speed claim is exp/mbconv_variants.py + BENCH.md's job.
+speed claim is exp/mbconv_variants.py's job.
 """
 
 from __future__ import annotations

@@ -30,7 +30,12 @@ def _rand_qkv(rng, b=1, h=2, s=128, d=32):
 def test_ring_matches_full_attention(mesh8, causal, use_flash):
     rng = np.random.default_rng(0)
     q, k, v = _rand_qkv(rng)
-    got = ring_attention(q, k, v, mesh8, causal=causal, use_flash=use_flash)
+    # The einsum attend needs no interpreter, so it runs as the compiled TPU
+    # path does: with shard_map's check_vma on.
+    got = ring_attention(
+        q, k, v, mesh8, causal=causal, use_flash=use_flash,
+        interpret=use_flash is not False,
+    )
     want = mha_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=3e-5)
 
@@ -41,17 +46,17 @@ def test_ring_falls_back_when_shard_has_no_tiling():
     mesh2 = make_mesh(2, model_parallel=1)
     rng = np.random.default_rng(4)
     q, k, v = _rand_qkv(rng, s=18)
-    got = ring_attention(q, k, v, mesh2, causal=True)
+    got = ring_attention(q, k, v, mesh2, causal=True, interpret=True)
     want = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=3e-5)
     with pytest.raises(ValueError, match="no MXU tiling"):
-        ring_attention(q, k, v, mesh2, use_flash=True)
+        ring_attention(q, k, v, mesh2, use_flash=True, interpret=True)
 
 
 def test_ring_output_keeps_sequence_sharding(mesh8):
     rng = np.random.default_rng(1)
     q, k, v = _rand_qkv(rng)
-    out = ring_attention(q, k, v, mesh8)
+    out = ring_attention(q, k, v, mesh8, interpret=True)
     # S stays sharded over the data axis: 8 shards, one per device.
     assert len(out.sharding.device_set) == 8
     spec = out.sharding.spec
@@ -69,12 +74,24 @@ def test_ring_on_subset_mesh():
     mesh2 = make_mesh(2, model_parallel=1)
     rng = np.random.default_rng(3)
     q, k, v = _rand_qkv(rng, s=64)
-    got = ring_attention(q, k, v, mesh2, causal=True)
+    got = ring_attention(q, k, v, mesh2, causal=True, interpret=True)
     want = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=3e-5)
 
 
-def test_ring_trainable_matches_autodiff_reference(mesh8):
+@pytest.mark.parametrize(
+    "attend",
+    [
+        # the flash attend in the Pallas interpreter (check_vma off with it)
+        {"interpret": True},
+        # the einsum attend with shard_map's check_vma ON: the trace-time
+        # validation the compiled TPU path runs under, which a bare-zeros
+        # scan carry in the backward ring failed on the four-chip host
+        {"use_flash": False},
+    ],
+    ids=["flash-interpret", "einsum-check-vma"],
+)
+def test_ring_trainable_matches_autodiff_reference(mesh8, attend):
     """Gradients through the trainable ring == autodiff of the full einsum
     reference, for both causal and bidirectional attention (the backward
     ring: dq local, dk/dv rotated home; ROADMAP r1 closed)."""
@@ -93,7 +110,7 @@ def test_ring_trainable_matches_autodiff_reference(mesh8):
     v = jnp.asarray(rng.normal(0, 1, (b, h, s, d)), jnp.float32)
 
     for causal in (False, True):
-        ring_fn = build_ring_attention_trainable(mesh8, causal=causal)
+        ring_fn = build_ring_attention_trainable(mesh8, causal=causal, **attend)
 
         def loss_ring(q, k, v):
             return (ring_fn(q, k, v) ** 2).sum()

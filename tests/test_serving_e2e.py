@@ -135,8 +135,21 @@ def test_health_ready_metrics_endpoints(stack):
     assert requests.get(f"{base_g}/readyz", timeout=5).status_code == 200
     assert "kdlt_gateway_requests_total" in requests.get(f"{base_g}/metrics", timeout=5).text
 
+    assert requests.get(f"{base_s}/readyz", timeout=5).text == "ready"
     models = requests.get(f"{base_s}/v1/models", timeout=5).json()
-    assert models[spec.name]["ready"] is True
+    st = models[spec.name]
+    assert st["ready"] is True
+    # The status names what the replica really runs on and through, so a
+    # driver without jax (chip_smoke.py) can refuse a CPU masquerade.
+    assert (st["platform"], st["device_kind"]) == ("cpu", "cpu")
+    assert st["device_count"] >= 1
+    assert st["fast_degraded"] is False and st["fast_engaged"] is False
+    assert st["batcher"] == "scheduler"
+    assert st["host_resize"] in ("native", "pil")
+    assert set(st["warm"]["buckets"]) == {str(b) for b in st["buckets"]}
+    assert "kdlt_xla_compile_requests_total" in requests.get(
+        f"{base_s}/metrics", timeout=5
+    ).text
     spec_json = requests.get(f"{base_s}/v1/models/{spec.name}", timeout=5).json()
     assert spec_json["name"] == spec.name
 

@@ -171,8 +171,7 @@ def test_shard_map_fast_path_matches_flax(xc_spec, monkeypatch):
     want = np.asarray(
         build_forward(xc_spec, dtype=jnp.bfloat16, fast=False)(variables, images)
     )
-    # 2e-2: same interpreter bf16-rounding spread across jax versions as
-    # test_fused_sepconv (measured 1.02e-2 on 0.4.x, under 1e-2 on current).
+    # 2e-2: same interpreter bf16-rounding bound as test_fused_sepconv.
     rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-6)
     assert rel < 2e-2, f"shard_map fast path diverges: {rel:.2e}"
 

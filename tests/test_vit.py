@@ -100,21 +100,14 @@ def test_vit_long_seq_exports_per_platform(tmp_path):
     variables = init_variables(spec, seed=0)
     directory = export_model(spec, variables, str(tmp_path))
     files = set(os.listdir(directory))
-    if hasattr(jax, "typeof"):
-        # Modern JAX: platform_dependent branches survive into the traced
-        # module, cannot co-lower cpu+tpu -> per-platform layout.
-        assert art.platform_module_file("cpu") in files
-        assert art.platform_module_file("tpu") in files
-        assert art.MODULE_FILE not in files
-        a = art.load_artifact(directory)
-        assert a.metadata["module_layout"] == "per-platform"
-        assert a.module_bytes_for("cpu") is not None
-    else:
-        # Pre-pruning JAX (utils.jaxcompat.platform_dependent): the branch
-        # resolves at trace time, so ONE portable module exports -- the
-        # layout differs but the artifact must still load and serve.
-        a = art.load_artifact(directory)
-        assert a.module_bytes_for("cpu") is not None
+    # platform_dependent branches survive into the traced module, which
+    # cannot co-lower cpu+tpu -> per-platform layout.
+    assert art.platform_module_file("cpu") in files
+    assert art.platform_module_file("tpu") in files
+    assert art.MODULE_FILE not in files
+    a = art.load_artifact(directory)
+    assert a.metadata["module_layout"] == "per-platform"
+    assert a.module_bytes_for("cpu") is not None
 
     # The engine must pick its device's module at load and serve from it
     # (CPU here -> the einsum branch of the platform-dependent module).

@@ -124,7 +124,10 @@ def test_warm_main_rc_and_json(tmp_path, monkeypatch, capsys):
     assert "warm-cli" in report["models"]
     # An empty root is rc=1 loudly: a warm pass that warmed NOTHING must
     # fail the image build rather than bake a cold cache silently.
-    assert warm.main(["--models", str(tmp_path / "empty")]) == 1
+    assert warm.main([
+        "--models", str(tmp_path / "empty"),
+        "--compile-cache-dir", str(tmp_path / "cache"),
+    ]) == 1
 
 
 def test_warm_main_rc_1_when_any_model_fails(tmp_path, monkeypatch):
