@@ -220,10 +220,8 @@ def compose_headline(model, dtype, params_dtype, results, faults, flops_img,
     return out, 0 if (valid_pool and headline_batch in eligible) else 1
 
 
-# Device peaks + FLOP counting now live in the runtime (runtime/flops.py)
-# so serving pods maintain the same MFU arithmetic as LIVE gauges
-# (kdlt_mfu_pct{model,bucket}); the bench keeps these names as its offline
-# reference implementation -- the acceptance check is that the two agree.
+# Device peaks + FLOP counting live in the runtime (runtime/flops.py): the
+# status page reports the peak and the bucket audit the FLOPs/image.
 from kubernetes_deep_learning_tpu.runtime.flops import (  # noqa: E402
     PEAK_TFLOPS_BY_KIND,
     compiled_flops_per_image,

@@ -215,7 +215,14 @@ class InFlightDispatcher:
     reusable staging buffers must rotate >= depth+1 buffers.
 
     Per-stage latency lands in the kdlt_pipeline_*_seconds histograms
-    (utils.metrics.PIPELINE_STAGES documents the stage semantics).
+    (utils.metrics.PIPELINE_STAGES documents the stage semantics), and the
+    live regions of enqueue_wait, dispatch and readback are profiler
+    annotations under their span names, on the thread that runs them.
+
+    Why the device waits, from where the work happens: every instant of the
+    dispatcher's life is booked to one of kdlt_pipeline_{inflight,
+    idle_dispatch,idle_no_batch}_seconds_total (utils.metrics.
+    PIPELINE_IDLE_CAUSES) by a three-state machine under _inflight_lock.
     """
 
     def __init__(self, engine=None, depth: int | None = None,
@@ -243,10 +250,13 @@ class InFlightDispatcher:
         # per-chip dispatch from fleet rounds; plain engines keep the
         # unlabeled single-host series.  Per-model stage series (scheduler
         # mode) are minted lazily in _stages_for.
-        self._m_stage = metrics_lib.pipeline_stage_histograms(
-            registry, engine=getattr(engine, "pipeline_engine_label", None)
-        )
+        label = getattr(engine, "pipeline_engine_label", None)
+        self._m_stage = metrics_lib.pipeline_stage_histograms(registry, engine=label)
         self._m_stage_models: dict[str, dict] = {}
+        self._m_idle = metrics_lib.pipeline_idle_counters(registry, engine=label)
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
         # Trace-aware engines (CrossHostEngine) take the member requests'
         # RequestTrace carriers through predict_async and record their own
         # protocol spans (crosshost.*) under the same waterfall the
@@ -271,6 +281,11 @@ class InFlightDispatcher:
         self._inflight: dict[int, tuple[Future, tuple, float]] = {}  # guarded-by: _inflight_lock
         self._inflight_lock = threading.Lock()
         self._seq = 0                # guarded-by: _inflight_lock
+        # The idle-cause state machine: the state is a function of
+        # (_inflight non-empty, _submitting > 0); _accrue_locked books the
+        # time since the last transition to it before either changes.
+        self._submitting = 0         # guarded-by: _inflight_lock
+        self._state_since = time.perf_counter()  # guarded-by: _inflight_lock
         self._expected_s: dict[tuple, float] = {}  # guarded-by: _inflight_lock
         if watchdog is None:
             watchdog = os.environ.get(WATCHDOG_ENV, "").strip() != "0"
@@ -328,6 +343,21 @@ class InFlightDispatcher:
             self._m_stage_models[model] = stages
         return stages
 
+    def _accrue_locked(self) -> None:
+        """Book the seconds since the last transition to the state they
+        were spent in.  Called under _inflight_lock BEFORE every change to
+        _inflight or _submitting (and on the watchdog's tick, so an idle
+        dispatcher's counters lag by at most that)."""
+        now = time.perf_counter()
+        if self._inflight:
+            cause = "inflight"
+        elif self._submitting:
+            cause = "idle_dispatch"
+        else:
+            cause = "idle_no_batch"
+        self._m_idle[cause].inc(now - self._state_since)
+        self._state_since = now
+
     def _engine_key(self, engine):
         spec = getattr(engine, "spec", None)
         return getattr(spec, "name", None) or id(engine)
@@ -363,7 +393,8 @@ class InFlightDispatcher:
         traces = tuple(t for t in traces if t is not None)
         t0 = time.perf_counter()
         w0 = trace_lib.now_s() if traces else 0.0
-        self._slots.acquire()
+        with self._annotate(trace_lib.SPAN_PIPELINE_ENQUEUE_WAIT):
+            self._slots.acquire()
         # kdlt-lint: disable=guarded-by -- the slot-semaphore handshake orders this read: close() drains every slot before flipping _closed, so a submit holding a slot observes the flip or the drain, never a torn state
         if self._closed:
             self._slots.release()
@@ -374,15 +405,22 @@ class InFlightDispatcher:
         stages["enqueue_wait"].observe(time.perf_counter() - t0)
         w1 = trace_lib.now_s() if traces else 0.0
         fut: Future = Future()
+        with self._inflight_lock:
+            self._accrue_locked()
+            self._submitting += 1
         t1 = time.perf_counter()
         try:
-            if self._faults is not None:
-                self._faults.fire("dispatch.submit")
-            if self._takes_traces(engine):
-                handle, n = engine.predict_async(images, traces=traces)
-            else:
-                handle, n = engine.predict_async(images)
+            with self._annotate(trace_lib.SPAN_PIPELINE_DISPATCH):
+                if self._faults is not None:
+                    self._faults.fire("dispatch.submit")
+                if self._takes_traces(engine):
+                    handle, n = engine.predict_async(images, traces=traces)
+                else:
+                    handle, n = engine.predict_async(images)
         except Exception as e:  # dispatch failure belongs to THIS future
+            with self._inflight_lock:
+                self._accrue_locked()
+                self._submitting -= 1
             self._slots.release()
             fut.set_exception(e)
             return fut
@@ -391,6 +429,8 @@ class InFlightDispatcher:
         w2 = trace_lib.now_s() if traces else 0.0
         bkey = (self._engine_key(engine), self._bucket_of(engine, n))
         with self._inflight_lock:
+            self._accrue_locked()
+            self._submitting -= 1
             token = self._seq
             self._seq += 1
             self._inflight[token] = (fut, bkey, dispatched_at)
@@ -419,11 +459,13 @@ class InFlightDispatcher:
         w3 = trace_lib.now_s() if traces else 0.0
         t0 = time.perf_counter()
         try:
-            if self._faults is not None:
-                self._faults.fire("dispatch.complete")
-            rows = np.asarray(handle)[:n]  # blocking device sync + D2H
+            with self._annotate(trace_lib.SPAN_PIPELINE_READBACK):
+                if self._faults is not None:
+                    self._faults.fire("dispatch.complete")
+                rows = np.asarray(handle)[:n]  # blocking device sync + D2H
         except Exception as e:  # device-side failure surfaces at sync
             with self._inflight_lock:
+                self._accrue_locked()
                 self._inflight.pop(token, None)
             self._slots.release()
             if not fut.cancelled():
@@ -434,6 +476,7 @@ class InFlightDispatcher:
         stages["readback"].observe(t1 - t0)
         self._observe_latency(bkey, t1 - dispatched_at)
         with self._inflight_lock:
+            self._accrue_locked()
             self._inflight.pop(token, None)
         try:
             if hasattr(engine, "record_completed"):
@@ -507,6 +550,7 @@ class InFlightDispatcher:
         """One watchdog scan; returns True when a stall was declared."""
         now = time.perf_counter()
         with self._inflight_lock:
+            self._accrue_locked()
             entries = list(self._inflight.items())
         overdue = [
             (token, fut, bkey)
@@ -540,6 +584,7 @@ class InFlightDispatcher:
         """
         self._stalled.set()
         with self._inflight_lock:
+            self._accrue_locked()
             stranded = list(self._inflight.items())
             self._inflight.clear()
         for _token, (fut, _n, _t0) in stranded:
@@ -587,6 +632,8 @@ class InFlightDispatcher:
                 self._closed = True
         self._completions.put(None)
         self._thread.join(timeout=0.5 if self._stalled.is_set() else 30.0)
+        with self._inflight_lock:
+            self._accrue_locked()  # the counters' last word: they sum to the age
         if self._watchdog_thread is not None:
             self._watchdog_thread.join(timeout=5.0)
 
@@ -850,17 +897,6 @@ class InferenceEngine:
         self._m_fast_degraded = registry.gauge(
             "kdlt_engine_fast_degraded",
             "1 when a fused fast-path compile failure forced the exact graph",
-        )
-        # Live device-time attribution (runtime.flops): per-bucket MFU +
-        # device-busy gauges from the same dispatch->sync timings as
-        # kdlt_engine_infer_seconds.  FLOPs per bucket are estimated on a
-        # background thread (lowering-only cost analysis, no compile); the
-        # registry already carries this engine's model/version labels, so
-        # the gauges read kdlt_mfu_pct{model,version,bucket} on /metrics.
-        self._mfu = flops_lib.MfuAccountant(
-            registry,
-            flops_lib.peak_tflops(self._device, str(self._compute_dtype)),
-            self._flops_per_image,
         )
         # Quantization scheme + tolerance-gate accounting (kdlt_quant_*,
         # minted centrally): the scheme gauge is 1 for the ACTIVE scheme
@@ -1312,13 +1348,14 @@ class InferenceEngine:
 
     def device_info(self) -> dict[str, Any]:
         """The status surface that keeps a green boot honest (GET
-        /v1/models): the device as JAX reports it, the peak the MFU gauges
-        divide by (None = a device_kind the table does not know), whether
-        the fused path is in the served programs or was degraded away, and
-        each bucket's warm-up seconds."""
+        /v1/models): the device as JAX reports it, its dense peak (None = a
+        device_kind the table does not know), whether the fused path is in
+        the served programs or was degraded away, each bucket's warm-up
+        seconds, and the device's memory as its allocator reports it
+        (absent where the backend reports none, as the CPU's does)."""
         import jax
 
-        return {
+        info = {
             "platform": self._device.platform,
             "device_kind": self._device.device_kind,
             "device_count": len(jax.local_devices()),
@@ -1329,14 +1366,25 @@ class InferenceEngine:
             "fast_degraded": bool(self.fast_degraded),
             "warm": dict(self.warm_report),
         }
+        stats = self._device.memory_stats()
+        if stats:
+            # A program's scratch is booked under *_reserved, apart from
+            # the buffers under *_in_use; both occupy the chip.
+            info["memory"] = {
+                k: int(stats[k])
+                for k in ("bytes_in_use", "peak_bytes_in_use",
+                          "peak_bytes_reserved", "bytes_limit")
+                if k in stats
+            }
+        return info
 
     def bucket_audit(self) -> dict[str, Any]:
         """Per-bucket padding-waste + FLOPs audit (/debug/profile?audit=
         buckets): admitted-vs-bucket sizes over the recent dispatch history
         plus FLOPs/img from the lowered cost analysis (cached, trace-only
-        -- never an XLA compile).  The diagnostic for the roofline gap the
-        MFU gauges leave unexplained: a high padding_waste_ratio means the
-        bucket ladder, not the program, is burning the flops."""
+        -- never an XLA compile).  A high padding_waste_ratio means the
+        bucket ladder, not the program, is burning the flops; MFU off-box is
+        kdlt_engine_images_total's rate x flops_per_image / peak_tflops."""
         hist = list(self._bucket_history)
         out: dict[str, Any] = {"window": len(hist), "buckets": {}}
         for b in self.buckets:
@@ -1353,12 +1401,9 @@ class InferenceEngine:
         return out
 
     def _audit_flops_for(self, bucket: int) -> float | None:
-        """FLOPs/img for the audit: the MfuAccountant's estimate when its
-        background thread already produced one, else computed once here and
-        cached (same lowering-only analysis)."""
-        got = self._mfu.flops_estimate(bucket)
-        if got is not None:
-            return got
+        """FLOPs/img for the audit: computed on the first ask, then cached
+        (lowering-only analysis, seconds of host time on the asking
+        thread; nothing lowers unasked)."""
         with self._audit_flops_lock:
             if bucket in self._audit_flops:
                 return self._audit_flops[bucket]
@@ -1371,14 +1416,13 @@ class InferenceEngine:
         return val
 
     def _flops_per_image(self, bucket: int) -> float | None:
-        """FLOPs/image at one bucket shape, for the live MFU gauges.
+        """FLOPs/image at one bucket shape, for the bucket audit.
 
-        Runs on the MfuAccountant's background thread.  Uses the NON-fused
-        flax graph (bench.py's rule: cost analysis cannot see inside Pallas
-        custom calls) and the LOWERING-level analysis -- trace only, never
-        an XLA compile, so attribution can never cost a serving pod compile
-        time.  Families with no in-tree model (exported-only artifacts)
-        raise inside and report None: their gauge simply doesn't exist.
+        Uses the NON-fused flax graph (bench.py's rule: cost analysis
+        cannot see inside Pallas custom calls) and the LOWERING-level
+        analysis -- trace only, never an XLA compile.  Families with no
+        in-tree model (exported-only artifacts) raise inside; the audit
+        reports None for them.
         """
         import jax
         import jax.numpy as jnp
@@ -1579,7 +1623,6 @@ class InferenceEngine:
         self._m_batches.inc()
         bucket = self.bucket_for(n)
         self._m_pad_waste.inc(bucket - n)
-        self._mfu.observe(bucket, n, seconds)
         self._bucket_history.append((bucket, n))
         if self._m_mesh is not None:
             self._m_mesh["collective"].inc(seconds)

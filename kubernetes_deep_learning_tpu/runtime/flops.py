@@ -1,36 +1,22 @@
-"""Device-peak tables, FLOP counting, and live MFU attribution.
+"""Device-peak table and FLOP counting.
 
 ``peak_tflops`` / ``compiled_flops_per_image`` started life inside
-bench.py, which meant MFU existed only as an *offline* number: the ROADMAP
-item "push MFU past ~38%" could not be read off a serving pod.  This
-module is their runtime home -- bench.py imports from here, and
-:class:`MfuAccountant` turns the same arithmetic into always-on gauges
-(``kdlt_mfu_pct{model,bucket}``, ``kdlt_device_busy_ratio``) fed by the
-in-flight dispatcher's dispatch->sync timings, so the roofline gap is
-visible on /metrics in production, per model and per compiled bucket.
+bench.py; this module is their runtime home (bench.py imports from here,
+the engine's status page reports the peak, the bucket audit the lowered
+FLOPs/image).  MFU itself is computed off-box: ``kdlt_engine_images_total``'s
+rate x the audit page's FLOPs/image over the peak.  How busy the device is
+comes from the dispatcher's ``kdlt_pipeline_*_seconds_total`` counters.
 
 FLOPs come from XLA's own cost analysis of the **non-fused flax graph**
 (bench.py's rule: cost analysis cannot see inside Pallas custom calls, so
-the fused fast path under-reports); the engine hands this module a
-``flops_fn`` that lowers that graph per bucket.  Lowering is trace-only
-(no XLA compile, no device work) but still not hot-path material, so it
-runs once per bucket on a background thread -- until the count arrives,
-the bucket's gauge simply doesn't exist.
+the fused fast path under-reports).
 """
 
 from __future__ import annotations
 
 import logging
-import math
-import os
-import threading
-import time
-
-from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
 
 log = logging.getLogger(__name__)
-
-MFU_ENV = "KDLT_MFU"  # "0" disables the live attribution layer
 
 # Per-chip dense peak (TFLOP/s) for the compute dtype, keyed by substrings
 # of jax's Device.device_kind.  An unknown device reports MFU as None
@@ -43,12 +29,6 @@ PEAK_TFLOPS_BY_KIND = {
     "v6 lite": {"bfloat16": 918.0, "float32": 459.0},  # Trillium
     "v6e": {"bfloat16": 918.0, "float32": 459.0},
 }
-
-
-def mfu_enabled(explicit: bool | None = None) -> bool:
-    if explicit is not None:
-        return bool(explicit)
-    return os.environ.get(MFU_ENV, "").strip() != "0"
 
 
 def peak_tflops(device, dtype_name: str) -> float | None:
@@ -81,9 +61,9 @@ def compiled_flops_per_image(jitted, batch: int, *example_args) -> float | None:
 def lowered_flops_per_image(jitted, batch: int, *example_args) -> float | None:
     """FLOPs/image from the LOWERED (pre-compile) cost analysis.
 
-    The live serving path must never pay an XLA compile just to label a
-    gauge, so the runtime uses the lowering-level analysis: trace + HLO
-    emission only, seconds of host time, no device involvement.  For the
+    The serving process must never pay an XLA compile for an audit page,
+    so the runtime uses the lowering-level analysis: trace + HLO emission
+    only, seconds of host time, no device involvement.  For the
     conv/attention families served here the flop count is dominated by ops
     fusion does not remove, so it tracks the compiled figure closely
     (bench.py still reports the compiled number offline; the acceptance
@@ -98,120 +78,3 @@ def lowered_flops_per_image(jitted, batch: int, *example_args) -> float | None:
     except Exception as e:  # noqa: BLE001 - best-effort, like the compiled path
         log.info("lowered cost analysis unavailable: %r", e)
         return None
-
-
-# Decay half-life for the device-busy accumulator: long enough to smooth
-# per-batch jitter, short enough that the gauge tracks a load change within
-# a scrape interval or two.
-BUSY_HALFLIFE_S = 30.0
-_LN2 = math.log(2.0)
-
-
-class MfuAccountant:
-    """Live per-bucket MFU + device-busy gauges for one serving engine.
-
-    ``observe(bucket, n, seconds)`` is called from the engine's completion
-    accounting (dispatch->sync timing, the same boundary as
-    ``kdlt_engine_infer_seconds``); it is O(1) -- a dict lookup, a couple
-    of multiplies, a gauge set.  The FLOPs/image figure each bucket needs
-    is produced by ``flops_fn(bucket)`` on a single background worker
-    thread, queued the first time a bucket completes.
-
-    MFU per batch is ``bucket_rows * flops_per_image / (seconds * peak)``:
-    the device executes the PADDED bucket, so padding waste honestly
-    depresses the number (same convention as bench.py's saturated img/s,
-    which never pads).  The gauge is an EWMA over batches.
-    """
-
-    def __init__(self, registry: metrics_lib.Registry,
-                 peak_tf: float | None, flops_fn,
-                 enabled: bool | None = None):
-        self.enabled = mfu_enabled(enabled) and peak_tf is not None
-        self._registry = registry
-        self._peak_flops = (peak_tf or 0.0) * 1e12
-        self._flops_fn = flops_fn
-        self._flops: dict[int, float | None] = {}
-        self._ewma: dict[int, float] = {}
-        self._gauges: dict[int, metrics_lib.Gauge] = {}
-        self._lock = threading.Lock()
-        self._pending: list[int] = []
-        self._worker: threading.Thread | None = None
-        # Busy accounting runs even when MFU itself cannot (unknown device
-        # kind): utilization needs no peak table.
-        self._busy_enabled = mfu_enabled(enabled)
-        self._busy = 0.0
-        self._busy_at = time.monotonic()
-        self._m_busy = (
-            metrics_lib.device_busy_gauge(registry)
-            if self._busy_enabled else None
-        )
-
-    def _ensure_flops_locked(self, bucket: int) -> None:
-        if bucket in self._flops or bucket in self._pending:
-            return
-        self._pending.append(bucket)
-        if self._worker is None or not self._worker.is_alive():
-            self._worker = threading.Thread(
-                target=self._flops_worker, name="kdlt-mfu-flops", daemon=True
-            )
-            self._worker.start()
-
-    def _flops_worker(self) -> None:
-        while True:
-            with self._lock:
-                if not self._pending:
-                    return
-                bucket = self._pending[0]
-            try:
-                flops = self._flops_fn(bucket)
-            except Exception as e:  # noqa: BLE001 - attribution must not kill serving
-                log.info("flops estimation failed for bucket %d: %r", bucket, e)
-                flops = None
-            with self._lock:
-                self._flops[bucket] = flops
-                self._pending.remove(bucket)
-
-    def observe(self, bucket: int, n: int, seconds: float) -> None:
-        """Account one completed batch (``n`` real rows padded to
-        ``bucket``) that held the device for ``seconds``."""
-        del n  # the device executed the padded bucket either way
-        if self._busy_enabled:
-            now = time.monotonic()
-            with self._lock:
-                dt = max(0.0, now - self._busy_at)
-                if dt > 0:
-                    self._busy *= 0.5 ** (dt / BUSY_HALFLIFE_S)
-                    self._busy_at = now
-                self._busy += seconds
-                # Steady state: a utilization-u stream decays to
-                # u * halflife / ln2, so this reads back u directly.
-                ratio = min(1.0, self._busy * _LN2 / BUSY_HALFLIFE_S)
-            self._m_busy.set(ratio)
-        if not self.enabled or seconds <= 0:
-            return
-        with self._lock:
-            self._ensure_flops_locked(bucket)
-            flops_img = self._flops.get(bucket)
-            if not flops_img:
-                return
-            mfu = (bucket * flops_img) / (seconds * self._peak_flops)
-            prev = self._ewma.get(bucket)
-            mfu = mfu if prev is None else 0.8 * prev + 0.2 * mfu
-            self._ewma[bucket] = mfu
-            gauge = self._gauges.get(bucket)
-            if gauge is None:
-                gauge = metrics_lib.mfu_bucket_gauge(self._registry, bucket)
-                self._gauges[bucket] = gauge
-        gauge.set(round(mfu * 100.0, 2))
-
-    def flops_estimate(self, bucket: int) -> float | None:
-        """The background worker's FLOPs/image figure for a bucket, if it
-        has been produced (None while pending or when estimation failed);
-        the bucket-shape audit reads this before computing its own."""
-        with self._lock:
-            return self._flops.get(bucket)
-
-    def snapshot(self) -> dict:
-        """{bucket: mfu_pct} for debugging/tests."""
-        with self._lock:
-            return {b: round(v * 100.0, 2) for b, v in self._ewma.items()}

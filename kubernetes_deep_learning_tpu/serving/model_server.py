@@ -20,13 +20,17 @@ Endpoints::
     GET  /v1/models/<name>             the ModelSpec (the discoverable
                                        contract; replaces saved_model_cli)
     GET  /healthz | /readyz | /metrics
-    POST /debug/profile                capture a jax.profiler device trace
-                                       ({"seconds": s}); traces land in
-                                       fresh directories under the server's
-                                       --profile-dir (never a client-chosen
-                                       path).  The tracing hook SURVEY.md
-                                       section 5 notes the reference lacks
-                                       entirely; disable with --no-profiling
+    POST /debug/profile                capture a jax.profiler trace
+                                       ({"seconds": s}): the device's planes,
+                                       Python and host tracers off; with
+                                       "annotations": true also this tier's
+                                       live spans as host annotations;
+                                       traces land in fresh directories
+                                       under the server's --profile-dir
+                                       (never a client-chosen path).  The
+                                       tracing hook SURVEY.md section 5
+                                       notes the reference lacks entirely;
+                                       disable with --no-profiling
 """
 
 from __future__ import annotations
@@ -405,8 +409,14 @@ class ModelServer:
         # Per-request span traces (utils.trace): the model-tier half of the
         # cross-tier waterfall, keyed by the propagated X-Request-Id and
         # served at /debug/trace/<rid>.  The registry wires the tail-based
-        # retention accounting (kdlt_trace_{retained,dropped}_total).
-        self.tracer = trace_lib.Tracer("model-server", registry=self.registry)
+        # retention accounting (kdlt_trace_{retained,dropped}_total).  Every
+        # live span is also a profiler annotation, so a /debug/profile
+        # capture shows the handler's stages beside the device's programs.
+        from jax.profiler import TraceAnnotation
+
+        self.tracer = trace_lib.Tracer(
+            "model-server", registry=self.registry, annotate=TraceAnnotation
+        )
         # SLO engine (utils.slo): per-model sliding-window goodput and
         # multi-window burn rates against $KDLT_SLO_TARGET, fed from the
         # same handler boundary as kdlt_server_request_seconds; serves
@@ -1108,34 +1118,40 @@ class ModelServer:
                             f"{limit}-byte limit "
                             f"({MAX_IMAGES_PER_REQUEST}-image cap)"
                         )
-                    with rt.span(trace_lib.SPAN_SERVER_DECODE, bytes=length):
-                        body = self.rfile.read(length)
+                    with rt.span(trace_lib.SPAN_SERVER_DECODE, bytes=length) as dt:
+                        with dt.span(trace_lib.SPAN_SERVER_READ_BODY):
+                            body = self.rfile.read(length)
                         self._body_consumed = True
                         ctype = self.headers.get("Content-Type", "")
                         encoded_wire = (
                             ctype.split(";")[0].strip()
                             == protocol.BYTES_CONTENT_TYPE
                         )
-                        if not encoded_wire:
-                            images = protocol.decode_predict_request(body, ctype)
-                    if encoded_wire:
                         # Raw-bytes ingest wire (GUIDE 10q): the payload is
-                        # the packed encoded JPEG/PNG blobs; decode happens
-                        # HERE, at the model tier, on the GIL-released pool
-                        # (through the decoded-uint8 cache), instead of at
-                        # the gateway fan-in.  A disabled server 400s --
-                        # the gateway's negotiation normally prevents this,
-                        # and on a stale-negotiation race it decodes and
-                        # resends on the tensor wire.
-                        if not server._ingest_enabled:
+                        # the packed encoded JPEG/PNG blobs; pixel decode
+                        # happens below, at the model tier, on the
+                        # GIL-released pool (through the decoded-uint8
+                        # cache), instead of at the gateway fan-in.  A
+                        # disabled server 400s -- the gateway's negotiation
+                        # normally prevents this, and on a
+                        # stale-negotiation race it decodes and resends on
+                        # the tensor wire.
+                        if encoded_wire and not server._ingest_enabled:
                             raise ValueError(
                                 "raw-bytes ingest is disabled on this "
                                 f"server (set {protocol.INGEST_ENV}=1 or "
                                 "use the tensor wire)"
                             )
-                        blobs = protocol.decode_bytes_predict_request(
-                            body, max_images=MAX_IMAGES_PER_REQUEST
-                        )
+                        with dt.span(trace_lib.SPAN_SERVER_UNPACK):
+                            if encoded_wire:
+                                blobs = protocol.decode_bytes_predict_request(
+                                    body, max_images=MAX_IMAGES_PER_REQUEST
+                                )
+                            else:
+                                images = protocol.decode_predict_request(
+                                    body, ctype
+                                )
+                    if encoded_wire:
                         batch = len(blobs)
                         src_shape = tuple(
                             getattr(
@@ -1196,24 +1212,26 @@ class ModelServer:
                                 images, deadline=deadline, trace=pt,
                                 priority=priority,
                             )
-                    out, out_ctype = protocol.encode_predict_response(
-                        logits, spec.labels, ctype
-                    )
-                    if server._faults is not None:
-                        out = server._faults.corrupt("server.predict", out)
-                    status = 200
-                    # The serving artifact's sha256 identity rides every
-                    # success: the gateway's response cache keys validity
-                    # on it (a reload with changed bytes changes the hash
-                    # and drops that model's entries; a byte-identical
-                    # version bump keeps them).
-                    ah = getattr(model, "artifact_hash", None)
-                    self._send(
-                        200, out, out_ctype,
-                        headers=(
-                            {protocol.ARTIFACT_HASH_HEADER: ah} if ah else None
-                        ),
-                    )
+                    with rt.span(trace_lib.SPAN_SERVER_RESPOND):
+                        out, out_ctype = protocol.encode_predict_response(
+                            logits, spec.labels, ctype
+                        )
+                        if server._faults is not None:
+                            out = server._faults.corrupt("server.predict", out)
+                        status = 200
+                        # The serving artifact's sha256 identity rides every
+                        # success: the gateway's response cache keys
+                        # validity on it (a reload with changed bytes
+                        # changes the hash and drops that model's entries; a
+                        # byte-identical version bump keeps them).
+                        ah = getattr(model, "artifact_hash", None)
+                        self._send(
+                            200, out, out_ctype,
+                            headers=(
+                                {protocol.ARTIFACT_HASH_HEADER: ah}
+                                if ah else None
+                            ),
+                        )
                 except faults_lib.InjectedDisconnect:
                     # Injected abrupt connection loss: no response bytes at
                     # all -- the client sees the socket die mid-request,
@@ -1490,15 +1508,14 @@ class ModelServer:
 
                 Blocks the calling client for ``seconds``; serving continues
                 on the other handler threads, which is the point -- the
-                trace shows real request execution on the device.
+                trace shows real request execution on the device, with this
+                tier's live spans beside it (ModelServer._capture_profile).
                 """
-                import tempfile
-
                 if self.command == "GET":
                     # GET /debug/profile?audit=buckets: the bucket-shape
                     # audit (padding waste + FLOPs/img) -- pure host-side
                     # bookkeeping, served even where device profiling is
-                    # disabled.
+                    # disabled.  ?seconds=N is the curl-friendly capture.
                     from urllib.parse import parse_qs, urlparse
 
                     q = parse_qs(urlparse(self.path).query)
@@ -1508,44 +1525,30 @@ class ModelServer:
                     return self._send_json(404, {"error": "profiling disabled"})
                 try:
                     if self.command == "GET":
-                        # GET /debug/profile?seconds=N (curl-friendly).
-                        from urllib.parse import parse_qs, urlparse
-
-                        q = parse_qs(urlparse(self.path).query)
                         seconds = float(q.get("seconds", ["2.0"])[0])
+                        annotations = q.get("annotations", ["0"])[0] == "1"
                     else:
                         length = int(self.headers.get("Content-Length", 0))
                         req = json.loads(self.rfile.read(length)) if length else {}
                         if not isinstance(req, dict):
                             raise ValueError("body must be a JSON object")
                         seconds = float(req.get("seconds", 2.0))
+                        annotations = req.get("annotations") is True
                     if not 0 < seconds <= 60:
                         raise ValueError("seconds must be in (0, 60]")
-                    # Client input never chooses the path: traces go into a
-                    # fresh dir under the operator-configured base (an
-                    # arbitrary "dir" would let any in-cluster client write
-                    # into e.g. the artifact root the version watcher scans).
-                    os.makedirs(server._profile_base, exist_ok=True)
-                    trace_dir = tempfile.mkdtemp(
-                        prefix="kdlt-trace-", dir=server._profile_base
-                    )
                 except (ValueError, TypeError, json.JSONDecodeError) as e:
                     return self._send_json(400, {"error": str(e)})
-                if not server._profile_lock.acquire(blocking=False):
+                try:
+                    reply = server._capture_profile(
+                        seconds, "kdlt-trace-", annotations
+                    )
+                except Exception as e:
+                    return self._send_json(500, {"error": str(e)})
+                if reply is None:
                     return self._send_json(
                         409, {"error": "a profile capture is already running"}
                     )
-                try:
-                    import jax
-
-                    jax.profiler.start_trace(trace_dir)
-                    time.sleep(seconds)
-                    jax.profiler.stop_trace()
-                except Exception as e:
-                    return self._send_json(500, {"error": str(e)})
-                finally:
-                    server._profile_lock.release()
-                self._send_json(200, {"trace_dir": trace_dir, "seconds": seconds})
+                self._send_json(200, reply)
 
         return Handler
 
@@ -1579,8 +1582,10 @@ class ModelServer:
                 "(timeline, pinned traces, snapshots, metrics delta)",
                 "/debug/trace/<rid>": "this tier's span waterfall for "
                 "one request id",
-                "/debug/profile?seconds=N": "capture a jax.profiler "
-                "device trace under KDLT_PROFILE_DIR",
+                "/debug/profile?seconds=N": "capture a jax.profiler trace "
+                "under KDLT_PROFILE_DIR: the device's planes, Python and "
+                "host tracers off; &annotations=1 adds this tier's live "
+                "spans as host annotations (costly under tensor traffic)",
                 "/debug/profile?audit=buckets": "per-model bucket-shape "
                 "audit: padding-waste ratio + compiled FLOPs/img per bucket",
             },
@@ -1596,29 +1601,64 @@ class ModelServer:
                 models[name] = audit_fn()
         return {"tier": "model-server", "models": models}
 
+    def _capture_profile(self, seconds: float, prefix: str,
+                         annotations: bool = False) -> dict | None:
+        """One profiler capture of ``seconds`` into a fresh directory under
+        the profile base; None when another capture holds the lock.
+
+        The Python tracer is always off: with it the profiler stalled the
+        device ~2 s at the start and wrote 200 MB for 3 s (PERF.md, PR 23).
+        The host tracer is off unless ``annotations``: level 1 is the
+        lowest that records this tier's TraceAnnotations (the live spans,
+        the dispatcher's stages), but no level separates them from the
+        runtime's own level-1 events, and under tensor traffic the
+        runtime's input re-tiling alone emits ~1 M of those a second -- a
+        2 s capture wrote 75 MB and stopped the process for a minute while
+        it was written (PERF.md, PR 24).  So the default is the device's
+        planes alone, which costs nothing while it runs, and annotations
+        are asked for where the bodies are small or the stall is
+        acceptable.  Client input never chooses the path: an arbitrary
+        directory would let any in-cluster client write into e.g. the
+        artifact root the version watcher scans.
+        """
+        import tempfile
+
+        import jax
+
+        if not self._profile_lock.acquire(blocking=False):
+            return None
+        try:
+            os.makedirs(self._profile_base, exist_ok=True)
+            trace_dir = tempfile.mkdtemp(prefix=prefix, dir=self._profile_base)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1 if annotations else 0
+            t0 = time.perf_counter()
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
+            t1 = time.perf_counter()
+            try:
+                time.sleep(seconds)
+            finally:
+                t2 = time.perf_counter()
+                jax.profiler.stop_trace()
+            return {
+                "trace_dir": trace_dir, "seconds": seconds,
+                "annotations": annotations,
+                "start_took_s": t1 - t0,
+                "stop_took_s": time.perf_counter() - t2,
+            }
+        finally:
+            self._profile_lock.release()
+
     def _incident_profile(self, seconds: float) -> dict:
         """Flight-recorder profile hook (KDLT_INCIDENT_PROFILE_S > 0): the
         same capture as /debug/profile, same lock -- a concurrent operator
         capture wins and the bundle notes the skip instead of waiting."""
-        import tempfile
-
         if self._profile_base is None:
             return {"skipped": "profiling disabled"}
-        if not self._profile_lock.acquire(blocking=False):
-            return {"skipped": "a profile capture is already running"}
-        try:
-            import jax
-
-            os.makedirs(self._profile_base, exist_ok=True)
-            trace_dir = tempfile.mkdtemp(
-                prefix="kdlt-incident-", dir=self._profile_base
-            )
-            jax.profiler.start_trace(trace_dir)
-            time.sleep(seconds)
-            jax.profiler.stop_trace()
-            return {"trace_dir": trace_dir, "seconds": seconds}
-        finally:
-            self._profile_lock.release()
+        return self._capture_profile(seconds, "kdlt-incident-") or {
+            "skipped": "a profile capture is already running"
+        }
 
     def shutdown(self) -> None:
         try:

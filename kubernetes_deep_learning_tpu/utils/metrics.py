@@ -84,6 +84,38 @@ def pipeline_stage_histograms(
     )
 
 
+# Where a dispatcher's wall time went, seen from the host
+# (runtime.engine.InFlightDispatcher): every instant belongs to exactly one
+# of these, so the three counters sum to the dispatcher's age.  One NAME per
+# cause, not one name with a label: consumers that sum a series over its
+# label sets would otherwise read back the age.
+PIPELINE_IDLE_CAUSES = (
+    ("inflight", "at least one batch dispatched and not yet read back"),
+    ("idle_dispatch", "nothing in flight while a submit is inside "
+     "predict_async: the device waits for the host to stage, transfer and "
+     "launch a batch"),
+    ("idle_no_batch", "nothing in flight and no submit in progress: no "
+     "batch was offered (handlers, scheduler or the client)"),
+)
+
+
+def pipeline_idle_counters(registry: "Registry", engine: str | None = None) -> dict:
+    """The dispatcher's device-idle-by-cause counters
+    (kdlt_pipeline_<cause>_seconds_total), labelled like its stage
+    histograms.  Utilisation as the host sees it is 1 - rate(the two idle
+    counters); the device finishes a batch a little before its readback
+    returns, so this reads slightly LESS idle than a device trace does."""
+    if engine:
+        registry = registry.with_labels(engine=engine)
+    return {
+        cause: registry.counter(
+            f"kdlt_pipeline_{cause}_seconds_total",
+            f"dispatcher wall seconds with {help}",
+        )
+        for cause, help in PIPELINE_IDLE_CAUSES
+    }
+
+
 # --- the bounded ``model`` label (multi-model serving) ----------------------
 #
 # Every per-model series on a shared /metrics page carries a ``model`` label
@@ -330,26 +362,6 @@ def trace_retention_metrics(registry: "Registry") -> dict:
             for cls, help in TRACE_RETENTION_CLASSES
         },
     }
-
-
-def mfu_bucket_gauge(registry: "Registry", bucket: int) -> "Gauge":
-    """Live per-bucket MFU gauge (runtime.flops.MfuAccountant); the caller's
-    registry carries the model/version labels, ``bucket`` values are the
-    engine's compiled ladder -- bounded by construction."""
-    return registry.with_labels(bucket=str(int(bucket))).gauge(
-        "kdlt_mfu_pct",
-        "live model FLOP/s utilization of the device's dense peak, per "
-        "compiled batch bucket (EWMA over dispatch->sync timings; compare "
-        "with bench.py's offline mfu_pct)",
-    )
-
-
-def device_busy_gauge(registry: "Registry") -> "Gauge":
-    return registry.gauge(
-        "kdlt_device_busy_ratio",
-        "decayed fraction of wall time the device spent executing this "
-        "engine's batches (dispatch->sync timings; ~30 s half-life)",
-    )
 
 
 def crosshost_metrics(registry: "Registry") -> dict:

@@ -35,7 +35,7 @@ import threading
 import time
 import uuid
 from collections import OrderedDict
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
 
@@ -64,6 +64,13 @@ SPAN_GATEWAY_UPSTREAM = "gateway.upstream"
 SPAN_SERVER_REQUEST = "server.request"
 SPAN_SERVER_ADMISSION = "server.admission"
 SPAN_SERVER_DECODE = "server.decode"
+# The two halves of server.decode's request parse, and what follows the
+# predict: the socket read of the body, the wire unpack (msgpack/JSON or
+# the packed-blobs frame), and response encode + send.  With these the
+# root span's self time is header parsing and bookkeeping only.
+SPAN_SERVER_READ_BODY = "server.read_body"
+SPAN_SERVER_UNPACK = "server.unpack"
+SPAN_SERVER_RESPOND = "server.respond"
 # Raw-bytes ingest wire (GUIDE 10q): the model tier's image-decode stage --
 # thread-pooled JPEG/PNG decode + resize of the blobs a bytes-wire request
 # carried.  Nested inside server.decode's request-parse span so a waterfall
@@ -101,6 +108,9 @@ SPAN_NAMES = frozenset({
     SPAN_SERVER_REQUEST,
     SPAN_SERVER_ADMISSION,
     SPAN_SERVER_DECODE,
+    SPAN_SERVER_READ_BODY,
+    SPAN_SERVER_UNPACK,
+    SPAN_SERVER_RESPOND,
     SPAN_SERVER_INGEST_DECODE,
     SPAN_SERVER_PREDICT,
     SPAN_ENGINE_PREDICT,
@@ -235,11 +245,20 @@ class Tracer:
 
     ``registry`` (optional) mints the retention accounting series
     ``kdlt_trace_{retained,dropped}_total{class=...}``.
+
+    ``annotate`` (optional) is a ``name -> context manager`` factory that
+    every LIVE span (``RequestTrace.span``) enters around its block, on the
+    thread doing the work.  The model tier passes
+    ``jax.profiler.TraceAnnotation``, which puts the span on the profiler's
+    host plane under the name /debug/trace shows (a flag check while no
+    profile is running); the gateway passes nothing and never imports jax.
     """
 
     def __init__(self, tier: str, max_traces: int = 512, max_spans: int = 128,
-                 registry: metrics_lib.Registry | None = None):
+                 registry: metrics_lib.Registry | None = None,
+                 annotate=None):
         self.tier = tier
+        self.annotate = annotate
         self.max_traces = max_traces
         self.max_spans = max_spans
         self._traces: OrderedDict[str, _TraceEntry] = OrderedDict()
@@ -405,9 +424,11 @@ class RequestTrace:
         still belongs on the waterfall.  Extra tags set on the yielded
         carrier's ``tags`` dict are merged at record time."""
         child = RequestTrace(self.tracer, self.trace_id, new_span_id(), self.span_id)
+        annotate = self.tracer.annotate
         t0 = now_s()
         try:
-            yield child
+            with annotate(name) if annotate is not None else nullcontext():
+                yield child
         finally:
             self.tracer.record(
                 self.trace_id, name, t0, now_s() - t0,

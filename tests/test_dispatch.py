@@ -267,3 +267,144 @@ def test_dispatcher_emits_stage_metrics():
         assert "kdlt_pipeline_depth 2.0" in text
     finally:
         d.close()
+
+
+# --- device-idle seconds by cause -------------------------------------------
+
+
+class TimedEngine:
+    """predict_async takes ``dispatch_s`` of host time; the "device" is a
+    serial queue that holds each batch ``device_s`` (a batch starts when
+    its dispatch returned and its predecessor finished); the sync returns
+    when the batch's device time is over."""
+
+    class _Timed:
+        def __init__(self, ready_at, out, fail):
+            self._ready_at, self._out, self._fail = ready_at, out, fail
+
+        def __array__(self, dtype=None, copy=None):
+            time.sleep(max(0.0, self._ready_at - time.perf_counter()))
+            if self._fail:
+                raise RuntimeError("device fault at sync")
+            return self._out
+
+    def __init__(self, dispatch_s, device_s, fail_dispatch_at=(), fail_sync_at=()):
+        self.dispatch_s, self.device_s = dispatch_s, device_s
+        self._fail_dispatch_at = set(fail_dispatch_at)
+        self._fail_sync_at = set(fail_sync_at)
+        self._free_at = 0.0
+        self._i = 0
+
+    def predict_async(self, images):
+        i, self._i = self._i, self._i + 1
+        time.sleep(self.dispatch_s)
+        if i in self._fail_dispatch_at:
+            raise ValueError(f"dispatch {i} rejected")
+        self._free_at = max(self._free_at, time.perf_counter()) + self.device_s
+        n = images.shape[0]
+        return self._Timed(
+            self._free_at, np.zeros((n, 2), np.float32), i in self._fail_sync_at
+        ), n
+
+
+def _serial(d, n):
+    for _ in range(n):
+        d.submit(_imgs(1)).result(timeout=5)
+
+
+def _with_gap(d, n):
+    d.submit(_imgs(1)).result(timeout=5)
+    time.sleep(0.15)
+    d.submit(_imgs(1)).result(timeout=5)
+
+
+def _overlapped(d, n):
+    for f in [d.submit(_imgs(1)) for _ in range(n)]:
+        f.result(timeout=5)
+
+
+def _tolerant(d, n):
+    for f in [d.submit(_imgs(1)) for _ in range(n)]:
+        try:
+            f.result(timeout=5)
+        except (ValueError, RuntimeError):
+            pass
+
+
+@pytest.mark.parametrize(
+    "drive, engine_kw, want",
+    [
+        # Each batch staged only after the last was read back: the device
+        # waits out every dispatch, and is held for every device time.
+        pytest.param(
+            _serial, dict(dispatch_s=0.03, device_s=0.03),
+            dict(idle_dispatch=(0.15, 0.45), inflight=(0.15, 0.45),
+                 idle_no_batch=(0.0, 0.05)),
+            id="serial-accrues-idle-dispatch",
+        ),
+        pytest.param(
+            _with_gap, dict(dispatch_s=0.01, device_s=0.02),
+            dict(idle_no_batch=(0.15, 0.40), idle_dispatch=(0.02, 0.12)),
+            id="gap-accrues-idle-no-batch",
+        ),
+        # Depth 2, dispatch shorter than the device time: after the first
+        # dispatch something is always in flight.
+        pytest.param(
+            _overlapped, dict(dispatch_s=0.01, device_s=0.05),
+            dict(idle_dispatch=(0.01, 0.06), idle_no_batch=(0.0, 0.05),
+                 inflight=(0.24, 0.60)),
+            id="overlapped-accrues-neither",
+        ),
+        pytest.param(
+            _tolerant, dict(dispatch_s=0.02, device_s=0.02, fail_dispatch_at=(1, 3)),
+            dict(idle_dispatch=(0.04, 0.30)),
+            id="failing-dispatch",
+        ),
+        pytest.param(
+            _tolerant, dict(dispatch_s=0.01, device_s=0.03, fail_sync_at=(0, 2, 4)),
+            dict(inflight=(0.12, 0.45)),
+            id="failing-sync",
+        ),
+    ],
+)
+def test_idle_seconds_by_cause(drive, engine_kw, want):
+    from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
+
+    reg = metrics_lib.Registry()
+    wall0 = time.perf_counter()
+    d = InFlightDispatcher(TimedEngine(**engine_kw), depth=2, registry=reg,
+                           watchdog=False)
+    born = d._state_since
+    try:
+        drive(d, 5)
+    finally:
+        d.close()
+    wall = time.perf_counter() - wall0
+    got = {cause: c.value for cause, c in d._m_idle.items()}
+    for cause, (lo, hi) in want.items():
+        assert lo <= got[cause] <= hi, (cause, got)
+    # The state machine ends where it began: nothing in flight, no submit
+    # inside predict_async -- also after failed dispatches and syncs.
+    assert d._submitting == 0 and not d._inflight
+    # Every instant is booked to exactly one cause: the three sum to the
+    # dispatcher's lifetime, which is the wall time the test saw.
+    assert sum(got.values()) == pytest.approx(d._state_since - born, rel=1e-6)
+    assert sum(got.values()) == pytest.approx(wall, rel=0.01, abs=0.02)
+    page = reg.render()
+    for cause in got:
+        assert f"kdlt_pipeline_{cause}_seconds_total " in page
+
+
+def test_idle_counters_advance_on_the_watchdog_tick_while_idle():
+    """An idle dispatcher's idle_no_batch must not wait for the next submit
+    to be booked: the watchdog's scan accrues it."""
+    d = InFlightDispatcher(TimedEngine(0.0, 0.0), depth=2, stall_floor_s=0.05)
+    try:
+        deadline = time.monotonic() + 5.0
+        while (d._m_idle["idle_no_batch"].value < 0.05
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        assert d._m_idle["idle_no_batch"].value >= 0.05
+        assert d._m_idle["inflight"].value == 0.0
+    finally:
+        d.close()

@@ -70,42 +70,45 @@ def test_engine_flops_per_image_from_lowered_cost_analysis(engine):
     assert flops1 == pytest.approx(flops, rel=0.2)
 
 
-def test_mfu_accountant_gauges_and_busy_ratio():
-    from kubernetes_deep_learning_tpu.runtime import flops as flops_lib
-    from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
+def test_nothing_lowers_unasked_and_the_audit_computes_on_demand(engine):
+    # The MFU accountant is gone (ISSUE 24): completions start no background
+    # lowering and mint no gauge; FLOPs/image exists only where it is asked
+    # for, on the audit page, computed once and cached.
+    import threading
 
-    registry = metrics_lib.Registry()
-    acct = flops_lib.MfuAccountant(
-        registry, peak_tf=1e-9,  # 1000 FLOP/s "device": tiny, predictable
-        flops_fn=lambda bucket: 100.0, enabled=True,
-    )
-    # First observation queues the background FLOPs estimate; wait for it,
-    # then observe again so the gauge exists with a value.
-    acct.observe(4, 4, 0.5)
-    deadline = __import__("time").monotonic() + 5.0
-    while not acct.snapshot() and __import__("time").monotonic() < deadline:
-        acct.observe(4, 4, 0.5)
-        __import__("time").sleep(0.01)
-    # 4 rows x 100 FLOP / (0.5 s x 1000 FLOP/s) = 80% MFU.
-    assert acct.snapshot()[4] == pytest.approx(80.0, abs=1.0)
-    page = registry.render()
-    assert 'kdlt_mfu_pct{bucket="4"}' in page
-    assert "kdlt_device_busy_ratio" in page
+    eng, _, _ = engine
+    eng.predict(np.zeros((2, *eng.spec.input_shape), np.uint8))
+    assert not [t for t in threading.enumerate() if t.name == "kdlt-mfu-flops"]
+    page = eng.registry.render()
+    assert "mfu" not in page and "busy_ratio" not in page
+    assert not eng._audit_flops
+    audit = eng.bucket_audit()
+    assert audit["buckets"][2]["flops_per_image"] > 0
+    assert set(eng._audit_flops) == set(eng.buckets)
+    assert eng.bucket_audit()["buckets"][2] == audit["buckets"][2]
 
 
-def test_mfu_accountant_disabled_without_peak():
-    from kubernetes_deep_learning_tpu.runtime import flops as flops_lib
-    from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
+def test_device_info_memory_block_follows_the_backend(engine, monkeypatch):
+    # GET /v1/models' device block: `memory` as the allocator reports it,
+    # absent where the backend reports none (the CPU's memory_stats() is
+    # None) -- never a made-up zero.
+    from types import SimpleNamespace
 
-    registry = metrics_lib.Registry()
-    acct = flops_lib.MfuAccountant(
-        registry, peak_tf=None, flops_fn=lambda b: 100.0
-    )
-    assert acct.enabled is False
-    acct.observe(4, 4, 0.1)  # busy accounting still runs; MFU does not
-    assert acct.snapshot() == {}
-    assert "kdlt_device_busy_ratio" in registry.render()
-    assert "kdlt_mfu_pct" not in registry.render()
+    eng, _, _ = engine
+    assert "memory" not in eng.device_info()
+    stats = {
+        "bytes_in_use": 5, "peak_bytes_in_use": 7, "peak_bytes_reserved": 11,
+        "bytes_limit": 13, "num_allocs": 99,
+    }
+    monkeypatch.setattr(eng, "_device", SimpleNamespace(
+        platform="tpu", device_kind="TPU v5 lite", memory_stats=lambda: stats,
+    ))
+    info = eng.device_info()
+    assert info["memory"] == {
+        "bytes_in_use": 5, "peak_bytes_in_use": 7, "peak_bytes_reserved": 11,
+        "bytes_limit": 13,
+    }
+    assert info["peak_tflops"] == 98.5  # float32 artifact on a v5e
 
 
 def test_input_validation(engine):

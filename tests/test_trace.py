@@ -82,6 +82,63 @@ def test_span_recorded_even_when_block_raises():
     assert [s["name"] for s in t.spans("rid")] == ["failing"]
 
 
+def test_span_enters_the_annotate_hook_with_its_name_and_exits_on_error():
+    """Live spans are profiler annotations where the tier passes a hook
+    (the model server passes jax.profiler.TraceAnnotation): entered with the
+    span's name around the block, exited even when the block raises."""
+    from contextlib import contextmanager
+
+    log = []
+
+    @contextmanager
+    def annotate(name):
+        log.append(("enter", name))
+        try:
+            yield
+        finally:
+            log.append(("exit", name))
+
+    t = trace_lib.Tracer("test", annotate=annotate)
+    rt = t.request_trace("rid")
+    with rt.span("server.decode") as dt:
+        with dt.span("server.read_body"):
+            log.append("body")
+    with pytest.raises(RuntimeError):
+        with rt.span("server.predict"):
+            raise RuntimeError("boom")
+    assert log == [
+        ("enter", "server.decode"), ("enter", "server.read_body"), "body",
+        ("exit", "server.read_body"), ("exit", "server.decode"),
+        ("enter", "server.predict"), ("exit", "server.predict"),
+    ]
+    # the waterfall is what it was without the hook
+    assert [s["name"] for s in t.spans("rid")] == [
+        "server.read_body", "server.decode", "server.predict",
+    ]
+
+
+def test_tracer_without_the_hook_calls_nothing_and_gateway_passes_none(traced_stack):
+    t = trace_lib.Tracer("test")
+    assert t.annotate is None
+    with t.request_trace("rid").span("gateway.request"):
+        pass
+    assert [s["name"] for s in t.spans("rid")] == ["gateway.request"]
+    # The tiers: the model server's live spans are TraceAnnotations; the
+    # gateway's tracer has no hook (that process never imports jax).
+    import jax
+
+    _, server, gateway, _ = traced_stack
+    assert server.tracer.annotate is jax.profiler.TraceAnnotation
+    assert gateway.tracer.annotate is None
+
+
+@pytest.mark.parametrize(
+    "name", ["server.read_body", "server.unpack", "server.respond"]
+)
+def test_front_spans_are_in_the_closed_vocabulary(name):
+    assert name in trace_lib.SPAN_NAMES
+
+
 def test_tracer_counts_dropped_spans_instead_of_silently_evicting():
     # The pre-PR-7 bug: spans past the cap vanished without a trace, so a
     # truncated waterfall read as missing instrumentation.
@@ -371,6 +428,47 @@ def test_model_tier_response_carries_trace_header(traced_stack):
     assert root["parent_id"] == "cafe0123"
 
 
+def test_msgpack_predict_waterfall_has_the_front_spans(traced_stack):
+    """server.decode divides into the socket read and the unpack; what
+    follows the predict is server.respond -- the root's self time is then
+    header parsing and bookkeeping only."""
+    from kubernetes_deep_learning_tpu.serving import protocol
+
+    spec, server, _, _ = traced_stack
+    rid = "front-spans-req"
+    r = requests.post(
+        f"http://127.0.0.1:{server.port}/v1/models/{spec.name}:predict",
+        data=protocol.encode_predict_request(np.zeros((2, 32, 32, 3), np.uint8)),
+        headers={"Content-Type": protocol.MSGPACK_CONTENT_TYPE,
+                 REQUEST_ID_HEADER: rid},
+        timeout=30,
+    )
+    assert r.status_code == 200
+    deadline = time.monotonic() + 3.0
+    while True:  # the root span records just after the response went out
+        spans = requests.get(
+            f"http://127.0.0.1:{server.port}/debug/trace/{rid}", timeout=5
+        ).json()["spans"]
+        by = {s["name"]: s for s in spans}
+        if "server.request" in by or time.monotonic() > deadline:
+            break
+        time.sleep(0.02)
+    for name in ("server.read_body", "server.unpack", "server.respond",
+                 "server.decode", "server.predict", "server.request"):
+        assert name in by, (name, sorted(by))
+    decode = by["server.decode"]
+    for child in ("server.read_body", "server.unpack"):
+        assert by[child]["parent_id"] == decode["span_id"]
+    assert (by["server.read_body"]["dur_ms"] + by["server.unpack"]["dur_ms"]
+            <= decode["dur_ms"] + 1e-3)
+    root = by["server.request"]
+    assert by["server.respond"]["parent_id"] == root["span_id"]
+    assert by["server.respond"]["start_s"] >= by["server.predict"]["start_s"]
+    children = sum(by[n]["dur_ms"] for n in (
+        "server.admission", "server.decode", "server.predict", "server.respond"))
+    assert children <= root["dur_ms"] + 1e-3
+
+
 def test_hedged_request_trace_shows_both_attempts_with_winner(
     tmp_path_factory,
 ):
@@ -446,6 +544,8 @@ def test_debug_profile_get_captures_into_profile_dir(
     assert r.status_code == 200, r.text
     out = r.json()
     assert out["seconds"] == 0.05
+    # by default the host tracer is off too: the device's planes alone
+    assert out["annotations"] is False and out["start_took_s"] >= 0
     assert out["trace_dir"].startswith(profile_dir)
     assert os.path.isdir(out["trace_dir"])
     # jax.profiler writes its plugin tree into the capture dir.
@@ -456,6 +556,62 @@ def test_debug_profile_get_captures_into_profile_dir(
         f"http://127.0.0.1:{server.port}/debug/profile?seconds=999", timeout=30
     )
     assert r.status_code == 400
+
+
+def test_debug_profile_reply_times_itself_and_the_trace_holds_the_dispatch(
+    tmp_path, monkeypatch, traced_stack
+):
+    """The capture's own cost is in the reply (start_took_s, stop_took_s),
+    and the written trace holds the dispatcher's live regions as host
+    annotations beside whatever the device planes hold."""
+    import glob
+
+    import jax
+
+    from kubernetes_deep_learning_tpu.serving import protocol
+
+    spec, server, _, _ = traced_stack
+    monkeypatch.setattr(server, "_profile_base", str(tmp_path / "profiles"))
+    reply = {}
+
+    def capture():
+        reply["r"] = requests.get(
+            f"http://127.0.0.1:{server.port}/debug/profile?seconds=0.2"
+            "&annotations=1",
+            timeout=60,
+        )
+
+    th = threading.Thread(target=capture)
+    th.start()
+    body = protocol.encode_predict_request(np.zeros((1, 32, 32, 3), np.uint8))
+    t_end = time.monotonic() + 10.0
+    while th.is_alive() and time.monotonic() < t_end:
+        r = requests.post(
+            f"http://127.0.0.1:{server.port}/v1/models/{spec.name}:predict",
+            data=body,
+            headers={"Content-Type": protocol.MSGPACK_CONTENT_TYPE},
+            timeout=30,
+        )
+        assert r.status_code == 200
+    th.join(timeout=60)
+    assert not th.is_alive()
+    assert reply["r"].status_code == 200, reply["r"].text
+    out = reply["r"].json()
+    assert out["seconds"] == 0.2 and out["annotations"] is True
+    assert out["start_took_s"] >= 0 and out["stop_took_s"] >= 0
+    files = glob.glob(out["trace_dir"] + "/plugins/profile/*/*.xplane.pb")
+    assert files, "the capture wrote no xplane file"
+    names = {
+        ev.name
+        for plane in jax.profiler.ProfileData.from_file(files[0]).planes
+        for line in plane.lines
+        for ev in line.events
+    }
+    for want in ("pipeline.dispatch", "pipeline.readback", "server.read_body",
+                 "server.predict"):
+        assert want in names, (want, sorted(names)[:40])
+    # the Python tracer is off: no frame of this file's or the server's code
+    assert not [n for n in names if n.startswith("$")]
 
 
 def test_profile_dir_env_is_honored(monkeypatch, tmp_path):
