@@ -444,6 +444,7 @@ class ModelServer:
         self._m_latency = self.registry.histogram(
             "kdlt_server_request_seconds", "request handling latency"
         )
+        self._m_unpack = metrics_lib.server_unpack_counters(self.registry)
         # Admission control (serving.admission): the model tier's front
         # door -- deadline-exhausted rejection before the TPU is touched,
         # AIMD concurrency limiting, and graceful drain.  admission=None ->
@@ -1142,11 +1143,24 @@ class ModelServer:
                                 f"server (set {protocol.INGEST_ENV}=1 or "
                                 "use the tensor wire)"
                             )
-                        with dt.span(trace_lib.SPAN_SERVER_UNPACK):
+                        with dt.span(trace_lib.SPAN_SERVER_UNPACK) as ut:
                             if encoded_wire:
                                 blobs = protocol.decode_bytes_predict_request(
                                     body, max_images=MAX_IMAGES_PER_REQUEST
                                 )
+                            elif ctype.startswith(protocol.MSGPACK_CONTENT_TYPE):
+                                # The pixels stay where rfile.read put
+                                # them: `images` is a view of `body`, which
+                                # it owns from here on (never pooled -- the
+                                # runtime stages from it after
+                                # predict_async has returned).
+                                images, zero_copy = (
+                                    protocol.decode_msgpack_tensor(body)
+                                )
+                                ut.tags["zero_copy"] = zero_copy
+                                server._m_unpack[
+                                    "view" if zero_copy else "copy"
+                                ].inc()
                             else:
                                 images = protocol.decode_predict_request(
                                     body, ctype

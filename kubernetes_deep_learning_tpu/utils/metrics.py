@@ -116,6 +116,29 @@ def pipeline_idle_counters(registry: "Registry", engine: str | None = None) -> d
     }
 
 
+# How the model server's tensor wire got its pixels out of a msgpack body
+# (serving.protocol.decode_msgpack_tensor).  The ``path`` label's value set
+# is exactly this tuple.
+SERVER_UNPACK_PATHS = (
+    ("view", "the pixels handed on as a view of the request body "
+     "(plain envelope, one-byte elements): nothing copied"),
+    ("copy", "the body unpacked by msgpack.unpackb (wider dtypes, extra "
+     "keys, anything unusual): the payload copied under the interpreter's "
+     "lock"),
+)
+
+
+def server_unpack_counters(registry: "Registry") -> dict:
+    """kdlt_server_unpack_total{path}: one count per msgpack tensor request
+    decoded in the server.unpack span."""
+    return {
+        path: registry.with_labels(path=path).counter(
+            "kdlt_server_unpack_total", f"msgpack tensor requests with {help}"
+        )
+        for path, help in SERVER_UNPACK_PATHS
+    }
+
+
 # --- the bounded ``model`` label (multi-model serving) ----------------------
 #
 # Every per-model series on a shared /metrics page carries a ``model`` label
