@@ -42,10 +42,9 @@ def model_spec(config: dict):
 
 
 def build_weights(config: dict, seed: int) -> dict:
-    from perfbench import pictures, weights
-    from perfbench.reference import FAMILIES
+    from perfbench import pictures, reference, weights
 
-    forward = FAMILIES[config["reference"]]
+    forward = reference.load(config["reference"]).forward
     flat = weights.make(weights.declare(forward, config), seed)
     cal = config["assumed"]["calibration"]
     weights.calibrate(

@@ -74,7 +74,7 @@ def main(argv: list[str] | None = None) -> int:
     import jax
     import numpy as np
 
-    from perfbench.reference import FAMILIES
+    from perfbench import reference
     from perfbench.reference.ops import Net, normalize
 
     if args.cache_dir:
@@ -91,7 +91,7 @@ def main(argv: list[str] | None = None) -> int:
                                       tuple(config["input_shape"]))
     else:
         pixels = load_pixels(args.inputs, config)
-    forward = FAMILIES[config["reference"]]
+    forward = reference.load(config["reference"]).forward
     block = int(config["assumed"]["reference_block"])
 
     @jax.jit

@@ -1,15 +1,32 @@
 """``BENCHMARK.json`` and the files it names: found by name, never by edit.
 
-A later PR adds a configuration, a traffic mix, a per-layer metric or a cell
-by adding ``configs/<name>.json``, ``traffic/<name>.json``,
-``layer_metrics/<name>.json`` (with ``readers/<reader>.py`` where the kind
-of reading is new) and an entry in ``BENCHMARK.json``.  Nothing here knows
-a name: a cell whose file is missing is an error, not a skip.
+A later PR adds a cell by adding files under ``perfbench/`` and entries in
+``BENCHMARK.json``; it edits no file that is there.  What it may add, by
+kind of file:
+
+- ``configs/<name>.json``: a configuration as it is run, with its limits;
+  it names its ``reference`` and, where the defaults do not fit, its
+  ``artifact_child`` and ``reference_child``.
+- ``reference/<config["reference"]>.py``: the plain reference of a family,
+  importing nothing of the program.
+- ``traffic/<name>.json``: the parameters of a mix; it names its ``entry``
+  and its ``generator``.
+- ``entries/<traffic["entry"]>.py``: all that a run knows of one wire of
+  the system: inputs, server arguments and checks, warm-up, drive,
+  comparison, end-to-end quantities (``perfbench/run.py`` lists them).
+- ``children/<name>.py``: a child process a configuration names to write
+  its artifact or to run its reference.
+- ``layer_metrics/<name>.json``: one per-layer metric; it names its reader.
+- ``readers/<reader>.py``: one kind of reading from spans, counters, trace.
+
+Nothing here knows a name: a cell whose file is missing is an error, not a
+skip.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
 import re
@@ -110,6 +127,9 @@ class Manifest:
                 raise ManifestError(f"bad 'better' on {m['name']}")
             if m["source"] not in SOURCES:
                 raise ManifestError(f"bad source on {m['name']}")
+            if "workloads" in m and not m["workloads"]:
+                # a metric arrives with the first cell that reports it
+                raise ManifestError(f"{m['name']} lists no cell")
             for wl in m.get("workloads", ()):
                 if wl not in self.workloads:
                     raise ManifestError(f"{m['name']} lists unknown cell {wl!r}")
@@ -135,6 +155,18 @@ class Manifest:
                 if m["moves"] not in reported:
                     raise ManifestError(
                         f"{m['name']} moves {m['moves']}, which {name} does not report")
+
+
+def load_module(bench_dir: str, kind: str, name: str):
+    """``perfbench/<kind>/<name>.py`` as a module, found by its name."""
+    path = os.path.join(bench_dir, kind, name + ".py")
+    if not NAME_RE.match(name) or not os.path.exists(path):
+        raise ManifestError(f"no perfbench/{kind}/{name}.py")
+    spec = importlib.util.spec_from_file_location(
+        "perfbench_%s_%s" % (kind, re.sub(r"\W", "_", name)), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def load_peaks(bench_dir: str, device_kind: str) -> dict:

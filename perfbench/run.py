@@ -6,31 +6,48 @@
 A parent that never imports jax (one process per chip).  It
 
 1. starts a host-only child that writes the cell's artifact from the seed
-   (``children/make_artifact.py``) and meanwhile makes the traffic's
-   pictures,
+   (the configuration's ``artifact_child``) and meanwhile makes the
+   traffic's inputs,
 2. boots the model server as the program does (``python -m
-   ...serving.model_server --platform tpu`` with the traffic's buckets and
+   ...serving.model_server --platform tpu`` with the entry's arguments and
    otherwise default flags, through ``children/serve.py``, which adds a side
-   port for ``memory_stats()``), and the gateway with default flags where
-   the traffic enters there,
-3. warms every bucket, waits for the program's per-bucket bookkeeping to
-   settle, leads in with the cell's own traffic and then measures for
-   ``--seconds``; with ``--trace 1`` a profiler trace of the serving process
-   is taken inside the window,
+   port for ``memory_stats()``), and whatever the entry puts in front of it,
+3. warms every shape, round after round until the rounds are steady, leads
+   in with the cell's own traffic and then measures for ``--seconds``; with
+   ``--trace 1`` a profiler trace of the serving process is taken inside
+   the window,
 4. stops every child (exit code 0 required), then runs the plain reference
-   on the freed chip over the run's own pictures and compares every answer
-   of the window with it,
+   (the configuration's ``reference_child``) on the freed chip over the
+   run's own inputs and compares what the window answered with it,
 5. prints the contract's line.
 
 It fails -- no fallback, no result line -- if the server reports another
-platform than asked, a degraded fused path, a compile request inside the
-window, or a ``device_kind`` that ``peaks.json`` does not hold.
+platform than asked, a status page the entry refuses, a compile request
+inside the window, or a ``device_kind`` that ``peaks.json`` does not hold.
+
+What a run knows of one wire of the system -- a modality -- sits in
+``entries/<traffic["entry"]>.py``, found by that name.  An entry is a
+module with, and this file asks it for nothing else:
+
+- ``GENERATORS``: the traffic file's ``generator`` values it takes;
+- ``make_inputs(run)``: the inputs from the seed;
+- ``server_args(run)`` -> (arguments, environment) beyond the common ones,
+  and ``check_status(run, page, at_boot)`` for ``GET /v1/models``;
+- ``boot_front(run)`` / ``stop_front(run)``: the tiers before the server;
+- ``warming(run)``: a context manager that yields ``one_round(k)``;
+- ``drive(run, on_window_start)``: lead-in and window; leaves
+  ``run.outcomes``, ``run.t_zero`` and ``run.window``;
+- ``reference_args(run)`` (called once the window has closed) and
+  ``REFERENCE_OUT``, the name of the file the reference child writes;
+- ``compare(run)`` -> (the ``compared`` block, each number beside its
+  limit, and the indices of the outcomes that are good);
+- ``QUANTITIES`` {name: unit} and ``quantities(run)`` -> {name: value}: the
+  end-to-end quantities it can give.
 """
 
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import math
 import os
@@ -46,22 +63,16 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
 from perfbench import manifest as manifest_lib  # noqa: E402
-from perfbench import pictures, procs, traffic  # noqa: E402
+from perfbench import procs  # noqa: E402
 from perfbench.procs import RunFailure  # noqa: E402
 
-PACKAGE = "kubernetes_deep_learning_tpu"
+PACKAGE = "kubernetes_deep_learning_tpu"   # the program under test
 TIME_LIMIT_S = 1150.0     # a first run may take 1200 s, compilation included
 FAILED_LATENCY_MS = 120_000.0   # a request with no answer, in a percentile
 
 
 def load_reader(bench_dir: str, name: str):
-    path = os.path.join(bench_dir, "readers", name + ".py")
-    if not os.path.exists(path):
-        raise manifest_lib.ManifestError(f"no reader perfbench/readers/{name}.py")
-    spec = importlib.util.spec_from_file_location(f"perfbench_reader_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    return manifest_lib.load_module(bench_dir, "readers", name)
 
 
 def percentile(sorted_values: list[float], pct: float) -> float:
@@ -72,6 +83,9 @@ def percentile(sorted_values: list[float], pct: float) -> float:
 
 class CellRun:
     """One run: its children, directories and what it gathered."""
+
+    FAILED_LATENCY_MS = FAILED_LATENCY_MS
+    percentile = staticmethod(percentile)
 
     def __init__(self, manifest, cell, seed: int, seconds: float, trace: bool,
                  platform: str = "tpu", work_root: str | None = None):
@@ -93,20 +107,25 @@ class CellRun:
         self.children = procs.Children(self.root, os.path.join(self.work, "logs"),
                                        env, TIME_LIMIT_S)
         self.config, self.mix = cell.config, cell.traffic
+        self.entry = manifest_lib.load_module(self.bench_dir, "entries", self.mix["entry"])
+        if self.mix["generator"] not in self.entry.GENERATORS:
+            raise manifest_lib.ManifestError(
+                f"entry {self.mix['entry']!r} takes the generators "
+                f"{self.entry.GENERATORS}, not {self.mix['generator']!r}")
         self.model = self.config["served_name"]
         self.compile_cache = (os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
                               or os.path.join(self.root, ".jax_cache"))
-        self.server = self.gateway = self.image_host = None
-        self.server_proc = self.gateway_proc = self.host_proc = None
+        self.server = self.server_proc = self.host_proc = None
+        self.tiers: dict[str, str] = {}   # tier name -> base URL, for /metrics and spans
 
     def child_script(self, name: str) -> str:
-        return os.path.join(HERE, "children", name)
+        return os.path.join(self.bench_dir, "children", name)
 
     # --- set-up ------------------------------------------------------------
 
     def start_artifact(self):
         return self.children.spawn("artifact", [
-            self.child_script("make_artifact.py"),
+            self.child_script(self.config.get("artifact_child", "make_artifact.py")),
             "--config", self.config_path(), "--seed", str(self.seed),
             "--out", os.path.join(self.work, "models"),
             "--module-cache", os.path.join(self.cache_root, "modules"),
@@ -117,124 +136,68 @@ class CellRun:
         return os.path.join(self.manifest.root,
                             self.manifest.configs[self.cell.config_name]["file"])
 
-    def make_inputs(self) -> None:
-        """The traffic's pictures, and what the reference will read."""
-        mix, shape = self.mix, tuple(self.config["input_shape"])
-        if mix["entry"] == "gateway-url":
-            pool_dir = os.path.join(self.work, "pool")
-            os.makedirs(pool_dir)
-            pool = pictures.encoded_pool(self.seed, mix["pictures"])
-            for i, (fmt, data) in enumerate(pool):
-                with open(os.path.join(pool_dir, f"{i:04d}.{fmt}"), "wb") as f:
-                    f.write(data)
-            self.pool_size = len(pool)
-            self.reference_inputs = ["--inputs", pool_dir]
-            port = procs.free_port()
-            self.host_proc = self.children.spawn(
-                "image_host", [self.child_script("image_host.py"), pool_dir, str(port)],
-                env=self.host_env)
-            self.image_host = f"http://127.0.0.1:{port}"
-        elif mix["entry"] == "server-tensor":
-            n, per = int(mix["pool"]), int(mix["images_per_request"])
-            pool = pictures.tensor_pool(self.seed, n, shape)
-            self.pool_size = n
-            self.reference_inputs = ["--tensor-pool", str(n)]
-            rows = traffic.balanced_rows(self.seed, n, per * int(mix["bodies"]))
-            self.body_rows = [tuple(int(r) for r in rows[i * per:(i + 1) * per])
-                              for i in range(int(mix["bodies"]))]
-            self.bodies = [traffic.encode_tensor_body(pool[list(r)]) for r in self.body_rows]
-        else:
-            raise manifest_lib.ManifestError(f"unknown entry {mix['entry']!r}")
-
     def boot_server(self) -> dict:
         port, side = procs.free_port(), procs.free_port()
-        buckets = ",".join(str(b) for b in self.mix["server_buckets"])
+        entry_argv, entry_env = self.entry.server_args(self)
         self.server_proc = self.children.spawn("server", [
             self.child_script("serve.py"), "--models", os.path.join(self.work, "models"),
-            "--port", str(port), "--buckets", buckets, "--platform", self.platform,
+            "--port", str(port), *entry_argv, "--platform", self.platform,
             "--profile-dir", os.path.join(self.work, "program-traces"),
-        ], env=dict(self.env, PERFBENCH_DEVICE_PORT=str(side),
+        ], env=dict(self.env, **entry_env, PERFBENCH_DEVICE_PORT=str(side),
                     PERFBENCH_TRACE_DIR=os.path.join(self.work, "traces")))
         self.server, self.side = f"http://127.0.0.1:{port}", f"http://127.0.0.1:{side}"
+        self.tiers["server"] = self.server
         body = procs.wait_ready(self.children, "server", self.server_proc, self.server)
         if body.strip() != "ready":
             raise RunFailure(f"/readyz says {body!r}, not 'ready'")
-        st = procs.get_json(self.server, "/v1/models")[self.model]
+        page = procs.get_json(self.server, "/v1/models")
         device = procs.get_json(self.side, "/device")
-        if st["platform"] != self.platform or device["platform"] != self.platform:
-            raise RunFailure(f"server runs on {st['platform']!r}, not {self.platform!r}")
+        if device["platform"] != self.platform:
+            raise RunFailure(f"server runs on {device['platform']!r}, not {self.platform!r}")
         if device["count"] < self.cell.chips:
             raise RunFailure(f"{device['count']} devices, the cell needs {self.cell.chips}")
-        if st["fast_degraded"]:
-            raise RunFailure("the fused path degraded at warm-up")
-        if bool(st["fast_engaged"]) != bool(self.config["fast_path"]):
-            raise RunFailure(f"fast_engaged is {st['fast_engaged']}, the configuration "
-                             f"states fast_path {self.config['fast_path']}")
-        if list(st["buckets"]) != list(self.mix["server_buckets"]):
-            raise RunFailure(f"server buckets {st['buckets']} != {self.mix['server_buckets']}")
+        self.entry.check_status(self, page, True)
         if self.platform == "tpu":
             self.peaks = manifest_lib.load_peaks(self.bench_dir, device["kind"])
         else:  # a rehearsal has no peak; device metrics are not reported
             self.peaks = None
-        self.labels = list(st["labels"])
-        return st
-
-    def boot_gateway(self) -> None:
-        port = procs.free_port()
-        self.gateway_proc = self.children.spawn("gateway", [
-            "-m", f"{PACKAGE}.serving.gateway", "--serving-host",
-            self.server.split("//")[1], "--port", str(port), "--model", self.model,
-        ], env=self.host_env)
-        self.gateway = f"http://127.0.0.1:{port}"
-        procs.wait_ready(self.children, "gateway", self.gateway_proc, self.gateway)
+        return page.get(self.model)
 
     def warm(self) -> None:
-        """Each bucket dispatched through the live path, round after round,
-        for ``min_seconds`` at the least and until a round takes no longer
-        than the quickest so far: whatever the program does at a bucket's
-        first dispatches (the MFU accountant lowers the whole graph on a
-        background thread, 4-5 s a bucket, one bucket after another) has
-        then run its course.  No rule names a part of the program."""
-        import numpy as np
-
+        """Every shape the traffic uses, dispatched through the live path
+        round after round (a round is the entry's), for ``min_seconds`` at
+        the least and until ``steady_rounds`` rounds in a row take no longer
+        than ``steady_within`` times the quickest so far.  A shape's first
+        dispatches run slower than its later ones -- programs loaded from
+        the compile cache, buffers allocated for the first time, whatever
+        the program still does lazily -- and the window has to start on
+        the steady rate; a boot that never settles is reported and goes on
+        after ``settle_timeout_s``.  No rule names a part of the program."""
         warm = self.mix["warm"]
-        shape = tuple(self.config["input_shape"])
-        buckets = [int(b) for b in self.mix["server_buckets"]]
-        entry = traffic.ServerTensor(self.server, self.model, [
-            traffic.encode_tensor_body(np.zeros((b, *shape), np.uint8)) for b in buckets])
         deadline = time.monotonic() + float(warm["settle_timeout_s"])
         times: list[float] = []
-        conn_box = [None]
-        while True:
-            t = time.monotonic()
-            for i in range(len(buckets)):
-                o = traffic.Outcome(i, f"pbwarm{self.seed}-{len(times)}-{i}", 0.0, ())
-                entry.send(conn_box, o, 120.0, i)
-                if o.status != 200:
-                    raise RunFailure(f"warm-up of bucket {buckets[i]} -> {o.status}: {o.error}")
-            times.append(time.monotonic() - t)
-            steady = int(warm["steady_rounds"])
-            if (sum(times) >= float(warm["min_seconds"]) and len(times) >= steady
-                    and max(times[-steady:]) <= float(warm["steady_within"]) * min(times)):
-                break
-            if time.monotonic() > deadline:
-                print(f"warm-up: rounds took {[round(x, 3) for x in times]} s and did not "
-                      "settle; going on", file=sys.stderr)
-                break
-        if conn_box[0] is not None:
-            conn_box[0].close()
+        with self.entry.warming(self) as one_round:
+            while True:
+                t = time.monotonic()
+                one_round(len(times))
+                times.append(time.monotonic() - t)
+                steady = int(warm["steady_rounds"])
+                if (sum(times) >= float(warm["min_seconds"]) and len(times) >= steady
+                        and max(times[-steady:]) <= float(warm["steady_within"]) * min(times)):
+                    break
+                if time.monotonic() > deadline:
+                    print(f"warm-up: rounds took {[round(x, 3) for x in times]} s and did "
+                          "not settle; going on", file=sys.stderr)
+                    break
         self.warm_rounds = times
 
     # --- the window ----------------------------------------------------------
 
     def snapshot(self) -> dict:
-        out = {"server": procs.parse_metrics(procs.scrape(self.server))}
-        if self.gateway:
-            out["gateway"] = procs.parse_metrics(procs.scrape(self.gateway))
-        return out
+        return {tier: procs.parse_metrics(procs.scrape(base))
+                for tier, base in self.tiers.items()}
 
     def drive(self) -> None:
-        mix = self.mix
         self.before = self.trace_reply = self.trace_error = None
         threads = []
 
@@ -254,26 +217,12 @@ class CellRun:
             for t in threads:
                 t.start()
 
-        lead = float(mix["lead_in_s"])
-        if mix["generator"] == "open-poisson":
-            entry = traffic.GatewayUrl(self.gateway, self.image_host, self.labels, self.seed)
-            self.outcomes, self.t_zero = traffic.run_open(
-                entry, mix, self.seed, lead, self.seconds, self.pool_size, on_window_start)
-        elif mix["generator"] == "closed":
-            entry = traffic.ServerTensor(self.server, self.model, self.bodies)
-            self.outcomes, self.t_zero = traffic.run_closed(
-                entry, mix, self.seed, lead, self.seconds, self.body_rows, on_window_start)
-        else:
-            raise manifest_lib.ManifestError(f"unknown generator {mix['generator']!r}")
+        self.entry.drive(self, on_window_start)
         for t in threads:
             t.join(timeout=180)
         if self.trace_error is not None or (self.trace and self.trace_reply is None):
             raise RunFailure(f"no profiler trace: {self.trace_error!r}")
         self.after = self.snapshot()
-        # open loop: the requests due in the window; closed loop: those
-        # answered after its start, whenever they were sent
-        closed = mix["generator"] == "closed"
-        self.window = [o for o in self.outcomes if (o.done_s if closed else o.due_s) >= 0]
 
     def take_trace(self, t_zero: float) -> None:
         offset, seconds = float(self.mix["trace_offset_s"]), float(self.mix["trace_seconds"])
@@ -294,9 +243,7 @@ class CellRun:
         out = []
         for i in picks:
             spans = []
-            for tier in (self.gateway, self.server):
-                if tier is None:
-                    continue
+            for tier in reversed(self.tiers.values()):   # the front first
                 status, body = procs.http_get(tier, f"/debug/trace/{recent[i].rid}")
                 if status == 200:
                     spans += json.loads(body).get("spans", [])
@@ -311,31 +258,25 @@ class CellRun:
             raise RunFailure(f"{compiles:.0f} compile requests after warm-up")
         self.spans = self.sample_spans() if self.trace else []
         self.device = procs.get_json(self.side, "/device")
-        st = procs.get_json(self.server, "/v1/models")[self.model]
-        if st["fast_degraded"]:
-            raise RunFailure("the fused path degraded during the window")
+        self.entry.check_status(self, procs.get_json(self.server, "/v1/models"), False)
 
     def stop_servers(self) -> None:
-        if self.gateway_proc is not None:
-            self.children.stop("gateway", self.gateway_proc)
+        self.entry.stop_front(self)
         self.children.stop("server", self.server_proc)
-        if self.host_proc is not None:
-            self.children.stop("image_host", self.host_proc)
 
     # --- after the window: reference and trace -------------------------------------
 
     def reference_and_trace(self) -> None:
-        ref_out = os.path.join(self.work, "reference.npy")
-        argv = [self.child_script("reference.py"), "--config", self.config_path(),
-                "--params", os.path.join(self.work, "models", self.model, "1", "params.msgpack"),
-                "--seed", str(self.seed), "--out", ref_out,
-                "--cache-dir", self.compile_cache, *self.reference_inputs]
+        ref_out = os.path.join(self.work, self.entry.REFERENCE_OUT)
+        argv = [self.child_script(self.config.get("reference_child", "reference.py")),
+                "--config", self.config_path(), "--seed", str(self.seed), "--out", ref_out,
+                "--cache-dir", self.compile_cache, *self.entry.reference_args(self)]
         ref = self.children.spawn("reference", argv)
         reducer = None
         if self.trace:
             reduced = os.path.join(self.work, "trace.json")
             reducer = self.children.spawn("reduce_trace", [
-                os.path.join(HERE, "reduce_trace.py"), self.trace_reply["trace_dir"],
+                os.path.join(self.bench_dir, "reduce_trace.py"), self.trace_reply["trace_dir"],
                 "--out", reduced], env=self.host_env)
         self.children.wait_exit("reference", ref)
         import numpy as np
@@ -350,46 +291,14 @@ class CellRun:
     # --- the verdict and the line ---------------------------------------------------
 
     def compare(self) -> dict:
-        """Every answer of the window against the reference's row for the
-        same picture.  Returns {name: {"value", "limit"}}."""
-        import numpy as np
-
-        ref = self.reference
-        scale = float(np.abs(ref).max())
-        worst, rows, wrong, unanswered = 0.0, 0, 0, 0
-        self.good = set()
-        for o in self.window:
-            if o.status == 0:
-                unanswered += 1
-                continue
-            if o.status != 200:
-                continue            # refused or shed: failed, not wrong
-            want = ref[list(o.rows)]
-            if o.scores is None or o.scores.shape != want.shape \
-                    or not np.isfinite(o.scores).all():
-                wrong += 1
-                continue
-            err = float(np.abs(o.scores.astype(np.float64) - want).max()) / scale
-            worst, rows = max(worst, err), rows + len(o.rows)
-            if err <= self.limits["logit_err"]:
-                self.good.add(o.index)
-        return {
-            "logit_err": {"value": worst, "limit": self.limits["logit_err"]},
-            "wrong_answers": {"value": wrong, "limit": 0},
-            "unanswered": {"value": unanswered, "limit": 0},
-            "rows_compared": {"value": rows, "limit_at_least": 1},
-        }
+        """What the window answered against the reference, by the entry's
+        rule.  Returns {name: {"value", "limit" or "limit_at_least"}}."""
+        compared, self.good = self.entry.compare(self)
+        return compared
 
     def end_to_end(self) -> dict:
-        w, s = self.window, self.seconds
-        values = {"setup_s": self.setup_s}
-        images = sum(len(o.rows) for o in w if o.index in self.good and o.done_s <= s)
-        values["images_per_s"] = images / s
-        lat = sorted((1000.0 * (o.done_s - o.due_s) if o.index in self.good
-                      else FAILED_LATENCY_MS) for o in w)
-        if lat:
-            values["latency_p50_ms"] = percentile(lat, 50)
-            values["latency_p95_ms"] = percentile(lat, 95)
+        values = dict(self.entry.quantities(self), setup_s=self.setup_s)
+        units = dict(self.entry.QUANTITIES, setup_s="s")
         out = {}
         for m in self.cell.end_to_end:
             # which quantity: the name up to its first dot; what follows
@@ -397,7 +306,11 @@ class CellRun:
             kind = m["name"].split(".")[0]
             if kind not in values:
                 raise manifest_lib.ManifestError(
-                    f"the harness computes no end-to-end metric {m['name']!r}")
+                    f"entry {self.mix['entry']!r} gives no end-to-end quantity "
+                    f"for {m['name']!r}")
+            if m["unit"] != units[kind]:
+                raise manifest_lib.ManifestError(
+                    f"{m['name']} is in {units[kind]}, not {m['unit']}")
             out[m["name"]] = {"value": values[kind], "unit": m["unit"]}
         return out
 
@@ -420,10 +333,10 @@ class CellRun:
         print(f"[{time.monotonic() - T0:7.1f}s] {phase}", file=sys.stderr, flush=True)
 
     def prepare(self) -> None:
-        """The artifact from the seed (a child) and, beside it, the pictures."""
+        """The artifact from the seed (a child) and, beside it, the inputs."""
         self.limits = self.config["limits"]
         artifact = self.start_artifact()
-        self.make_inputs()
+        self.entry.make_inputs(self)
         self.mark("inputs made")
         self.children.wait_exit("artifact", artifact)
         self.mark("artifact written")
@@ -432,8 +345,7 @@ class CellRun:
         """Servers up and warm; from here a compile request is a failure."""
         self.boot_server()
         self.mark("server ready")
-        if self.mix["entry"] == "gateway-url":
-            self.boot_gateway()
+        self.entry.boot_front(self)
         self.warm()
         self.mark(f"warm: rounds of {[round(t, 3) for t in self.warm_rounds]} s")
         self.compiles_at_ready = procs.parse_metrics(procs.scrape(self.server)).get(
@@ -450,10 +362,8 @@ class CellRun:
         self.reference_and_trace()
         self.mark("reference and trace read")
         compared = self.compare()
-        correct = (compared["logit_err"]["value"] <= compared["logit_err"]["limit"]
-                   and compared["wrong_answers"]["value"] == 0
-                   and compared["unanswered"]["value"] == 0
-                   and compared["rows_compared"]["value"] >= 1)
+        correct = all(c["value"] <= c["limit"] if "limit" in c
+                      else c["value"] >= c["limit_at_least"] for c in compared.values())
         metrics = self.end_to_end()   # computed in both kinds of run, printed in one
         device = {"platform": self.device["platform"], "kind": self.device["kind"],
                   "count": self.device["count"],
@@ -498,10 +408,10 @@ def main(argv: list[str] | None = None) -> int:
     try:
         manifest = manifest_lib.Manifest(ROOT)
         cell = manifest.cell(args.workload)
+        run = CellRun(manifest, cell, args.seed, args.seconds, bool(args.trace))
     except manifest_lib.ManifestError as e:
         print(f"perfbench: {e}", file=sys.stderr)
         return 2
-    run = CellRun(manifest, cell, args.seed, args.seconds, bool(args.trace))
     try:
         line = run.run()
     except (RunFailure, manifest_lib.ManifestError) as e:

@@ -9,8 +9,7 @@ import numpy as np
 import pytest
 
 from perfbench import manifest as M
-from perfbench import pictures, weights
-from perfbench.reference import FAMILIES
+from perfbench import pictures, reference, weights
 from perfbench.reference.ops import Net, normalize
 
 
@@ -36,7 +35,7 @@ def test_reference_agrees_with_the_program(family):
     from perfbench.children.make_artifact import model_spec
 
     config = CASES[family]()
-    forward = FAMILIES[config["reference"]]
+    forward = reference.load(config["reference"]).forward
     flat = weights.make(weights.declare(forward, config), 11)
     weights.calibrate(forward, config, flat, pictures.calibration_pixels(11, 8, 96))
     tree = weights.nest(flat)
