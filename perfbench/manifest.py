@@ -21,6 +21,17 @@ kind of file:
 
 Nothing here knows a name: a cell whose file is missing is an error, not a
 skip.
+
+Which PR may add what.  A PR that changes the program may add files under
+``perfbench/`` and entries at the ends of ``configs``, ``workloads`` and
+``per_layer``, and nothing else: an ``end_to_end`` entry can refuse a PR,
+so it is a ``benchmark`` PR's to add, with a bound from measured runs.  An
+end-to-end metric without a ``workloads`` list is reported by every cell
+there is and every cell to come (``Manifest.reports``): ``setup_s`` and
+``latency_p50_ms``, which every entry has to give.  So the first cell of a
+new wire arrives in a program PR reporting those two, its per-layer
+metrics moving ``latency_p50_ms``; the wire's own end-to-end metrics come
+in the next ``benchmark`` PR, listing the cell that then exists.
 """
 
 from __future__ import annotations
@@ -106,6 +117,28 @@ class Manifest:
         return Cell(name, int(w["chips"]), w["config"], config, w["traffic"],
                     traffic, e2e, layer)
 
+    def entry(self, cell: Cell):
+        """``entries/<traffic["entry"]>.py`` of a cell, held to what the cell
+        asks of it before anything runs: its generator, and every end-to-end
+        metric the cell reports, by quantity (the name up to its first dot;
+        what follows only tells cells apart that are held to different
+        bounds) and unit."""
+        mix = cell.traffic
+        entry = load_module(self.bench_dir, "entries", mix["entry"])
+        if mix["generator"] not in entry.GENERATORS:
+            raise ManifestError(f"entry {mix['entry']!r} takes the generators "
+                                f"{entry.GENERATORS}, not {mix['generator']!r}")
+        units = dict(entry.QUANTITIES, setup_s="s")
+        for m in cell.end_to_end:
+            kind = m["name"].split(".")[0]
+            if kind not in units:
+                raise ManifestError(
+                    f"entry {mix['entry']!r} gives no end-to-end quantity for "
+                    f"{m['name']!r}, which {cell.name} reports")
+            if m["unit"] != units[kind]:
+                raise ManifestError(f"{m['name']} is in {units[kind]}, not {m['unit']}")
+        return entry
+
     def validate(self) -> None:
         """The contract's rules that a file can be checked against here."""
         d = self.data
@@ -146,6 +179,7 @@ class Manifest:
             raise ManifestError(f"configs unused or unknown: {used ^ set(self.configs)}")
         for name in self.workloads:
             cell = self.cell(name)  # every file resolves
+            self.entry(cell)        # and its entry gives what the cell reports
             reported = {m["name"] for m in cell.end_to_end}
             if "setup_s" not in reported or len(reported) < 2:
                 raise ManifestError(f"{name} reports too few end-to-end metrics")

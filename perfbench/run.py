@@ -42,7 +42,9 @@ module with, and this file asks it for nothing else:
 - ``compare(run)`` -> (the ``compared`` block, each number beside its
   limit, and the indices of the outcomes that are good);
 - ``QUANTITIES`` {name: unit} and ``quantities(run)`` -> {name: value}: the
-  end-to-end quantities it can give.
+  end-to-end quantities it can give, ``latency_p50_ms`` among them: a
+  request's time from its send (closed loop) or its due time (open loop) to
+  its last byte, which every cell reports (``manifest.py``).
 """
 
 from __future__ import annotations
@@ -107,11 +109,7 @@ class CellRun:
         self.children = procs.Children(self.root, os.path.join(self.work, "logs"),
                                        env, TIME_LIMIT_S)
         self.config, self.mix = cell.config, cell.traffic
-        self.entry = manifest_lib.load_module(self.bench_dir, "entries", self.mix["entry"])
-        if self.mix["generator"] not in self.entry.GENERATORS:
-            raise manifest_lib.ManifestError(
-                f"entry {self.mix['entry']!r} takes the generators "
-                f"{self.entry.GENERATORS}, not {self.mix['generator']!r}")
+        self.entry = manifest.entry(cell)
         self.model = self.config["served_name"]
         self.compile_cache = (os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
                               or os.path.join(self.root, ".jax_cache"))
@@ -298,19 +296,11 @@ class CellRun:
 
     def end_to_end(self) -> dict:
         values = dict(self.entry.quantities(self), setup_s=self.setup_s)
-        units = dict(self.entry.QUANTITIES, setup_s="s")
         out = {}
         for m in self.cell.end_to_end:
-            # which quantity: the name up to its first dot; what follows
-            # only tells cells apart that are held to different bounds
-            kind = m["name"].split(".")[0]
-            if kind not in values:
-                raise manifest_lib.ManifestError(
-                    f"entry {self.mix['entry']!r} gives no end-to-end quantity "
-                    f"for {m['name']!r}")
-            if m["unit"] != units[kind]:
-                raise manifest_lib.ManifestError(
-                    f"{m['name']} is in {units[kind]}, not {m['unit']}")
+            kind = m["name"].split(".")[0]   # Manifest.entry checked it and its unit
+            if kind not in values:           # a window that answered nothing
+                raise RunFailure(f"the window gives no value for {m['name']!r}")
             out[m["name"]] = {"value": values[kind], "unit": m["unit"]}
         return out
 

@@ -1,15 +1,26 @@
 #!/usr/bin/env python3
-"""A scratch manifest around the stand-in lane: a copy of ``perfbench/``
-with ``lane_server.py`` in ``children/serve.py``'s place, and -- as a later
-PR adds a cell -- the family's file, its artifact child, a configuration,
-two traffic files (a closed loop and Poisson arrivals), per-layer data
-files and the entries of a ``BENCHMARK.json`` that is in no committed file.
-``tests/test_generate_entry.py`` rehearses whole runs from it on the CPU;
-run as a command it makes one run on the device that JAX finds, the token
-entry's proof of plumbing on the real host (PERF.md, section 6, PR 26):
+"""A scratch manifest around the stand-in lane, made as a later PR adds a
+cell: a copy of ``perfbench/`` gains files alone -- the family's reference,
+its artifact child, the lane itself (``children/lane_server.py``), a
+configuration, two traffic files (a closed loop and Poisson arrivals),
+per-layer data files -- and a ``BENCHMARK.json`` that is in no committed
+file.  Two manifests:
+
+- ``build(root)``: the token entry's own, all seven of its quantities as
+  end-to-end metrics (``tests/test_generate_entry.py``);
+- ``build(root, appended=True)``: the committed ``BENCHMARK.json`` with the
+  stand-in's configuration, cells and per-layer metrics appended at the ends
+  of their lists and ``end_to_end`` untouched, as a program PR may: the
+  token cells report ``setup_s`` and ``latency_p50_ms``, which list no
+  cells (``tests/test_extend.py``).
+
+The one thing no file of the benchmark can stand in for is the program, so
+``LaneRun`` starts the lane where a run starts ``children/serve.py``.  Run
+as a command this makes one run on the device that JAX finds, the proof of
+plumbing on the real host (PERF.md, section 6, PRs 26 and 28):
 
     python3 perfbench/tests/standin/build.py --root .scratch/standin \
-        --workload standin-closed --seed 5 --seconds 20 --trace 1
+        --appended --workload standin-closed --seed 5 --seconds 20 --trace 1
 """
 
 from __future__ import annotations
@@ -23,6 +34,9 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, os.path.dirname(BENCH))
+
+from perfbench import manifest as manifest_lib  # noqa: E402
+from perfbench import run as run_lib  # noqa: E402
 
 CONFIG = {
     "name": "standin-lm", "served_name": "gen-default", "reference": "byte_lm",
@@ -49,49 +63,65 @@ E2E = {"output_tokens_per_s": ("tokens/s", "higher"), "ttft_p50_ms": ("ms", "low
        "ttft_p95_ms": ("ms", "lower"), "itl_p50_ms": ("ms", "lower"),
        "itl_p95_ms": ("ms", "lower"), "latency_p50_ms": ("ms", "lower"),
        "latency_p95_ms": ("ms", "lower")}
-LAYER = {
-    "lane_tokens_per_s": {"reader": "metrics_delta", "scale": 1.0,
-                          "num": [["server", "lane_tokens_total", 1]],
-                          "den": [["server", "lane_seconds_total", 1]]},
-    "device_idle_pct.standin": {"reader": "trace_busy", "value": "idle_pct"},
+LAYER = {   # name -> unit, better, source, the data file
+    "lane_tokens_per_s": ("tokens/s", "higher", "program_counter", {
+        "reader": "metrics_delta", "scale": 1.0,
+        "num": [["server", "lane_tokens_total", 1]],
+        "den": [["server", "lane_seconds_total", 1]]}),
+    "device_idle_pct.standin": ("%", "lower", "device_trace", {
+        "reader": "trace_busy", "value": "idle_pct"}),
 }
 
 
-def build(root: str) -> None:
-    """The scratch tree under ``root``."""
+class LaneRun(run_lib.CellRun):
+    """A run whose server child is the stand-in lane, in the program's place."""
+
+    def child_script(self, name: str) -> str:
+        return super().child_script("lane_server.py" if name == "serve.py" else name)
+
+
+def entries(moves: str) -> dict:
+    """The stand-in's entries of a ``BENCHMARK.json``, list by list."""
+    return {
+        "configs": [{"name": CONFIG["name"], "source": "perfbench/tests/standin", "reduced": [],
+                     "file": f"perfbench/configs/{CONFIG['name']}.json", "why": "a toy"}],
+        "workloads": [{"name": n, "config": CONFIG["name"], "traffic": n, "chips": 1,
+                       "why": "the token entry's plumbing"} for n in MIXES],
+        "per_layer": [{"name": n, "unit": u, "better": b, "source": s, "layer": "the lane",
+                       "moves": moves, "workloads": list(MIXES)}
+                      for n, (u, b, s, _spec) in LAYER.items()],
+    }
+
+
+def build(root: str, appended: bool = False) -> None:
+    """The scratch tree under ``root``: files added, none edited."""
     bench = os.path.join(root, "perfbench")
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns("__pycache__", "tests"))
     children = os.path.join(bench, "children")
-    os.rename(os.path.join(children, "serve.py"), os.path.join(children, "serve_side.py"))
-    shutil.copy(os.path.join(HERE, "lane_server.py"), os.path.join(children, "serve.py"))
+    shutil.copy(os.path.join(HERE, "lane_server.py"), children)
     shutil.copy(os.path.join(HERE, "lane_artifact.py"), children)
     shutil.copy(os.path.join(HERE, "byte_lm.py"), os.path.join(bench, "reference"))
-    config, mixes = CONFIG, MIXES
-    with open(os.path.join(bench, "configs", config["name"] + ".json"), "w") as f:
-        json.dump(config, f)
-    for name, mix in mixes.items():
+    with open(os.path.join(bench, "configs", CONFIG["name"] + ".json"), "w") as f:
+        json.dump(CONFIG, f)
+    for name, mix in MIXES.items():
         with open(os.path.join(bench, "traffic", name + ".json"), "w") as f:
             json.dump(mix, f)
-    for name, spec in LAYER.items():
+    for name, (*_entry, spec) in LAYER.items():
         with open(os.path.join(bench, "layer_metrics", name + ".json"), "w") as f:
             json.dump(spec, f)
-    manifest = {
-        "command": ["python3", "perfbench/run.py"], "paths": ["perfbench"], "run_seconds": 20,
-        "configs": [{"name": config["name"], "source": "perfbench/tests/standin", "reduced": [],
-                     "file": f"perfbench/configs/{config['name']}.json", "why": "a toy"}],
-        "workloads": [{"name": n, "config": config["name"], "traffic": n, "chips": 1,
-                       "why": "the token entry's plumbing"} for n in mixes],
-        "end_to_end": [{"name": n, "unit": u, "better": b, "bound": 0.1, "source": "host_clock"}
-                       for n, (u, b) in E2E.items()]
-        + [{"name": "setup_s", "unit": "s", "better": "lower", "bound": 0.1,
-            "source": "host_clock"}],
-        "per_layer": [{"name": n, "unit": u, "better": b, "source": s, "layer": "the lane",
-                       "moves": "output_tokens_per_s"}
-                      for n, u, b, s in (("lane_tokens_per_s", "tokens/s", "higher",
-                                          "program_counter"),
-                                         ("device_idle_pct.standin", "%", "lower",
-                                          "device_trace"))],
-    }
+    if appended:
+        with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+        for key, new in entries("latency_p50_ms").items():
+            manifest[key] += new
+    else:
+        manifest = {
+            "command": ["python3", "perfbench/run.py"], "paths": ["perfbench"],
+            "run_seconds": 20, **entries("output_tokens_per_s"),
+            "end_to_end": [{"name": n, "unit": u, "better": b, "bound": 0.1,
+                            "source": "host_clock"}
+                           for n, (u, b) in dict(E2E, setup_s=("s", "lower")).items()],
+        }
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(manifest, f, indent=1)
 
@@ -99,22 +129,20 @@ def build(root: str) -> None:
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--root", required=True)
+    p.add_argument("--appended", action="store_true")
     p.add_argument("--workload", required=True)
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, default=20.0)
     p.add_argument("--trace", type=int, default=0)
     p.add_argument("--platform", default="tpu")
     args = p.parse_args(argv)
-    from perfbench import manifest as manifest_lib
-    from perfbench import run as run_lib
-
     if not os.path.exists(os.path.join(args.root, "BENCHMARK.json")):
-        build(args.root)
+        build(args.root, args.appended)
     manifest = manifest_lib.Manifest(args.root)
     manifest.validate()
-    run = run_lib.CellRun(manifest, manifest.cell(args.workload), args.seed, args.seconds,
-                          bool(args.trace), platform=args.platform,
-                          work_root=os.path.join(args.root, "work"))
+    run = LaneRun(manifest, manifest.cell(args.workload), args.seed, args.seconds,
+                  bool(args.trace), platform=args.platform,
+                  work_root=os.path.join(args.root, "work"))
     try:
         line = run.run()
     finally:

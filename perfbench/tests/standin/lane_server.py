@@ -1,10 +1,10 @@
 """A stand-in for the model server's generative lane, for the token entry's
-tests and its proof of plumbing: it takes ``children/serve.py``'s place in
-a scratch copy of the benchmark, is started with the same arguments and
-environment, and speaks what a run asks of the program -- ``/readyz``,
-``GET /v1/models``, ``/metrics``, the side port's ``/device`` and
-``/trace`` (``children/serve.py``'s own, kept beside it as
-``serve_side.py``) and the wire of
+tests and its proof of plumbing: added beside ``children/serve.py`` in a
+scratch copy of the benchmark, it is started in that file's place
+(``build.py::LaneRun``) with the same arguments and environment, and speaks
+what a run asks of the program -- ``/readyz``, ``GET /v1/models``,
+``/metrics``, the side port's ``/device`` and ``/trace``
+(``children/serve.py``'s own) and the wire of
 ``perfbench/tokens.py`` -- over ``byte_lm.py``'s prefill and decode step,
 one jitted program each, a request a thread, ``slots`` at a time.  Nothing
 of the program under test runs here, so no number it gives is the
@@ -45,7 +45,7 @@ def main(argv: list[str]) -> int:
     import numpy as np
 
     from perfbench import reference
-    from perfbench.children import serve_side   # children/serve.py as committed
+    from perfbench.children import serve   # the side port, as committed
 
     lm = reference.load("byte_lm")
     model = os.environ["KDLT_DECODE_MODEL"]
@@ -142,7 +142,7 @@ def main(argv: list[str]) -> int:
         def log_message(self, fmt, *a):
             pass
 
-    serve_side.start_side_port(int(os.environ["PERFBENCH_DEVICE_PORT"]))
+    serve.start_side_port(int(os.environ["PERFBENCH_DEVICE_PORT"]))
     httpd = ThreadingHTTPServer(("127.0.0.1", args.port), Handler)
     httpd.daemon_threads = True
     signal.signal(signal.SIGTERM, lambda *_: threading.Thread(target=httpd.shutdown).start())

@@ -5,7 +5,11 @@ has no configuration of), an entry it has never seen
 (``extend/server-bytes.py``: the model server's bytes wire), their
 configuration, traffic files and entries in ``BENCHMARK.json`` -- and whole
 runs are rehearsed on the CPU; every file that was in the copy keeps the
-hash it had."""
+hash it had.  Then the same for a wire the benchmark has no cell of, as a
+program PR has to bring it: the committed ``BENCHMARK.json`` with entries
+appended to ``configs``, ``workloads`` and ``per_layer`` alone (the token
+stand-in of ``standin/``), whose cell reports the two end-to-end metrics
+that list no cells, ``setup_s`` and ``latency_p50_ms``."""
 
 import hashlib
 import json
@@ -16,6 +20,7 @@ import pytest
 
 from perfbench import manifest as M
 from perfbench import run as R
+from perfbench.tests.standin import build as standin
 
 EXTEND = os.path.join(os.path.dirname(os.path.abspath(__file__)), "extend")
 
@@ -119,12 +124,12 @@ def test_an_unknown_entry_generator_or_quantity_is_an_error(extended, tmp_path):
         odd = M.Cell(**{**cell.__dict__, "traffic": dict(cell.traffic, **change)})
         with pytest.raises(M.ManifestError, match=match):
             R.CellRun(manifest, odd, 1, 1.0, False, platform="cpu", work_root=str(tmp_path))
-    token = {"name": "ttft_p50_ms", "unit": "ms"}
-    odd = M.Cell(**{**cell.__dict__, "end_to_end": cell.end_to_end + (token,)})
-    run = R.CellRun(manifest, odd, 1, 1.0, False, platform="cpu", work_root=str(tmp_path))
-    run.window, run.good, run.setup_s = [], set(), 1.0
-    with pytest.raises(M.ManifestError, match="gives no end-to-end quantity"):
-        run.end_to_end()
+    for metric, match in (({"name": "ttft_p50_ms", "unit": "ms"},
+                           "gives no end-to-end quantity for 'ttft_p50_ms'"),
+                          ({"name": "latency_p50_ms.tiny", "unit": "s"}, "is in ms, not s")):
+        odd = M.Cell(**{**cell.__dict__, "end_to_end": cell.end_to_end + (metric,)})
+        with pytest.raises(M.ManifestError, match=match):     # before anything runs
+            R.CellRun(manifest, odd, 1, 1.0, False, platform="cpu", work_root=str(tmp_path))
 
 
 def test_a_metric_arrives_with_its_first_cell(tmp_path):
@@ -136,10 +141,77 @@ def test_a_metric_arrives_with_its_first_cell(tmp_path):
     m.validate()
     tokens = M.load_module(m.bench_dir, "entries", "server-generate").QUANTITIES
     assert {"output_tokens_per_s", "ttft_p50_ms", "itl_p95_ms"} <= set(tokens)
-    assert not set(tokens) & {e["name"] for e in m.data["end_to_end"]}
+    # all the two wires share is the one that lists no cells
+    assert set(tokens) & {e["name"] for e in m.data["end_to_end"]} == {"latency_p50_ms"}
     d = json.loads(json.dumps(m.data))
     d["end_to_end"].append({"name": "ttft_p50_ms", "unit": "ms", "better": "lower",
                             "bound": 0.1, "source": "host_clock", "workloads": []})
     json.dump(d, open(tmp_path / "BENCHMARK.json", "w"))
     with pytest.raises(M.ManifestError, match="lists no cell"):
         M.Manifest(str(tmp_path), bench_dir=m.bench_dir).validate()
+
+
+# --- a new wire's first cell, as a program PR may bring it ---------------------------------
+
+
+@pytest.fixture(scope="module")
+def appended(tmp_path_factory):
+    root = tmp_path_factory.mktemp("appended")
+    standin.build(str(root), appended=True)
+    m = M.Manifest(str(root))
+    m.validate()
+    yield m, str(root / "work")
+    committed = hashes(os.path.join(M.ROOT, "perfbench"))
+    after = hashes(m.bench_dir)
+    kept = {k: v for k, v in committed.items()
+            if "__pycache__" not in k and not k.startswith("tests" + os.sep)}
+    assert {k: after.get(k) for k in kept} == kept          # no committed file edited
+    assert len(after) == len(kept) + 8                       # and eight added
+
+
+def test_a_new_wires_first_cell_arrives_by_appended_entries(appended):
+    """What PR 27 could not do and the next ``model_config`` PR has to: no
+    end-to-end entry is added, and the token cell still reports two."""
+    manifest, work = appended
+    committed = M.Manifest().data
+    for key in ("command", "paths", "run_seconds", "end_to_end"):
+        assert manifest.data[key] == committed[key]
+    for key in ("configs", "workloads", "per_layer"):
+        n = len(committed[key])
+        assert manifest.data[key][:n] == committed[key] and len(manifest.data[key]) > n
+    cell = manifest.cell("standin-closed")
+    assert [m["name"] for m in cell.end_to_end] == ["setup_s", "latency_p50_ms"]
+    assert {m["moves"] for m, _ in cell.per_layer} == {"latency_p50_ms"}
+    run = standin.LaneRun(manifest, cell, 2**31 + 28, 3.0, False, platform="cpu",
+                          work_root=work)
+    try:
+        line = run.run()
+    finally:
+        run.children.kill_all()
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
+    assert line["compared"]["tokens_compared"]["value"] == 6 * 64
+    assert set(line["metrics"]) == {"setup_s", "latency_p50_ms"}
+    assert line["metrics"]["latency_p50_ms"] == {
+        "value": run.entry.quantities(run)["latency_p50_ms"], "unit": "ms"}
+    assert 0 < line["metrics"]["latency_p50_ms"]["value"] < 3000.0
+    # the image cells report it too, from the picture entries' own arithmetic
+    for name in committed["workloads"]:
+        assert "latency_p50_ms" in {m["name"] for m in manifest.cell(name["name"]).end_to_end}
+
+
+def test_a_quantity_that_lists_no_cells_binds_every_entry(appended, tmp_path):
+    """An end-to-end metric without a ``workloads`` list is reported by every
+    cell, so a cell whose entry does not give it is an error that names it:
+    a wire's own metrics list their cells."""
+    manifest, _work = appended
+    d = json.loads(json.dumps(manifest.data))
+    d["end_to_end"].append({"name": "output_tokens_per_s", "unit": "tokens/s",
+                            "better": "higher", "bound": 0.05, "source": "host_clock"})
+    os.symlink(manifest.bench_dir, tmp_path / "perfbench")
+    json.dump(d, open(tmp_path / "BENCHMARK.json", "w"))
+    with pytest.raises(M.ManifestError, match="'server-tensor' gives no end-to-end quantity "
+                                              "for 'output_tokens_per_s'"):
+        M.Manifest(str(tmp_path)).validate()
+    d["end_to_end"][-1]["workloads"] = ["standin-closed", "standin-open"]
+    json.dump(d, open(tmp_path / "BENCHMARK.json", "w"))
+    M.Manifest(str(tmp_path)).validate()

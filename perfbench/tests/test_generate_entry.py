@@ -237,8 +237,8 @@ def lane(tmp_path_factory):
 
 def drive(lane, cell, seed, trace=False):
     manifest, work = lane
-    run = R.CellRun(manifest, manifest.cell(cell), seed, 3.0, trace, platform="cpu",
-                    work_root=work)
+    run = standin.LaneRun(manifest, manifest.cell(cell), seed, 3.0, trace, platform="cpu",
+                          work_root=work)
     try:
         return run, run.run()
     finally:

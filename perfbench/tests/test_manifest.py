@@ -17,8 +17,11 @@ ROOT = M.ROOT
 def test_manifest_validates_and_every_cell_resolves():
     m = M.Manifest()
     m.validate()
+    no_list = [e["name"] for e in m.data["end_to_end"] if "workloads" not in e]
+    assert no_list == ["setup_s", "latency_p50_ms"]      # what every cell reports
     for name in m.workloads:
         cell = m.cell(name)
+        assert set(no_list) < {e["name"] for e in cell.end_to_end}
         assert cell.config["name"] == cell.config_name
         assert cell.traffic["generator"] in ("open-poisson", "closed")
         assert cell.traffic["entry"] in ("gateway-url", "server-tensor")

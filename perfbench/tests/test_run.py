@@ -76,7 +76,7 @@ def test_whole_run_line_and_control(tiny, capfd):
     assert set(line["metrics"]) <= names and "dispatch_ms.x512" in line["metrics"]
     assert "device_idle_pct.x512" not in line["metrics"]
     for v in line["metrics"].values():
-        assert set(v) == {"value", "unit"} and v["value"] > 0
+        assert set(v) == {"value", "unit"} and v["value"] >= 0   # a true zero reads 0
     R.report(line)
     out, err = capfd.readouterr()
     last = json.loads(out.strip().splitlines()[-1])
@@ -137,9 +137,8 @@ def test_url_entry_rehearsal(tiny, tmp_path):
     d = json.load(open(root / "BENCHMARK.json"))
     d["workloads"].append({"name": "tiny-url", "config": "tiny-xception",
                            "traffic": "tiny-url", "chips": 1, "why": "y"})
-    d["end_to_end"] += [{"name": n, "unit": "ms", "better": "lower", "bound": 0.05,
-                         "source": "host_clock", "workloads": ["tiny-url"]}
-                        for n in ("latency_p50_ms", "latency_p95_ms")]
+    d["end_to_end"].append({"name": "latency_p95_ms", "unit": "ms", "better": "lower",
+                            "bound": 0.05, "source": "host_clock", "workloads": ["tiny-url"]})
     d["per_layer"] += [{"name": n, "unit": "ms", "better": "lower", "source": "host_clock",
                         "layer": "x", "moves": "latency_p50_ms", "workloads": ["tiny-url"]}
                        for n in ("gateway_self_ms", "ingest_decode_ms", "gen_late_p95_ms")]
