@@ -3696,7 +3696,7 @@ def bench_mesh_ab(reps=3, size=96, buckets=(8, 16), arms=(1, 2, 4), seed=0,
         compiled_arg_bytes = None
         try:
             ma = (
-                eng._jitted.lower(eng._variables, fixtures[buckets[-1]])
+                eng._jitted.lower(eng._variables, eng._zero_wire(buckets[-1]))
                 .compile()
                 .memory_analysis()
             )

@@ -266,6 +266,17 @@ def capture(workload: str, seed: int, out_dir: str, seconds: float, window: floa
             / max(1.0, after[f"kdlt_pipeline_{st}_seconds_count"]
                   - first_page[f"kdlt_pipeline_{st}_seconds_count"])
             for st in ("enqueue_wait", "dispatch", "execute", "readback")}
+        # by label set, at the run's end: the engine's input paths (PR 29;
+        # whole buckets sent as views of the body should all read "view")
+        # and the compile requests, which stand still once the server is warm
+        report["engine_input_total"] = {
+            series: float(value)
+            for series, _, value in (line.rpartition(" ")
+                                     for line in procs.scrape(run.server).splitlines())
+            if series.startswith(("kdlt_engine_input_total{", "kdlt_engine_batches_total"))}
+        report["compile_requests"] = {
+            "at_warm": first_page.get("kdlt_xla_compile_requests_total"),
+            "at_end": after.get("kdlt_xla_compile_requests_total")}
         report["trace_bytes"] = sum(
             os.path.getsize(os.path.join(base, f))
             for base, _d, files in os.walk(reply["trace_dir"]) for f in files)

@@ -152,12 +152,16 @@ def build_mesh_serving_jit(
     commits it to P(data) on the transfer path, the ``None`` entry keeps
     the params' committed (load-time) shardings, and ``out_shardings``
     replicates the logits so the all-gather happens ON DEVICE and readback
-    is a local ``np.asarray``.
+    is a local ``np.asarray``.  P(data) names the leading axis only, so the
+    batch may have any rank behind it: the engine hands over its wire form
+    (runtime.engine.wire_form, ``uint8[bucket, H, W*C]``).
 
     ``forward`` overrides the inner function (the engine passes its
-    quantization-aware live forward so int8 leaves ride the sharded
-    layout); ``fast`` wraps the inner under shard_map exactly as
-    build_sharded_jit does.  ``donate=True`` donates the batch argument
+    quantization-aware live forward, wrapped to un-wire its argument first
+    -- runtime.engine.wired -- so int8 leaves ride the sharded layout and
+    the reshape to NHWC is each shard's own, inside the one program);
+    ``fast`` wraps the inner under shard_map exactly as build_sharded_jit
+    does.  ``donate=True`` donates the batch argument
     (argnum 1), composing PR 9's buffer donation with the GSPMD layout.
     """
     inner = forward

@@ -14,6 +14,16 @@ TPU-first design decisions:
   recompiles.  This is SURVEY.md section 7's hard part (b).
 - **Normalization on device.** The engine takes uint8 batches straight off
   the wire; the scale/shift fuses into the first conv (see models.build_forward).
+- **The batch crosses in the host's byte order.** Callers pass
+  ``uint8[n, H, W, C]``; the compiled program's parameter is the WIRE FORM
+  of its bucket, ``uint8[bucket, H, W*C]`` -- a reshape of the caller's
+  memory (wire_form / to_wire / stage below) -- and the program's first
+  operation splits the rows back into NHWC on the device.  A parameter of
+  the NHWC shape itself gets a device layout far from the host's (batch on
+  the lanes, or channel planes), and the runtime then transposes every
+  batch pixel by pixel on the host's threads before the program may start;
+  kdlt_engine_input_total{path} counts the batches whose wire form was a
+  view against those that took a host copy first.
 - **Pipelined dispatch, serialized enqueue.** predict() is thread-safe;
   only the ENQUEUE of a program is serialized by a lock (one accelerator
   executes one program at a time anyway, and JAX's async dispatch returns
@@ -141,6 +151,91 @@ def _donate_jit(fn, donate: bool):
     return jax.jit(fn, donate_argnums=(1,))
 
 
+# --- the wire form of a uint8 batch ---------------------------------------
+#
+# A compiled program's parameter has a device layout of the compiler's
+# choosing, and for ``uint8[bucket, H, W, 3]`` that layout is far from the
+# host's byte order: on a v5e the batch goes on the lanes for Xception's
+# 512x299x299x3 (major to minor H, C, W, B) and B7's 64x600x600x3 becomes
+# channel planes (B, C, H, W).  Handed such an array, the runtime
+# transposes it ON THE HOST, three-byte pixels apart, on its own threads,
+# after predict_async has returned and before the program may start.  So
+# the batch crosses as ``uint8[bucket, H, W*C]`` -- the WIRE FORM: the same
+# bytes with a pixel's channels folded into its row, a reshape of the
+# caller's memory and never a copy -- whose device layout keeps a row's
+# bytes together (whole 128-byte runs move, not single bytes), and the
+# first operation of the bucket's own program splits the rows back into
+# NHWC: the device re-tiles 137 MB of bytes in 0.4 ms where the host took
+# 138.  One helper pair for every program the engine compiles: to_wire
+# (host) / wired (program).  The table of forms tried on the chip, word
+# views and flat forms among them, is in PERF.md (PR 29) and
+# exp/stage_forms.py reads it again.
+
+
+def wire_form(batch_shape: Sequence[int]) -> tuple[int, ...]:
+    """The wire form's shape for ``uint8[bucket, ..., W, C]``: the last two
+    axes folded into one.  Shape arithmetic alone; the leading axis stays
+    the batch, which is the axis a mesh engine shards."""
+    *lead, w, c = (int(d) for d in batch_shape)
+    return (*lead, w * c)
+
+
+def to_wire(batch: np.ndarray) -> np.ndarray:
+    """The host's half: a C-contiguous ``uint8[bucket, H, W, C]`` in its wire
+    form.  A view of the same memory, never a copy; read-only and
+    unaligned memory (a request body's pixels start at an arbitrary byte
+    offset inside the msgpack envelope) is accepted as it is."""
+    return batch.reshape(wire_form(batch.shape))
+
+
+def from_wire(wire, image_shape: Sequence[int]):
+    """The program's half, its first operation: wire form -> uint8 NHWC.
+
+    The barrier pins the reshape on the bytes, so that what follows is the
+    program as it was for an NHWC parameter and the preamble costs one
+    re-tiling of uint8 whatever the model (v5e: +0.35 ms on Xception's
+    106.5 at bucket 512, +0.10 ms on B7's 168.9 at bucket 64).  Left free,
+    XLA moves elementwise work across the reshape: that saved Xception
+    0.06 ms and cost B7 0.98 -- it split the per-channel normalisation
+    around the reshape through a float32 copy of the batch."""
+    import jax
+
+    pixels = wire.reshape((wire.shape[0], *image_shape))
+    return jax.lax.optimization_barrier(pixels)
+
+
+def wired(forward, image_shape: Sequence[int]):
+    """``forward(variables, images)`` as ``f(variables, wire)``, under
+    forward's own name (a jitted program is named after its function, and
+    the benchmark's trace reduction counts programs named ``jit_*``).  An
+    argument of the images' own rank is pixels already -- the float32 debug
+    path shares the jit -- and passes through."""
+    import functools
+
+    @functools.wraps(forward)
+    def call(variables, wire):
+        if wire.ndim == len(image_shape):
+            wire = from_wire(wire, image_shape)
+        return forward(variables, wire)
+
+    return call
+
+
+def stage(images: np.ndarray, bucket: int) -> tuple[np.ndarray, bool]:
+    """``uint8[n, *image]`` as the wire form of its bucket, and whether that
+    took a host copy: padding ``n`` up to the bucket, or an array that is
+    not C-contiguous.  A whole bucket of contiguous rows is a view."""
+    n = images.shape[0]
+    copied = False
+    if bucket != n:
+        padded = np.zeros((bucket, *images.shape[1:]), images.dtype)
+        padded[:n] = images
+        images, copied = padded, True
+    elif not images.flags.c_contiguous:
+        images, copied = np.ascontiguousarray(images), True
+    return to_wire(images), copied
+
+
 def _env_float(name: str, default: float) -> float:
     raw = os.environ.get(name, "")
     try:
@@ -154,11 +249,20 @@ def resolve_pipeline_depth(depth: int | None = None) -> int:
 
     Depth 1 is serial dispatch (each batch fully materialized before the
     next is assembled).  Depth 2 overlaps batch N+1's host-side gather and
-    H2D transfer with batch N's device execution, which is the whole win on
-    a single chip: the device runs one program at a time, so depth 3+ only
-    queues more work behind the same execution stream and adds latency
-    without adding throughput.  Clamped to >=1; a typo'd env value degrades
-    to the default rather than killing serving.
+    H2D transfer with batch N's device execution.  That is the whole win on
+    a single chip WHERE STAGING A BATCH TAKES NO LONGER THAN RUNNING ONE:
+    the device runs one program at a time, so once the next batch's input
+    is there in time depth 3+ only queues more work behind the same
+    execution stream and adds latency without adding throughput.  Where
+    staging is the longer of the two, two slots leave the device waiting:
+    in the benchmark's Xception cell (137 MB a batch; PERF.md, PR 29) the
+    runtime took ~138 ms to re-tile an NHWC batch on the host against a
+    106 ms program, a batch held its slot ~245 ms, and the device idled
+    12-13% at depth 2 (4,200 img/s); since the batch crosses in its wire
+    form staging takes ~52 ms, the device idles 0.2% (4,800 img/s), and a
+    third slot has nothing left to hide.  The default stays 2.  Clamped to
+    >=1; a typo'd env value degrades to the default rather than killing
+    serving.
     """
     if depth is None:
         raw = os.environ.get(PIPELINE_DEPTH_ENV, "")
@@ -773,7 +877,8 @@ class InferenceEngine:
                 sharded_call = build_sequence_parallel_forward(
                     self.spec, mesh, dtype=jnp.dtype(self._compute_dtype)
                 )
-                self._jitted = sharded_call
+                # A jit of the jitted forward inlines it: one program a batch.
+                self._jitted = jax.jit(wired(sharded_call, self.spec.input_shape))
                 self._jitted_f32 = sharded_call
                 self._f32_lock = threading.Lock()
                 self._init_metrics(registry)
@@ -839,7 +944,8 @@ class InferenceEngine:
             and artifact.module_bytes_for(platform) is not None
         ):
             self._jitted = _donate_jit(
-                artifact.exported_for(platform).call, self._donate
+                wired(artifact.exported_for(platform).call, self.spec.input_shape),
+                self._donate,
             )
             # The exported module is traced for the uint8 wire path only;
             # float32 "pre-normalized" input (protocol.decode_predict_request's
@@ -893,6 +999,7 @@ class InferenceEngine:
         self._m_pad_waste = registry.counter(
             "kdlt_engine_pad_images_total", "padding rows executed (bucket waste)"
         )
+        self._m_input = metrics_lib.engine_input_counters(registry)
         self._m_warmup = registry.gauge("kdlt_engine_warmup_seconds", "total warmup compile time")
         self._m_fast_degraded = registry.gauge(
             "kdlt_engine_fast_degraded",
@@ -1074,7 +1181,7 @@ class InferenceEngine:
         x = rng.integers(
             0, 256, size=(b, *self.spec.input_shape), dtype=np.uint8
         )
-        got = np.asarray(self._jitted(self._variables, x))[:b]
+        got = np.asarray(self._jitted(self._variables, to_wire(x)))[:b]
         prev = self._quantization_active
         try:
             # The reference IS the fallback program: _live_forward with the
@@ -1092,16 +1199,13 @@ class InferenceEngine:
                 ref_fn = build_mesh_serving_jit(
                     self.spec, self.mesh, jnp.dtype(self._compute_dtype),
                     fast=False,
-                    forward=self._live_forward(jnp.dtype(self._compute_dtype)),
+                    forward=self._wired_live_forward(),
                 )
             else:
-                ref_fn = jax.jit(
-                    self._live_forward(jnp.dtype(self._compute_dtype))
-                )
+                ref_fn = jax.jit(self._wired_live_forward())
         finally:
             self._quantization_active = prev
-        # kdlt-lint: disable=donation-safety -- x is a host numpy batch; donation consumes device-resident jax.Arrays only, a host array is copied at dispatch and stays valid
-        ref = np.asarray(ref_fn(self._variables, x))[:b]
+        ref = np.asarray(ref_fn(self._variables, to_wire(x)))[:b]
         drift = float(
             np.abs(got - ref).max() / (np.abs(ref).max() + 1e-9)
         )
@@ -1164,7 +1268,7 @@ class InferenceEngine:
         """
 
         def warm_one(b: int) -> None:
-            x = np.zeros((b, *self.spec.input_shape), np.uint8)
+            x = self._zero_wire(b)
             t0 = time.perf_counter()
             np.asarray(self._jitted(self._variables, x))  # compile+run
             # Per-bucket wall time feeds the cache-hit/compile provenance
@@ -1239,17 +1343,30 @@ class InferenceEngine:
                 build_mesh_serving_jit,
             )
 
-            dtype = jnp.dtype(self._compute_dtype)
             self._jitted = build_mesh_serving_jit(
-                self.spec, self.mesh, dtype, fast=self._fast,
-                forward=self._live_forward(dtype), donate=self._donate,
+                self.spec, self.mesh, jnp.dtype(self._compute_dtype),
+                fast=self._fast, forward=self._wired_live_forward(),
+                donate=self._donate,
             )
             self._jitted_f32 = self._jitted
             return
-        self._jitted = _donate_jit(
-            self._live_forward(jnp.dtype(self._compute_dtype)), self._donate
-        )
+        self._jitted = _donate_jit(self._wired_live_forward(), self._donate)
         self._jitted_f32 = self._jitted
+
+    def _wired_live_forward(self):
+        """The live forward for the active scheme, taking the wire form."""
+        import jax.numpy as jnp
+
+        return wired(
+            self._live_forward(jnp.dtype(self._compute_dtype)),
+            self.spec.input_shape,
+        )
+
+    def _zero_wire(self, bucket: int) -> np.ndarray:
+        """A zero batch of one bucket in the form serving hands over: what
+        warms, lowers or places a bucket's program must give it the
+        argument serving gives it, or it is another program."""
+        return np.zeros(wire_form((bucket, *self.spec.input_shape)), np.uint8)
 
     def _live_forward(self, dtype):
         """The live-jit forward for the ACTIVE quantization scheme: plain
@@ -1282,9 +1399,8 @@ class InferenceEngine:
         """
         import jax
 
-        x = np.zeros((bucket, *self.spec.input_shape), np.uint8)
         (var_info, img_info), _kwargs = self._jitted.lower(
-            self._variables, x
+            self._variables, self._zero_wire(bucket)
         ).args_info
         return {
             "variables": any(
@@ -1337,7 +1453,7 @@ class InferenceEngine:
             from kubernetes_deep_learning_tpu.parallel import mesh as mesh_par
 
             placed = jax.device_put(
-                np.zeros((self.max_batch, *self.spec.input_shape), np.uint8),
+                self._zero_wire(self.max_batch),
                 mesh_par.batch_sharding(self.mesh),
             )
             self._batch_rows = {
@@ -1445,9 +1561,9 @@ class InferenceEngine:
             def base(variables, images):  # noqa: F811 - wrapped exact forward
                 return exact(dequantize_variables(variables), images)
 
-        x = np.zeros((bucket, *self.spec.input_shape), np.uint8)
         return flops_lib.lowered_flops_per_image(
-            jax.jit(base), bucket, self._variables, x
+            jax.jit(wired(base, self.spec.input_shape)), bucket,
+            self._variables, self._zero_wire(bucket),
         )
 
     def _f32_forward(self):
@@ -1508,6 +1624,7 @@ class InferenceEngine:
         forward); _live_forward raises for those, at first use.
         """
         if self._ingest_jitted is None:
+            src = self.ingest_source_shape  # takes _f32_lock itself, once
             with self._f32_lock:
                 if self._ingest_jitted is None:
                     import jax
@@ -1519,7 +1636,8 @@ class InferenceEngine:
                     )
                     inner = self._live_forward(jnp.dtype(self._compute_dtype))
 
-                    def fused(variables, batch):
+                    def fused(variables, wire):
+                        batch = from_wire(wire, src)
                         x = batch.astype(jnp.float32)
                         x = jax.image.resize(
                             x, (batch.shape[0], h, w, c), method=method
@@ -1555,12 +1673,8 @@ class InferenceEngine:
                 f"predict_ingest_async takes uint8 images, got {images.dtype}"
             )
         n = images.shape[0]
-        bucket = self.bucket_for(n)
-        if bucket != n:
-            pad = np.zeros((bucket - n, *src), images.dtype)
-            batch = np.concatenate([images, pad], axis=0)
-        else:
-            batch = images
+        batch, copied = stage(images, self.bucket_for(n))
+        self._m_input["copy" if copied else "view"].inc()
         self._ingest_fused()  # build outside the dispatch lock
         with self._lock:
             # kdlt-lint: disable=lock-around-jit -- same serialized-enqueue contract as predict_async: dispatch is async, the lock covers only the enqueue, and donated-buffer dispatches must not interleave
@@ -1581,10 +1695,17 @@ class InferenceEngine:
         while this one executes (the batcher's pipelining hook).
 
         Aliasing contract: ``images`` must stay unmodified until the result
-        is materialized.  Whether jax copies host arrays at dispatch is
-        BACKEND-DEPENDENT (the CPU client can alias aligned host memory
-        zero-copy), so a caller with a reusable staging buffer must rotate
-        depth+1 buffers or copy -- see NativeBatcher's staging-buffer ring.
+        is materialized.  A whole bucket of C-contiguous rows is handed to
+        the program as a VIEW of the caller's memory (its wire form;
+        read-only and unaligned memory is fine), and this call returns when
+        the transfer is enqueued, not when it is done: the runtime reads
+        that memory on its own threads afterwards, until the transfer
+        completes on a TPU, and for as long as the program runs wherever
+        the client aliases host memory zero-copy (the CPU client can).
+        Only a batch the engine had to copy first (n < bucket, or not
+        contiguous: counted ``path="copy"``) is free at return.  So a
+        caller with a reusable staging buffer must rotate depth+1 buffers
+        or copy -- see NativeBatcher's staging-buffer ring.
         InFlightDispatcher is the general pipelining wrapper over this
         hook: bounded in-flight depth, FIFO completion thread, futures.
         """
@@ -1597,12 +1718,8 @@ class InferenceEngine:
         if images.dtype != np.uint8:
             raise ValueError(f"predict_async takes uint8 images, got {images.dtype}")
         n = images.shape[0]
-        bucket = self.bucket_for(n)
-        if bucket != n:
-            pad = np.zeros((bucket - n, *self.spec.input_shape), images.dtype)
-            batch = np.concatenate([images, pad], axis=0)
-        else:
-            batch = images
+        batch, copied = stage(images, self.bucket_for(n))
+        self._m_input["copy" if copied else "view"].inc()
         with self._lock:
             # kdlt-lint: disable=lock-around-jit -- serialized enqueue is the documented contract: dispatch is async (returns an unmaterialized handle), so the lock covers only the enqueue, and XLA requires donated-buffer dispatches not to interleave
             logits = self._jitted(self._variables, batch)

@@ -139,6 +139,28 @@ def server_unpack_counters(registry: "Registry") -> dict:
     }
 
 
+# How a uint8 batch reached its bucket's program (runtime.engine.stage): in
+# the engine's wire form as a view of the caller's memory, or after a host
+# copy.  The ``path`` label's value set is exactly this tuple.
+ENGINE_INPUT_PATHS = (
+    ("view", "a whole bucket of contiguous rows: the wire form is a view "
+     "of the caller's array, nothing copied on the host"),
+    ("copy", "a host copy first: rows padded up to the bucket, or an "
+     "array that was not C-contiguous"),
+)
+
+
+def engine_input_counters(registry: "Registry") -> dict:
+    """kdlt_engine_input_total{path}: one count per uint8 batch handed to
+    a compiled program by predict_async / predict_ingest_async."""
+    return {
+        path: registry.with_labels(path=path).counter(
+            "kdlt_engine_input_total", f"uint8 batches dispatched with {help}"
+        )
+        for path, help in ENGINE_INPUT_PATHS
+    }
+
+
 # --- the bounded ``model`` label (multi-model serving) ----------------------
 #
 # Every per-model series on a shared /metrics page carries a ``model`` label
