@@ -9,7 +9,7 @@ Times block1 (normalize + conv 3x3/2 s2 -> 32ch + BN/relu + conv 3x3 -> 64ch
 - both stem convs via s2d/im2col combined.
 
 Each variant is checked numerically against the reference formulation before
-timing (atol on bf16).  Timing uses the bench.py anti-LICM chained scan.
+timing (atol on bf16).  Timing uses the anti-LICM chained scan.
 """
 
 from __future__ import annotations

@@ -10,8 +10,7 @@ on the real chip:
 3. optionally a trace-span breakdown of where the remaining time goes.
 
 Method: pipelined bursts (amortizes per-dispatch host cost)
-plus a chained-scan cross-check at the headline batch, same discipline as
-bench.py.  Numerics are asserted against the flax graph before any timing
+plus a chained-scan cross-check at the headline batch.  Numerics are asserted against the flax graph before any timing
 is believed.
 
 Usage (TPU):  python exp/mbconv_variants.py --batches 64,128 --reps 3
@@ -129,8 +128,7 @@ def main():
                 return acc
 
             for use_fast, tag in ((False, "flax"), (True, "fused")):
-                # Capped like bench.py's auto-k: keep a single device
-                # execution to a few seconds.
+                # Capped: keep a single device execution to a few seconds.
                 kk = max(24, min(500, int(2.0 / (t_fast if use_fast else t_flax))))
                 float(chained(variables, x, kk, use_fast))  # compile+run
                 t0 = time.perf_counter()

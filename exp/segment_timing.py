@@ -3,7 +3,7 @@
 Times jitted sub-forwards (entry flow to each cut point, middle flow alone,
 exit flow alone) at serving-relevant batch sizes, so the Pallas fusion work
 targets the segment that actually dominates.  Each timed fn chains K=8
-data-dependent iterations (same anti-LICM trick as bench.py) to amortize
+data-dependent iterations (the anti-LICM trick: no iteration can be hoisted) to amortize
 per-dispatch host cost.
 
 Usage: python exp/segment_timing.py [--batch 256]

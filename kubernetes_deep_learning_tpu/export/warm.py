@@ -1,7 +1,8 @@
 """kdlt-warm: AOT-compile every registry model's bucket ladder into the
 persistent compile cache (zero-cold-start scale-up).
 
-BENCH_r05 measured 7-28 s of live XLA compile per bucket, which makes a
+A bucket's live XLA compile takes tens of seconds on the chip (a cell's
+first set-up reads 71-83 s against 34-40 s warm: PERF.md), which makes a
 freshly scaled model-server pod dead weight exactly when the HPA added it
 because load spiked.  The persistent compile cache (utils.compilecache,
 GUIDE §10b) already makes a RE-compile a disk read; what was missing is

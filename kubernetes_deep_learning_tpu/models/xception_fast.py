@@ -196,8 +196,8 @@ def build_fast_forward(
 
         # Batch rides the sublane axis in the kernels' (H, W, B, C) layout,
         # and their (H, W, bt) -> rows collapse is only Mosaic-legal when
-        # the batch tile is 8-aligned (BENCH_r02's batch-1 compile
-        # failure).  Pad the batch ONCE to a multiple of 8 and slice after
+        # the batch tile is 8-aligned (a batch-1 tile failed to compile
+        # on the v5e).  Pad the batch ONCE to a multiple of 8 and slice after
         # the head mean, so the per-kernel padding in ops.fused_sepconv
         # stays a no-op and small serving buckets (1, 2, 4) compile the
         # same fused program.

@@ -76,10 +76,6 @@ def get_spec(name: str) -> ModelSpec:
         ) from None
 
 
-def list_specs() -> list[str]:
-    return sorted(_REGISTRY)
-
-
 # The flagship model: the reference's 10-class clothing classifier
 # (labels from reference model_server.py:21-32, input contract from
 # reference guide.md:220-231: (-1, 299, 299, 3) f32 -> (-1, 10) f32).

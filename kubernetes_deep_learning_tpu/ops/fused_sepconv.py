@@ -88,8 +88,8 @@ def pick_batch_tile(batch: int, h: int, w: int, c: int, budget_bytes: int = 9 <<
 
     Only 8-multiples are ever returned: the kernel collapses (H, W, bt) into
     MXU rows, and Mosaic rejects that reshape unless the sublane-adjacent
-    dim is 8-aligned (BENCH_r02: ``(361,728)->(19,19,1,728)`` at bt=1 failed
-    to compile).  Callers with ``batch % 8 != 0`` must pad the batch axis up
+    dim is 8-aligned (``(361,728)->(19,19,1,728)`` at bt=1 failed to compile
+    on the v5e).  Callers with ``batch % 8 != 0`` must pad the batch axis up
     to a multiple of 8 first -- ``fused_sepconv_block_t`` and
     ``fused_sepconv_chain_t`` do this internally.
     """

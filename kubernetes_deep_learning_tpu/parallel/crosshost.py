@@ -131,9 +131,9 @@ XH_COMPRESS_ENV = "KDLT_XH_COMPRESS"
 _XH_CODEC_ZLIB, _XH_CODEC_LZ4 = 1, 2
 
 # Watchdog slack for rounds that include a compile: the first round per
-# (mode, bucket) after an install traces+compiles the SPMD program (7-28 s
-# in BENCH_r05; minutes on big models), which a flat round timeout would
-# misread as a dead peer -- exit(70) -> recompile -> crash loop (ADVICE r3).
+# (mode, bucket) after an install traces+compiles the SPMD program (tens
+# of seconds; minutes on big models), which a flat round timeout would
+# misread as a dead peer -- exit(70) -> recompile -> crash loop.
 # The steady-state watchdog arms only once a (mode, bucket) has a completed
 # round to base an EWMA on; until then only this slack multiple of the
 # round timeout backstops an infinitely wedged compile round.
@@ -309,8 +309,8 @@ class RoundStallWatch:
     in-flight entry outlives its bound:
 
     - a (mode, bucket) key with NO completed sample yet is a COMPILE
-      round: the steady-state watchdog is not armed for it (compile time
-      is 7-28 s in BENCH_r05 and a flat bound would misread it as a dead
+      round: the steady-state watchdog is not armed for it (a compile
+      takes tens of seconds and a flat bound would misread it as a dead
       peer); only ``compile_slack_s`` (0 = unbounded) backstops an
       infinitely wedged compile.
     - once a key has a sample, bound = max(floor, multiple x EWMA).
@@ -890,8 +890,7 @@ class CrossHostForward:
                 if self._metrics is not None:
                     self._metrics["broadcast"].observe(t1 - t0)
                     self._metrics["rounds"].inc()
-                    # kdlt-lint: disable=hot-path-sync -- inflight_rounds is a host int (semaphore accounting); no device handle involved, nothing can block
-                    self._metrics["inflight"].set(float(self.inflight_rounds))
+                    self._metrics["inflight"].set(self.inflight_rounds)
                 w1 = trace_lib.now_s() if traces else 0.0
                 if traces:
                     for tr in traces:
@@ -927,7 +926,7 @@ class CrossHostForward:
         self._watch.complete(seq, seconds)
         self._slots.release()
         if self._metrics is not None:
-            self._metrics["inflight"].set(float(self.inflight_rounds))
+            self._metrics["inflight"].set(self.inflight_rounds)
 
     def _drain(self):
         """Acquire every in-flight slot (waits for all dispatched rounds to

@@ -25,13 +25,12 @@ def create_batcher(engine, impl: str = "auto", dispatcher=None, **kwargs):
     batcher (the native queue pipelines in its own dispatch loop instead,
     so the kwarg is dropped for it).
 
-    The core check is measured, not theoretical (bench.py
-    --batcher-sweep): the native batcher's multi-in-flight pipeline
+    Why the core check: the native batcher's multi-in-flight pipeline
     spreads dispatch across threads (dispatcher, device sync, C++
     completion), and on a single-core host the GIL convoys those handoffs
-    -- the Python batcher's one-thread dispatch loop beats it at every
-    simulated device latency (0.5-10 ms).  The pipeline needs a second
-    core to pay off.
+    -- the Python batcher's one-thread dispatch loop beat it at every
+    simulated device latency (0.5-10 ms) on a stub device.  The pipeline
+    needs a second core to pay off.
     """
     import os
 

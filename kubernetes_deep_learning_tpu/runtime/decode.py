@@ -574,31 +574,23 @@ class Generation:
 class DecodeScheduler:
     """The per-step scheduler: admission queue in, token events out.
 
-    ``continuous=True`` (the lane's reason to exist): every loop
+    Continuous batching (the lane's reason to exist): every loop
     iteration first slot-fills freed decode slots from the queue (by
     (priority rank, absolute deadline) order -- same shed order as the
     image tier), then runs ONE batched step and fans the materialized
     tokens out to their generations.
-
-    ``continuous=False`` is the static request-boundary baseline the
-    ``--decode-ab`` bench arms against: admissions only happen when the
-    whole batch has drained, i.e. the classic serve-then-swap batch
-    server.  Same engine, same programs -- only the admission policy
-    differs, which is exactly the variable the A/B isolates.
     """
 
     def __init__(
         self,
         engine: DecodeEngine,
         *,
-        continuous: bool = True,
         registry: metrics_lib.Registry | None = None,
         recorder=None,
         tracer=None,
         queue_cap: int | None = None,
     ):
         self.engine = engine
-        self.continuous = continuous
         self.registry = registry
         self.recorder = recorder
         self.tracer = tracer
@@ -689,10 +681,8 @@ class DecodeScheduler:
     # --- the decode loop (single thread owns all device state) --------------
 
     def _admit_locked(self) -> list[Generation]:
-        """Pop admissible generations under the lock; continuous mode
-        slot-fills whatever is free, static mode waits for a full drain."""
-        if not self.continuous and self._live:
-            return []
+        """Pop admissible generations under the lock, slot-filling
+        whatever is free."""
         admitted: list[Generation] = []
         self._queue.sort(key=lambda g: g._order)  # type: ignore[attr-defined]
         remaining: list[Generation] = []

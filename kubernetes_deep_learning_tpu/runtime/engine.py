@@ -86,7 +86,7 @@ DONATE_ENV = "KDLT_DONATE"
 # with the cache off) paid a live XLA compile.  A wall-time threshold is
 # the honest signal available from outside XLA: cache hits are disk
 # reads (ms to ~100 ms even for the chunked big-bucket programs) while
-# the compiles they replace take 7-28 s on the v5e (BENCH_r05), and
+# the compiles they replace take tens of seconds on the v5e, and
 # enable_compile_cache sets min_compile_time_secs=0.5 so a program fast
 # enough to sit under the default threshold was never cache-eligible
 # anyway.
@@ -682,9 +682,8 @@ class InFlightDispatcher:
         The completion thread materializes in FIFO order, so one stuck
         handle blocks every later in-flight batch too -- this process
         needs a restart, its callers need another replica.  The watchdog
-        is the normal caller; chaos tooling (bench.py --chaos-ab's stall
-        arm) calls it directly to stage a wedged replica without waiting
-        out a real device hang.
+        is the normal caller; a test calls it directly to stage a wedged
+        replica without waiting out a real device hang.
         """
         self._stalled.set()
         with self._inflight_lock:
@@ -1534,8 +1533,8 @@ class InferenceEngine:
     def _flops_per_image(self, bucket: int) -> float | None:
         """FLOPs/image at one bucket shape, for the bucket audit.
 
-        Uses the NON-fused flax graph (bench.py's rule: cost analysis
-        cannot see inside Pallas custom calls) and the LOWERING-level
+        Uses the NON-fused flax graph (cost analysis cannot see inside
+        Pallas custom calls) and the LOWERING-level
         analysis -- trace only, never an XLA compile.  Families with no
         in-tree model (exported-only artifacts) raise inside; the audit
         reports None for them.

@@ -1,15 +1,13 @@
 """Device-peak table and FLOP counting.
 
-``peak_tflops`` / ``compiled_flops_per_image`` started life inside
-bench.py; this module is their runtime home (bench.py imports from here,
-the engine's status page reports the peak, the bucket audit the lowered
-FLOPs/image).  MFU itself is computed off-box: ``kdlt_engine_images_total``'s
+The engine's status page reports the peak, the bucket audit the lowered
+FLOPs/image.  MFU itself is computed off-box: ``kdlt_engine_images_total``'s
 rate x the audit page's FLOPs/image over the peak.  How busy the device is
 comes from the dispatcher's ``kdlt_pipeline_*_seconds_total`` counters.
 
-FLOPs come from XLA's own cost analysis of the **non-fused flax graph**
-(bench.py's rule: cost analysis cannot see inside Pallas custom calls, so
-the fused fast path under-reports).
+FLOPs come from XLA's own cost analysis of the **non-fused flax graph**:
+cost analysis cannot see inside Pallas custom calls, so the fused fast path
+under-reports (7.5 vs ~17 GFLOPs/img for Xception).
 """
 
 from __future__ import annotations
@@ -39,25 +37,6 @@ def peak_tflops(device, dtype_name: str) -> float | None:
     return None
 
 
-def compiled_flops_per_image(jitted, batch: int, *example_args) -> float | None:
-    """FLOPs/image of the compiled forward, from XLA's own cost analysis.
-
-    IMPORTANT: run this on the NON-fused (flax) forward -- XLA's cost
-    analysis does not see inside Pallas custom calls, so the fused fast
-    path under-reports (7.5 vs ~17 GFLOPs/img) and would overstate MFU's
-    denominator honesty check.
-    """
-    try:
-        ca = jitted.lower(*example_args).compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
-        flops = float(ca.get("flops", 0.0))
-        return flops / batch if flops > 0 else None
-    except Exception as e:  # noqa: BLE001 - cost analysis is best-effort
-        log.info("cost analysis unavailable: %r", e)
-        return None
-
-
 def lowered_flops_per_image(jitted, batch: int, *example_args) -> float | None:
     """FLOPs/image from the LOWERED (pre-compile) cost analysis.
 
@@ -65,9 +44,7 @@ def lowered_flops_per_image(jitted, batch: int, *example_args) -> float | None:
     so the runtime uses the lowering-level analysis: trace + HLO emission
     only, seconds of host time, no device involvement.  For the
     conv/attention families served here the flop count is dominated by ops
-    fusion does not remove, so it tracks the compiled figure closely
-    (bench.py still reports the compiled number offline; the acceptance
-    check is that the two MFUs agree within ~2 points).
+    fusion does not remove, so it tracks the compiled figure closely.
     """
     try:
         ca = jitted.lower(*example_args).cost_analysis()
@@ -75,6 +52,6 @@ def lowered_flops_per_image(jitted, batch: int, *example_args) -> float | None:
             ca = ca[0]
         flops = float(ca.get("flops", 0.0))
         return flops / batch if flops > 0 else None
-    except Exception as e:  # noqa: BLE001 - best-effort, like the compiled path
+    except Exception as e:  # noqa: BLE001 - cost analysis is best-effort
         log.info("lowered cost analysis unavailable: %r", e)
         return None

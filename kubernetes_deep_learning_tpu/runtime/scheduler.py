@@ -26,7 +26,7 @@ one place).  Across lanes a :class:`SchedulerPolicy` arbitrates:
 
 - ``fifo`` -- the naive baseline: whichever lane's head request arrived
   first.  Head-of-line blocking across models is the failure mode this
-  exists to demonstrate (bench.py --multimodel-ab's baseline arm).
+  exists to demonstrate.
 - ``weighted_deadline`` (default) -- earliest *effective* deadline first:
   a lane's urgency is its earliest absolute deadline minus the estimated
   service time of the batch (latest viable start), so a slow model's

@@ -17,9 +17,6 @@ spirit of Clockwork (OSDI '20) and DAGOR (SoCC '18):
 - ``controller``: the per-tier front door combining the above, the
   ``kdlt_admission_*`` metrics, and graceful drain (SIGTERM flips /readyz,
   stops admission, lets in-flight work finish).
-
-bench.py --overload-ab is the acceptance harness: goodput (in-deadline
-completions/s) under 2x offered load with admission on vs off.
 """
 
 from kubernetes_deep_learning_tpu.serving.admission.breaker import CircuitBreaker

@@ -11,8 +11,7 @@ tier's label.
 
 ``enabled=False`` (or KDLT_ADMISSION=0) keeps the controller as a pure
 in-flight tracker: no limiter, no deadline rejection -- the exact legacy
-behavior, which is what bench.py --overload-ab's baseline arm measures --
-but drain still works (shutdown semantics are not load policy).
+behavior -- but drain still works (shutdown semantics are not load policy).
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ from failure: nothing in the system can deliberately break a component, so
 (Basiri et al., "Chaos Engineering", IEEE Software '16) says recovery code
 that is never exercised is broken by default; this module makes breaking a
 component a one-env-var operation, deterministic enough to assert on in
-tests and the bench.py --chaos-ab harness.
+tests.
 
 Configuration: ``KDLT_FAULTS=point:kind:rate[:arg][,point:kind:rate[:arg]]``
 with ``KDLT_FAULTS_SEED`` (default 0) seeding the per-(point, kind) random

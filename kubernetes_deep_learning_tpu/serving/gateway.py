@@ -171,7 +171,6 @@ class Gateway:
         hedge_delay_ms: float | None = None,
         probe_interval_s: float | None = None,
         slo: bool | None = None,
-        slo_windows=None,
         cache: bool | None = None,
         cache_ttl_s: float | None = None,
         cache_max_mb: float | None = None,
@@ -182,7 +181,6 @@ class Gateway:
         brownout_enter: float | None = None,
         brownout_exit: float | None = None,
         brownout_dwell_s: float | None = None,
-        brownout_eval_s: float = BROWNOUT_EVAL_S,
         incident: bool | None = None,
         incident_dir: str | None = None,
         incident_triggers: str | None = None,
@@ -236,13 +234,7 @@ class Gateway:
         # burn-rate windows -- this tier sees what the user saw (including
         # failover/hedging saves the model tier's own view cannot know
         # about).  /debug/slo here also merges every replica's view.
-        # slo_windows overrides the (label, seconds) window pair -- benches
-        # compress hours of burn dynamics into seconds while keeping the
-        # "5m" label contract the brownout ladder and dashboards key on.
-        self.slo = slo_lib.SloEngine(
-            self.registry, tier="gateway", enabled=slo,
-            windows=slo_windows if slo_windows is not None else slo_lib.WINDOWS,
-        )
+        self.slo = slo_lib.SloEngine(self.registry, tier="gateway", enabled=slo)
         self._m_requests = self.registry.counter("kdlt_gateway_requests_total", "requests")
         self._m_errors = self.registry.counter("kdlt_gateway_errors_total", "errors")
         self._m_latency = self.registry.histogram(
@@ -281,7 +273,6 @@ class Gateway:
             burn_enter=brownout_enter, burn_exit=brownout_exit,
             dwell_s=brownout_dwell_s,
         )
-        self._brownout_eval_s = max(0.05, brownout_eval_s)
         self._brownout_stop = threading.Event()
         self._brownout_thread: threading.Thread | None = None
         if self.brownout.enabled:
@@ -362,7 +353,7 @@ class Gateway:
     # --- brownout control loop ---------------------------------------------
 
     def _brownout_loop(self) -> None:
-        while not self._brownout_stop.wait(self._brownout_eval_s):
+        while not self._brownout_stop.wait(BROWNOUT_EVAL_S):
             try:
                 prev_stage = self.brownout.stage
                 self.brownout.evaluate()

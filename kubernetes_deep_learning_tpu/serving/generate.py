@@ -107,7 +107,6 @@ class GenerateLane:
         slo=None,
         tracer=None,
         recorder=None,
-        continuous: bool = True,
         engine: DecodeEngine | None = None,
         engine_kwargs: dict | None = None,
         queue_cap: int | None = None,
@@ -121,7 +120,7 @@ class GenerateLane:
         self.slo = slo
         self.tracer = tracer
         self.scheduler = DecodeScheduler(
-            self.engine, continuous=continuous, registry=registry,
+            self.engine, registry=registry,
             recorder=recorder, tracer=tracer, queue_cap=queue_cap,
         )
         self.scheduler.start()
@@ -272,5 +271,4 @@ class GenerateLane:
                 "pages_in_use": self.engine.pages_in_use,
                 "pages_total": self.engine.num_pages - 1,
             },
-            "continuous": self.scheduler.continuous,
         }

@@ -1,7 +1,7 @@
 """Gateway-side upstream micro-batching: fat requests to the model tier.
 
-Throughput math that motivates this (measured with bench.py
---host-saturation): the model server is ONE Python process per accelerator, so its
+Throughput math that motivates this: the model server is ONE Python
+process per accelerator, so its
 HTTP/protocol handling is GIL-serialized -- per-request host cost caps its
 single-image ingest rate regardless of handler threads.  Gateways, by
 contrast, are stateless and scale horizontally (the reference's own replica

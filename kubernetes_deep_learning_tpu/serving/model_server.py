@@ -125,8 +125,8 @@ class ServedModel:
         pipeline_depth=None, scheduler=None, weight=None,
     ):
         # engine_factory: swap the execution engine (default InferenceEngine).
-        # runtime.stub.StubEngine measures the host path with the device
-        # taken out (bench.py --host-saturation).
+        # runtime.stub.StubEngine is the host path with the device taken
+        # out (the tests' engine).
         # scheduler: the server's shared UnifiedScheduler (runtime.scheduler)
         # -- when set and the engine supports async dispatch, this model
         # serves through a per-model scheduling lane + the tier's ONE shared
@@ -387,7 +387,6 @@ class ModelServer:
         incident_triggers: str | None = None,
         incident_dedup_s: float | None = None,
         decode: bool | None = None,
-        decode_continuous: bool = True,
         ingest: bool | None = None,
         decode_pool: int | None = None,
     ):
@@ -551,7 +550,7 @@ class ModelServer:
         if generate_lib.decode_enabled(decode):
             self.generate = generate_lib.GenerateLane(
                 registry=self.registry, slo=self.slo, tracer=self.tracer,
-                recorder=self.recorder, continuous=decode_continuous,
+                recorder=self.recorder,
             )
             self.recorder.add_snapshot_provider(
                 "decode", self.generate.debug_payload
@@ -1980,13 +1979,6 @@ def main(argv: list[str] | None = None) -> int:
         "name is $KDLT_DECODE_MODEL (gen-default)",
     )
     p.add_argument(
-        "--static-decode-batching",
-        action="store_true",
-        help="with --decode: replace continuous (token-boundary) batching "
-        "with static request-boundary batching -- the A/B baseline the "
-        "bench's --decode-ab compares against; never use in production",
-    )
-    p.add_argument(
         "--aot-warm",
         action="store_true",
         help="AOT-compile every model's FULL default bucket ladder into "
@@ -2093,7 +2085,6 @@ def main(argv: list[str] | None = None) -> int:
         ),
         slo=False if args.no_slo else None,
         decode=True if args.decode else None,
-        decode_continuous=not args.static_decode_batching,
     )
     # SIGTERM -> flip /readyz, stop admission, let in-flight batches finish,
     # then stop; fits inside the k8s terminationGracePeriodSeconds budget.

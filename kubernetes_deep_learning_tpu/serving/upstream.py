@@ -43,8 +43,7 @@ probe interval -- the drain window receives only hedges already in
 flight, never fresh primaries.
 
 ``KDLT_FAILOVER=0`` disables health/hedging/selection smarts (blind
-round-robin) -- the A/B baseline arm of ``bench.py --chaos-ab`` and
-``--churn-ab``.
+round-robin).
 
 The pool tracks a ``reference_spec``: the first model contract discovered
 from any replica.  Replicas must match it before serving traffic through

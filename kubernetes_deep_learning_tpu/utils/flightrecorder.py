@@ -28,9 +28,9 @@ evidence at the moment it happens (Dapper's lesson) at always-on cost
   utils/metrics.py (incident_metrics), nowhere else.
 
 The recorder is per-tier and constructor-injected (never process-global:
-the benches run a gateway and several model servers in one process).
+the tests run a gateway and several model servers in one process).
 ``KDLT_INCIDENT=0`` is the kill switch -- every hook degrades to a cheap
-no-op, which is what bench.py --incident-ab's recorder-off arm measures.
+no-op.
 """
 
 from __future__ import annotations

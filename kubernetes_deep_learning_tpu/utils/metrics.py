@@ -2,7 +2,7 @@
 
 The reference has no observability at all (SURVEY.md section 5: no /metrics,
 no structured logs); both tiers here expose a /metrics endpoint rendered from
-one of these registries, which also feeds bench.py's latency percentiles.
+one of these registries.
 """
 
 from __future__ import annotations
@@ -413,8 +413,8 @@ def crosshost_metrics(registry: "Registry") -> dict:
     """The cross-host round series (kdlt_crosshost_*), one set per serving
     engine/version (parallel.crosshost.CrossHostForward.attach_metrics).
 
-    Centralized like pipeline_stage_histograms so the leader, bench.py
-    --crosshost-ab, and dashboards key one set of names.  Stage semantics
+    Centralized like pipeline_stage_histograms so the leader and
+    dashboards key one set of names.  Stage semantics
     mirror the round protocol: ``broadcast`` is the leader's DCN
     control+payload broadcast (host-blocking, the part pipelining
     overlaps), ``collective`` is dispatch->device-completion of the SPMD
@@ -681,7 +681,7 @@ def admission_metrics(registry: "Registry") -> dict:
 
 # Serving-path fault tolerance (serving.upstream, serving.faults, the
 # dispatcher watchdog).  Centralized like the helpers above so the gateway
-# pool, the model tier, and bench.py --chaos-ab emit the SAME series names.
+# pool and the model tier emit the SAME series names.
 
 
 def upstream_pool_metrics(registry: "Registry") -> dict:
@@ -718,8 +718,8 @@ CACHE_EVICTION_REASONS = (
 def cache_metrics(registry: "Registry") -> dict:
     """The gateway-tier response-cache series (kdlt_cache_*).
 
-    Centralized like the helpers above so the cache, /debug/cache, and
-    bench.py --cache-ab key one set of names.  ``hits`` never touched
+    Centralized like the helpers above so the cache and /debug/cache
+    key one set of names.  ``hits`` never touched
     admission or the upstream; ``coalesced`` rode another request's
     flight (admitted-but-not-dispatched); ``misses`` paid the full path.
     """
@@ -828,8 +828,7 @@ INGEST_FALLBACK_REASONS = (
 def ingest_gateway_metrics(registry: "Registry") -> dict:
     """The gateway tier's raw-bytes ingest series (kdlt_ingest_*): how
     much traffic rides the bytes wire, why the rest fell back, and the
-    wire bytes actually shipped (the payload-diet receipt bench.py
-    --ingest-ab cross-checks)."""
+    wire bytes actually shipped (the payload-diet receipt)."""
     return {
         "bytes_requests": registry.counter(
             "kdlt_ingest_bytes_requests_total",
@@ -904,11 +903,10 @@ def pool_membership_metrics(registry: "Registry") -> dict:
     """Pool-level dynamic-membership series (kdlt_pool_*).
 
     Minted HERE and nowhere else (tools/check_metrics.py confines the
-    kdlt_pool_ prefix to this module) so the gateway pool and bench.py
-    --churn-ab key one set of names.  ``members`` counts replicas in
+    kdlt_pool_ prefix to this module).  ``members`` counts replicas in
     rotation OR quarantine (everything the resolver currently believes
-    in); joins/leaves count membership transitions, which is what the
-    churn bench's assertions and any flap alert key on.
+    in); joins/leaves count membership transitions, which is what any
+    flap alert keys on.
     """
     return {
         "members": registry.gauge(

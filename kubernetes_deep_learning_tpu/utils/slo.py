@@ -170,7 +170,6 @@ class SloEngine:
         enabled: bool | None = None,
         target: float | None = None,
         latency_objective_ms: float | None = None,
-        windows=WINDOWS,
         clock=time.monotonic,
     ):
         self.tier = tier
@@ -179,7 +178,7 @@ class SloEngine:
         self.latency_objective_ms = resolve_latency_objective_ms(
             latency_objective_ms
         )
-        self.windows = tuple(windows)
+        self.windows = WINDOWS
         self._max_window_s = max(s for _, s in self.windows)
         self._clock = clock
         self._lock = threading.Lock()

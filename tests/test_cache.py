@@ -189,7 +189,7 @@ def test_singleflight_wait_timeout_and_failure_propagation():
         flight.wait(1.0)
 
 
-def test_singleflight_finish_is_identity_checked():
+def test_singleflight_finish_is_identity_checked(monkeypatch):
     sf = cache_lib.SingleFlight()
     flight, _ = sf.begin("k")
     sf.finish("k", flight)
@@ -203,16 +203,48 @@ def test_singleflight_finish_is_identity_checked():
 # --- gateway wiring (stubbed fetch + upstream) -------------------------------
 
 
-def _stub_gateway(monkeypatch=None, upstream_delay_s=0.0, **kw):
-    """A bind=False Gateway whose fetch and upstream hop are stubbed; the
-    upstream call count is the singleflight/caching ground truth."""
+def _png_bytes() -> bytes:
+    import io
+
+    from PIL import Image
+
+    buf = io.BytesIO()
+    Image.fromarray(np.zeros((8, 8, 3), np.uint8)).save(buf, format="PNG")
+    return buf.getvalue()
+
+
+def _offline_gateway(monkeypatch, fetch, **kw):
+    """A bind=False Gateway that reaches nothing outside the process.
+
+    The download is stubbed at the one seam every fetch goes through on
+    either wire (ops/preprocess.fetch_image_bytes), and the model's contract
+    is in place as if discovered from a server that advertises no ingest
+    capability, so requests ride the tensor wire (_predict_batch, which the
+    tests stub) and decode here.
+    """
+    from kubernetes_deep_learning_tpu.modelspec import ModelSpec
+    from kubernetes_deep_learning_tpu.ops import preprocess
+
+    monkeypatch.setattr(
+        preprocess, "fetch_image_bytes", lambda url, *a, **k: fetch(url)
+    )
     gw = Gateway(
         serving_host="127.0.0.1:1", model="stub-model", bind=False, **kw
     )
-    calls = {"n": 0}
+    gw.pool.reference_spec = ModelSpec(
+        name="stub-model", family="xception",  # never instantiated
+        input_shape=(8, 8, 3), labels=("a", "b", "c"),
+    )
+    return gw
 
-    def fake_fetch(url):
-        return np.zeros((8, 8, 3), np.uint8)
+
+def _stub_gateway(monkeypatch, upstream_delay_s=0.0, **kw):
+    """An offline gateway whose fetch succeeds and whose upstream hop is
+    stubbed; the upstream call count is the singleflight/caching ground
+    truth."""
+    png = _png_bytes()
+    gw = _offline_gateway(monkeypatch, lambda url: png, **kw)
+    calls = {"n": 0}
 
     def fake_predict_batch(images, request_id="", deadline=None, trace=None,
                            model=None, priority=None):
@@ -223,13 +255,12 @@ def _stub_gateway(monkeypatch=None, upstream_delay_s=0.0, **kw):
             gw.cache.note_artifact_hash(model or gw.model, "hash-v1")
         return [np.arange(3, dtype=np.float32)], ["a", "b", "c"]
 
-    gw._fetch_one = fake_fetch
     gw._predict_batch = fake_predict_batch
     return gw, calls
 
 
-def test_gateway_hit_skips_upstream_and_admission_slot():
-    gw, calls = _stub_gateway()
+def test_gateway_hit_skips_upstream_and_admission_slot(monkeypatch):
+    gw, calls = _stub_gateway(monkeypatch)
     try:
         body = json.dumps({"url": "http://img/x.png"}).encode()
         s1, out1, _, h1 = gw.handle_predict(body, "rid-1")
@@ -255,7 +286,7 @@ def test_gateway_hit_skips_upstream_and_admission_slot():
 
 def test_gateway_kill_switch_disables_cache_and_coalescing(monkeypatch):
     monkeypatch.setenv(cache_lib.CACHE_ENV, "0")
-    gw, calls = _stub_gateway()
+    gw, calls = _stub_gateway(monkeypatch)
     try:
         assert gw.cache is None
         body = json.dumps({"url": "http://img/x.png"}).encode()
@@ -268,12 +299,10 @@ def test_gateway_kill_switch_disables_cache_and_coalescing(monkeypatch):
         gw.shutdown()
 
 
-def test_gateway_batch_requests_bypass_the_cache():
-    gw, calls = _stub_gateway()
+def test_gateway_batch_requests_bypass_the_cache(monkeypatch):
+    gw, calls = _stub_gateway(monkeypatch)
     try:
         body = json.dumps({"urls": ["http://img/x.png"]}).encode()
-        gw.pool.reference_spec = None  # spec_for is stubbed below
-        gw.spec_for = lambda model=None: None
         s1, _, _, h1 = gw.handle_predict(body, "rid-1")
         s2, _, _, h2 = gw.handle_predict(body, "rid-2")
         assert (s1, s2) == (200, 200)
@@ -284,8 +313,8 @@ def test_gateway_batch_requests_bypass_the_cache():
         gw.shutdown()
 
 
-def test_gateway_cache_bust_salt_coalesces_but_never_stores():
-    gw, calls = _stub_gateway()
+def test_gateway_cache_bust_salt_coalesces_but_never_stores(monkeypatch):
+    gw, calls = _stub_gateway(monkeypatch)
     try:
         body = json.dumps({"url": "http://img/x.png"}).encode()
         _, _, _, h1 = gw.handle_predict(body, "rid-1", cache_bust="salt-a")
@@ -306,10 +335,10 @@ def test_gateway_cache_bust_salt_coalesces_but_never_stores():
         gw.shutdown()
 
 
-def test_hung_flight_waiters_honor_their_own_deadlines():
+def test_hung_flight_waiters_honor_their_own_deadlines(monkeypatch):
     """ISSUE 8 satellite: a follower whose budget expires gets its OWN 504
     without cancelling the leader, whose flight completes and is cached."""
-    gw, calls = _stub_gateway(upstream_delay_s=1.0)
+    gw, calls = _stub_gateway(monkeypatch, upstream_delay_s=1.0)
     try:
         body = json.dumps({"url": "http://img/slow.png"}).encode()
         leader_result: dict = {}
@@ -345,8 +374,8 @@ def test_hung_flight_waiters_honor_their_own_deadlines():
         gw.shutdown()
 
 
-def test_concurrent_identical_requests_coalesce_to_one_upstream_call():
-    gw, calls = _stub_gateway(upstream_delay_s=0.25)
+def test_concurrent_identical_requests_coalesce_to_one_upstream_call(monkeypatch):
+    gw, calls = _stub_gateway(monkeypatch, upstream_delay_s=0.25)
     try:
         body = json.dumps({"url": "http://img/popular.png"}).encode()
         results: list = []
@@ -379,11 +408,11 @@ def test_concurrent_identical_requests_coalesce_to_one_upstream_call():
         gw.shutdown()
 
 
-def test_upstream_error_is_shared_with_followers_but_never_cached():
+def test_upstream_error_is_shared_with_followers_but_never_cached(monkeypatch):
     """ISSUE 8 satellite (cache x faults): a failed flight's error fans
     out to its waiters, but the NEXT request retries upstream -- errors
     must never be served from the cache."""
-    gw, calls = _stub_gateway()
+    gw, calls = _stub_gateway(monkeypatch)
     fail = {"on": True}
     real_predict = gw._predict_batch
 
@@ -416,11 +445,11 @@ def test_upstream_error_is_shared_with_followers_but_never_cached():
         gw.shutdown()
 
 
-def test_hot_reload_with_changed_bytes_evicts_cached_entries():
+def test_hot_reload_with_changed_bytes_evicts_cached_entries(monkeypatch):
     """ISSUE 8 satellite: the artifact hash is the invalidation key -- a
     reload with changed bytes drops the model's entries; a byte-identical
     version bump (same hash) keeps them."""
-    gw, calls = _stub_gateway()
+    gw, calls = _stub_gateway(monkeypatch)
     current = {"hash": "artifact-v1"}
     real_predict = gw._predict_batch
 
@@ -458,8 +487,8 @@ def test_hot_reload_with_changed_bytes_evicts_cached_entries():
         gw.shutdown()
 
 
-def test_debug_cache_endpoint_payload():
-    gw, _calls = _stub_gateway()
+def test_debug_cache_endpoint_payload(monkeypatch):
+    gw, _calls = _stub_gateway(monkeypatch)
     try:
         body = json.dumps({"url": "http://img/x.png"}).encode()
         gw.handle_predict(body, "rid-1")
@@ -483,11 +512,15 @@ def test_debug_cache_endpoint_payload():
 
 def test_debug_cache_reports_disabled_posture(monkeypatch):
     monkeypatch.setenv(cache_lib.CACHE_ENV, "0")
-    gw, _calls = _stub_gateway()
+    gw, _calls = _stub_gateway(monkeypatch)
     try:
         status, payload, _ = gw.handle_get("/debug/cache")
         assert status == 200
-        assert json.loads(payload) == {"enabled": False}
+        data = json.loads(payload)
+        # The response tier is off and says nothing else; the decoded-uint8
+        # tier is its own switch and still reports.
+        assert data.pop("decoded")["enabled"] is True
+        assert data == {"enabled": False}
     finally:
         gw.shutdown()
 
@@ -620,7 +653,7 @@ def test_negative_cache_disabled_when_ttl_zero():
     assert c.put("k", b"x", "t", "m", "h") is True
 
 
-def test_negative_cache_metrics_minted_centrally():
+def test_negative_cache_metrics_minted_centrally(monkeypatch):
     reg = metrics_lib.Registry()
     c = cache_lib.ResponseCache(registry=reg, ttl_s=60.0, max_mb=1.0,
                                 neg_ttl_s=5.0)
@@ -630,15 +663,9 @@ def test_negative_cache_metrics_minted_centrally():
     assert "kdlt_cache_negative_hits_total 1" in page
 
 
-def _failing_fetch_gateway(neg_ttl_s, fail_with=None, **kw):
-    """A stub gateway whose image fetch always fails (the hammered-bad-URL
-    scenario); ``fetches`` is the cost ground truth."""
-    from kubernetes_deep_learning_tpu.serving.gateway import UpstreamError
-
-    gw = Gateway(
-        serving_host="127.0.0.1:1", model="stub-model", bind=False,
-        cache_neg_ttl_s=neg_ttl_s, **kw
-    )
+def _failing_fetch_gateway(monkeypatch, neg_ttl_s, fail_with=None, **kw):
+    """An offline gateway whose image fetch always fails (the
+    hammered-bad-URL scenario); ``fetches`` is the cost ground truth."""
     fetches = {"n": 0}
 
     def fake_fetch(url):
@@ -647,12 +674,14 @@ def _failing_fetch_gateway(neg_ttl_s, fail_with=None, **kw):
             raise fail_with
         raise ValueError("404 Not Found fetching image")
 
-    gw._fetch_one = fake_fetch
+    gw = _offline_gateway(
+        monkeypatch, fake_fetch, cache_neg_ttl_s=neg_ttl_s, **kw
+    )
     return gw, fetches
 
 
-def test_gateway_negative_caches_repeated_bad_url():
-    gw, fetches = _failing_fetch_gateway(neg_ttl_s=5.0)
+def test_gateway_negative_caches_repeated_bad_url(monkeypatch):
+    gw, fetches = _failing_fetch_gateway(monkeypatch, neg_ttl_s=5.0)
     try:
         body = json.dumps({"url": "http://img/broken.png"}).encode()
         s1, out1, _, h1 = gw.handle_predict(body, "rid-1")
@@ -673,8 +702,8 @@ def test_gateway_negative_caches_repeated_bad_url():
         gw.shutdown()
 
 
-def test_gateway_negative_cache_expires_and_disabled_posture():
-    gw, fetches = _failing_fetch_gateway(neg_ttl_s=0.05)
+def test_gateway_negative_cache_expires_and_disabled_posture(monkeypatch):
+    gw, fetches = _failing_fetch_gateway(monkeypatch, neg_ttl_s=0.05)
     try:
         body = json.dumps({"url": "http://img/broken.png"}).encode()
         gw.handle_predict(body, "rid-1")
@@ -684,7 +713,7 @@ def test_gateway_negative_cache_expires_and_disabled_posture():
         assert fetches["n"] == 2  # expired: the bad URL is re-checked
     finally:
         gw.shutdown()
-    gw, fetches = _failing_fetch_gateway(neg_ttl_s=0.0)
+    gw, fetches = _failing_fetch_gateway(monkeypatch, neg_ttl_s=0.0)
     try:
         body = json.dumps({"url": "http://img/broken.png"}).encode()
         gw.handle_predict(body, "rid-1")
@@ -695,11 +724,11 @@ def test_gateway_negative_cache_expires_and_disabled_posture():
         gw.shutdown()
 
 
-def test_gateway_never_negative_caches_5xx():
+def test_gateway_never_negative_caches_5xx(monkeypatch):
     from kubernetes_deep_learning_tpu.serving.gateway import UpstreamError
 
     gw, fetches = _failing_fetch_gateway(
-        neg_ttl_s=5.0, fail_with=UpstreamError("replica down", http_status=502)
+        monkeypatch, neg_ttl_s=5.0, fail_with=UpstreamError("replica down", http_status=502)
     )
     try:
         body = json.dumps({"url": "http://img/x.png"}).encode()

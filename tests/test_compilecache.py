@@ -28,6 +28,38 @@ def restore_jax_cache_config(monkeypatch):
     jax_cc.reset_cache()
 
 
+def test_compile_cache_empty_env_is_unset_not_disable(monkeypatch):
+    from kubernetes_deep_learning_tpu.utils.compilecache import (
+        DEFAULT_CACHE_DIR,
+        resolve_cache_dir,
+    )
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.setenv("KDLT_COMPILE_CACHE_DIR", "")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/jax-cc")
+    # "" was once a disable sentinel and suppressed the fallback.
+    assert resolve_cache_dir() == "/tmp/jax-cc"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    # Nothing set: the one fixed path, <checkout>/.jax_cache -- never a
+    # temp dir, a pid or a clock.
+    assert resolve_cache_dir() == DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    # The explicit sentinels still disable everything downstream...
+    for sentinel in ("off", "none", "0", " OFF "):
+        monkeypatch.setenv("KDLT_COMPILE_CACHE_DIR", sentinel)
+        assert resolve_cache_dir() is None
+    # ...but never an explicit programmatic argument.
+    assert resolve_cache_dir("/tmp/explicit") == "/tmp/explicit"
+    # JAX's own variable, where set, beats ours, the flag and the argument
+    # (the flag IS the argument: main() passes --compile-cache-dir through).
+    monkeypatch.setenv("KDLT_COMPILE_CACHE_DIR", "/tmp/kdlt-cc")
+    assert resolve_cache_dir() == "/tmp/kdlt-cc"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/jax-cc")
+    assert resolve_cache_dir() == "/tmp/jax-cc"
+    assert resolve_cache_dir("/tmp/explicit") == "/tmp/jax-cc"
+    monkeypatch.setenv("KDLT_COMPILE_CACHE_DIR", "off")
+    assert resolve_cache_dir() == "/tmp/jax-cc"
+
+
 def test_enable_compile_cache_follows_the_contract_or_raises(
     tmp_path, monkeypatch, restore_jax_cache_config
 ):

@@ -162,7 +162,7 @@ def test_continuous_batch_streams_bit_identical_to_solo(engine):
         ("x", 12),
         ("long-ish prompt here", 6),
     ]
-    sched = decode_lib.DecodeScheduler(engine, continuous=True)
+    sched = decode_lib.DecodeScheduler(engine)
     sched.start()
     streamed: dict[int, list[int]] = {}
 
@@ -189,8 +189,36 @@ def test_continuous_batch_streams_bit_identical_to_solo(engine):
         )
 
 
+def test_freed_slot_is_refilled_before_the_batch_drains(engine):
+    """Admission is at token boundaries: with both slots taken, a queued
+    request starts when the SHORT neighbour retires, while the long one is
+    still decoding -- not when the whole batch has drained (the
+    serve-then-swap batch server, whose queued requests wait out the longest
+    member)."""
+    long_prompt, long_budget = "a much longer prompt", 10
+    assert len(engine.decode_solo(long_prompt, long_budget)) == long_budget
+    sched = decode_lib.DecodeScheduler(engine)
+    # Queued before the loop starts, so admission order is submission order:
+    # the long and the short one take the two slots, the third waits.
+    long_gen = sched.submit(long_prompt, long_budget, rid="long")
+    short_gen = sched.submit("short", 2, rid="short")
+    queued_gen = sched.submit("x", 2, rid="queued")
+    sched.start()
+    try:
+        for gen in (long_gen, short_gen, queued_gen):
+            events = list(gen.iter_events(timeout_s=60.0))
+            assert events[-1][0] == "done", (gen.rid, events)
+    finally:
+        sched.close()
+    assert len(long_gen.tokens) == long_budget
+    assert queued_gen.tokens == engine.decode_solo("x", 2)
+    # The queued request's first token came after the short one's last (it
+    # took that slot) and before the long one's last (nobody waited for it).
+    assert short_gen.t_last <= queued_gen.t_first < long_gen.t_last
+
+
 def test_scheduler_submit_rejects_oversize_prompts(engine):
-    sched = decode_lib.DecodeScheduler(engine, continuous=True)
+    sched = decode_lib.DecodeScheduler(engine)
     # 40 chars + budget 10 > the 32-token context (with BOS): a 400, not
     # an admission.
     with pytest.raises(ValueError):
@@ -199,7 +227,7 @@ def test_scheduler_submit_rejects_oversize_prompts(engine):
 
 
 def test_scheduler_queue_cap_sheds_with_queuefull(engine):
-    sched = decode_lib.DecodeScheduler(engine, continuous=True, queue_cap=1)
+    sched = decode_lib.DecodeScheduler(engine, queue_cap=1)
     # Loop NOT started: the first admission sits in the queue, the second
     # hits the cap.
     sched.submit("a", 2)
@@ -209,7 +237,7 @@ def test_scheduler_queue_cap_sheds_with_queuefull(engine):
 
 
 def test_expired_deadline_finishes_as_deadline_without_tokens(engine):
-    sched = decode_lib.DecodeScheduler(engine, continuous=True)
+    sched = decode_lib.DecodeScheduler(engine)
     sched.start()
     gen = sched.submit("abc", 4, deadline=Deadline(0.0))
     events = list(gen.iter_events(timeout_s=30.0))
@@ -219,7 +247,7 @@ def test_expired_deadline_finishes_as_deadline_without_tokens(engine):
 
 
 def test_cancel_stops_a_queued_generation(engine):
-    sched = decode_lib.DecodeScheduler(engine, continuous=True)
+    sched = decode_lib.DecodeScheduler(engine)
     gen = sched.submit("abc", 4)
     gen.cancel()
     sched.start()

@@ -11,8 +11,11 @@ the class of mistakes the reference's guide debugs by kubectl-eye
 
 from __future__ import annotations
 
+import ast
+import io
 import os
 import re
+import tokenize
 
 import pytest
 import yaml
@@ -43,6 +46,86 @@ def test_dockerfile_copy_sources_exist():
             assert os.path.exists(os.path.join(REPO, src)), (
                 f"{name}: COPY source {src!r} does not exist in the build context"
             )
+
+
+# --- no document names a file that is not there ------------------------------
+
+# Names that are not files of this repository, each with what it is.
+_NOT_OURS = {
+    "test.py": "the reference repository's client script",
+    "convert.py": "the reference repository's Keras -> SavedModel script",
+    "spec.json": "a file of the artifact layout, written by the exporter",
+    "metadata.json": "a file of the artifact layout, written by the exporter",
+    "bundle.json": "an example name for an incident bundle copied off a pod",
+}
+_SKIP_DIRS = {"__pycache__", "build", "tfs_gen", "chiprun_out"}
+_PROSE_SUFFIXES = (".py", ".md", ".yaml", ".yml", ".dockerfile", ".toml", ".cc", ".h")
+_FILE_NAME = re.compile(
+    r"(?<![\w./*<>{}$-])((?:[\w.-]+/)*[\w.-]+\.(?:py|json))(?![\w*])"
+)
+_PACKAGE = os.path.join(REPO, "kubernetes_deep_learning_tpu")
+
+
+def _walk(top):
+    for dirpath, dirnames, filenames in os.walk(top):
+        dirnames[:] = [
+            d for d in dirnames if d not in _SKIP_DIRS and not d.startswith(".")
+        ]
+        for name in filenames:
+            yield os.path.join(dirpath, name)
+
+
+def _prose(path):
+    """What a file says to its reader: all of a document or manifest, the
+    comments and docstrings of a Python file (its code and string literals
+    are data: fixture names, dictionary keys, attribute reads)."""
+    text = _read(path)
+    if not path.endswith(".py"):
+        return text
+    parts = [
+        tok.string
+        for tok in tokenize.generate_tokens(io.StringIO(text).readline)
+        if tok.type == tokenize.COMMENT
+    ]
+    for node in ast.walk(ast.parse(text)):
+        if isinstance(
+            node, (ast.Module, ast.ClassDef, ast.FunctionDef, ast.AsyncFunctionDef)
+        ):
+            parts.append(ast.get_docstring(node, clean=False) or "")
+    return "\n".join(parts)
+
+
+@pytest.mark.parametrize(
+    "source",
+    [
+        "README.md", "GUIDE.md", "exp/README.md",
+        ".claude/skills/verify/SKILL.md", "deploy",
+        "kubernetes_deep_learning_tpu", "tools", "tests",
+    ],
+)
+def test_documents_name_only_files_that_exist(source):
+    """Every ``*.py`` or ``*.json`` a document, manifest, comment or docstring
+    names is in the tree: a bare name is some file's name, a path resolves
+    from the checkout or from the package.  ROADMAP.md, CHANGES.md and PERF.md
+    are history and are not read."""
+    basenames = {os.path.basename(f) for f in _walk(REPO)}
+    top = os.path.join(REPO, source)
+    files = [top] if os.path.isfile(top) else [
+        f for f in _walk(top) if f.endswith(_PROSE_SUFFIXES)
+    ]
+    assert files, source
+    missing = set()
+    for path in files:
+        for name in _FILE_NAME.findall(_prose(path)):
+            if "/" not in name:
+                if name not in basenames and name not in _NOT_OURS:
+                    missing.add((os.path.relpath(path, REPO), name))
+                continue
+            first = name.split("/")[0]
+            roots = [r for r in (REPO, _PACKAGE) if os.path.isdir(os.path.join(r, first))]
+            if roots and not any(os.path.exists(os.path.join(r, name)) for r in roots):
+                missing.add((os.path.relpath(path, REPO), name))
+    assert not missing, f"named but not in the tree: {sorted(missing)}"
 
 
 def test_dockerfile_entrypoints_are_real_console_scripts():
@@ -226,8 +309,8 @@ def test_k8s_model_tier_replicated_for_failover():
 
 def test_compose_has_second_model_replica_wired_for_failover():
     """docker-compose: two model-server replicas, the gateway's
-    KDLT_SERVING_HOST listing both, hedging configured -- the compose-local
-    topology bench.py --chaos-ab models."""
+    KDLT_SERVING_HOST listing both, hedging configured -- the topology
+    tests/test_failover.py kills a replica of."""
     from kubernetes_deep_learning_tpu.serving.gateway import SERVING_HOST_ENV
     from kubernetes_deep_learning_tpu.serving.upstream import (
         HEDGE_DELAY_ENV,

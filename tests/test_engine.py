@@ -138,8 +138,8 @@ def test_fast_compile_failure_degrades_to_exact_graph(engine):
 
     fast=True on the CPU backend is a REAL reproduction, not a mock: the
     Pallas TPU kernel cannot lower for CPU outside interpret mode, so the
-    first warmup bucket raises at compile exactly like BENCH_r02's batch-1
-    Mosaic rejection did on TPU.
+    first warmup bucket raises at compile exactly like a batch-1 Mosaic
+    rejection did on TPU.
     """
     _, variables, spec = engine
     import jax

@@ -1,7 +1,7 @@
 """Fused sepconv kernel + Xception fast path, validated on CPU.
 
 The Pallas kernel runs in interpret mode here (tests are hermetic-CPU,
-conftest.py); the real-TPU speed claim is bench.py's job.  What IS pinned
+conftest.py); speed is the benchmark's business (perfbench/).  What IS pinned
 here: kernel-vs-reference numerics, BN folding against flax.linen.BatchNorm
 (including the Keras-parity epsilon), batch-tile picking rules, and the
 full fast-forward's logits against the stock flax graph on the same
@@ -39,7 +39,7 @@ def _random_block_weights(rng, c):
     [
         (4, 6, 6, 256),
         (2, 5, 7, 128),
-        # non-8-multiple batches (the serving buckets that killed BENCH_r02)
+        # non-8-multiple batches (serving buckets whose tile Mosaic refused)
         # run via sublane padding and must match on the real rows
         (1, 6, 6, 128),
         (3, 6, 6, 128),
@@ -94,7 +94,7 @@ def test_pick_batch_tile_rules():
     # huge spatial extents fall back to the smallest aligned tile
     assert pick_batch_tile(256, 74, 74, 728) == 8
     # NEVER a non-8-multiple: Mosaic rejects the kernel's (H, W, bt) row
-    # collapse for unaligned bt (BENCH_r02's batch-1 failure).  Unaligned
+    # collapse for unaligned bt (a batch-1 tile failed on the v5e).  Unaligned
     # batches are padded by the kernel wrappers, which then see a multiple
     # of 8 -- but pick_batch_tile itself must stay safe for any input.
     assert pick_batch_tile(6, 19, 19, 728) == 8
@@ -248,7 +248,7 @@ def test_chunked_fast_forward_matches_monolithic(fast_spec, monkeypatch):
     """The chunk wrapper (slice -> forward_one -> concat) must be a pure
     batching identity.  Scaled down (chunk=1 over batch 2) so interpret-mode
     cost stays test-sized; the production chunk geometry (16 over 32-64) is
-    exercised on real TPU by bench.py's sweep."""
+    run by no cell of the benchmark (ROADMAP D17)."""
     from kubernetes_deep_learning_tpu.models import xception_fast
     from kubernetes_deep_learning_tpu.ops.preprocess import normalize
 

@@ -385,8 +385,8 @@ def test_production_tree_lints_clean_within_budget(capsys):
     bad = active(findings)
     assert bad == [], "\n".join(f.format() for f in bad)
     # Every suppression that survives review carries a justification; the
-    # count is asserted loosely so adding one is a conscious test edit.
-    assert len([f for f in findings if f.suppressed]) <= 8
+    # count is the number that stand, so adding one is a conscious test edit.
+    assert len([f for f in findings if f.suppressed]) <= 9
     assert elapsed < 10.0, f"full-tree lint took {elapsed:.1f}s (budget 10s)"
 
 

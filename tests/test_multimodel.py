@@ -257,7 +257,8 @@ def test_client_cli_passes_model(monkeypatch, capsys):
     calls = {}
 
     def fake_predict_url(gateway, image_url, retries=2, deadline_ms=None,
-                         stats=None, model=None, cache_bust=None):
+                         stats=None, model=None, cache_bust=None,
+                         priority=None):
         calls.update(model=model)
         return {"x": 1.0}
 

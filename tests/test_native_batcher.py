@@ -63,7 +63,7 @@ def test_create_batcher_auto_respects_core_count(monkeypatch):
     finally:
         b.close()
     # On a single-core host the GIL convoys the native pipeline's
-    # cross-thread handoffs (measured: bench.py --batcher-sweep), so auto
+    # cross-thread handoffs, so auto
     # degrades to the one-thread Python dispatcher.
     monkeypatch.setattr(os, "cpu_count", lambda: 1)
     if hasattr(os, "sched_getaffinity"):

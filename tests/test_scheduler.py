@@ -198,6 +198,7 @@ def _lane(name, weight=1.0, cost_s=0.0, deadlines=(), enq_ts=(), served=0.0):
         u = SimpleNamespace(
             n=1, deadline_abs=None if d is None else now + d,
             enq_t=now + (enq_ts[i] if i < len(enq_ts) else 0.0),
+            priority=None,  # no class: no slack on the effective deadline
         )
         lane.queue.append(u)
         lane.pending_images += 1

@@ -21,7 +21,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from kdlt_lint.core import (  # noqa: E402,F401
-    EXTRA_FILES,
     PACKAGE,
     REPO,
     SKIP_PARTS,

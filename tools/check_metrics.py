@@ -30,7 +30,6 @@ from kdlt_lint.passes.metrics_names import (  # noqa: E402,F401
     MetricsNamingPass,
 )
 from kdlt_lint.core import (  # noqa: E402,F401
-    EXTRA_FILES,
     PACKAGE,
     REPO,
     SKIP_PARTS,

@@ -17,7 +17,6 @@ from dataclasses import dataclass, field
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 PACKAGE = "kubernetes_deep_learning_tpu"
-EXTRA_FILES = ("bench.py",)
 SKIP_PARTS = {"tfs_gen", "__pycache__"}
 
 SUPPRESS_RE = re.compile(
@@ -196,11 +195,7 @@ class LintPass:
 
 
 def iter_production_files(repo: str = REPO) -> list[str]:
-    files: list[str] = [
-        os.path.join(repo, f)
-        for f in EXTRA_FILES
-        if os.path.exists(os.path.join(repo, f))
-    ]
+    files: list[str] = []
     for dirpath, dirnames, filenames in os.walk(os.path.join(repo, PACKAGE)):
         dirnames[:] = [d for d in dirnames if d not in SKIP_PARTS]
         files.extend(
