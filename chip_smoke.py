@@ -643,6 +643,7 @@ def smoke(run: Run, args) -> dict:
         "data_parallel": args.data_parallel,
         "batch_rows_per_device": st.get("batch_rows_per_device"),
         "fast_engaged": st["fast_engaged"],
+        "fused_blocks": st.get("fused_blocks"),
         "fast_degraded": 0,
         "peak_tflops": st["peak_tflops"],
         "batcher": st["batcher"],

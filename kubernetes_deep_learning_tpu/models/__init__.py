@@ -67,6 +67,17 @@ def has_fast_forward(spec: ModelSpec) -> bool:
     return spec.family == "xception"
 
 
+def fused_blocks(spec: ModelSpec, batch: int) -> list[str]:
+    """Names of the blocks the fast path's program for ``batch`` images
+    runs as Pallas kernels (the status page's ``fused_blocks``): the same
+    shape arithmetic the forward asks while it traces."""
+    if not has_fast_forward(spec):
+        return []
+    from kubernetes_deep_learning_tpu.models import xception_fast
+
+    return xception_fast.fused_blocks(spec, batch)
+
+
 def resolve_fast(
     spec: ModelSpec, dtype: Any, fast: bool | str, backend: str | None = None
 ) -> bool:

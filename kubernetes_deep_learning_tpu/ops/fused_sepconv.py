@@ -99,6 +99,60 @@ def pick_batch_tile(batch: int, h: int, w: int, c: int, budget_bytes: int = 9 <<
     return 8
 
 
+# What a chain whose whole-extent tile is larger than the serving default
+# (96 MiB, _compiler_params) may ask of the v5e's 128 MiB of VMEM.
+CHAIN_VMEM_LIMIT_BYTES = 116 << 20
+
+
+def chain_vmem_bytes(h: int, w: int, bt: int, widths: tuple[int, ...]) -> int:
+    """VMEM one grid step of ``fused_sepconv_chain_t`` holds for a
+    (h, w, bt, widths[0]) tile whose stages are widths[i] -> widths[i+1]:
+    the input and output tiles (bf16, double-buffered by the pipeline), the
+    weights (likewise), and the widest float32 value of the body (a
+    depthwise sum or a GEMM's output).  Channels ride the lanes, so a
+    width counts as its multiple of 128.  Within 5% of what Mosaic reports
+    for the v5e at the four tiles read (``exp/entry_chains.py --describe``:
+    205.2 and 159.9 MiB asked against 214.3 and 152.7 reckoned)."""
+    lanes = [-(-c // 128) * 128 for c in widths]
+    rows = h * w * bt
+    tiles = 2 * rows * (lanes[0] + lanes[-1]) * 2
+    weights = 2 * sum(
+        9 * ci * 4 + ci * co * 2 + 2 * co * 4 for ci, co in zip(lanes, lanes[1:])
+    )
+    return tiles + weights + rows * max(lanes) * 4
+
+
+# The smallest padded batch an entry chain runs at: what exp/entry_chains.py
+# has timed on the chip and seen win (PERF.md section 6, PR 31).  Below it
+# XLA keeps a program's small activations and weights in VMEM beside the
+# kernel (``S(1)`` in the compiled bucket-16 and -64 programs), a chain that
+# really holds 107 of the 128 MiB leaves them no room, and one bucket-64
+# arrangement never returned from the chip.
+CHAIN_MIN_BATCH = 256
+
+
+def chain_batch_tile(batch: int, h: int, w: int, widths: tuple[int, ...]) -> int:
+    """Whether a downsample block's two sepconv stages should run as one
+    Pallas chain over (h, w, batch, widths[0]), by shape arithmetic alone:
+    the batch tile to run it with, or 0 to leave the block to XLA.
+
+    A chain wants every stage's channels to fill the 128 lanes (Xception's
+    block 2 reads 64: its depthwise stencil would run on half-empty
+    vectors, the negative result of exp/fused_entry.py), a batch of at
+    least ``CHAIN_MIN_BATCH``, and its whole-extent tile, reckoned 5% high
+    because ``chain_vmem_bytes`` may read that much low, inside the limit
+    the kernel is compiled with -- what the rule admits must compile, or
+    warm-up degrades the whole engine to the flax graph.  ``batch`` is the
+    padded batch (a multiple of 8).
+    """
+    if min(widths) < 128 or batch < CHAIN_MIN_BATCH:
+        return 0
+    for bt in (16, 8):
+        if batch % bt == 0 and 1.05 * chain_vmem_bytes(h, w, bt, widths) <= CHAIN_VMEM_LIMIT_BYTES:
+            return bt
+    return 0
+
+
 def sepconv_block_reference(x, dw, pw, scale, shift):
     """Plain-jnp semantics of the fused kernel (NHWC), for tests and CPU."""
     import jax.numpy as jnp
@@ -212,10 +266,10 @@ def _compiler_params(limit_bytes: int = 96 * 1024 * 1024) -> Any:
     from jax.experimental.pallas import tpu as pltpu
 
     # The default 16 MiB scoped-vmem cap rejects the bt=16 tile; v5e has
-    # 128 MiB physical VMEM.  Default 96 MiB: the serving path's largest
-    # tile needs far less, and it leaves headroom below the physical cap.
-    # Only the experimental entry path's block3 chain (74x74, 128->256
-    # channels, peaks ~107 MiB at bt=8) requests 110 explicitly.
+    # 128 MiB physical VMEM.  Default 96 MiB: the middle and exit flows'
+    # tiles need far less, and it leaves headroom below the physical cap.
+    # Only the entry flow's chains ask for more (CHAIN_VMEM_LIMIT_BYTES):
+    # block 3's (74x74, 128->256 channels) holds ~107 MiB at bt=8.
     return pltpu.CompilerParams(vmem_limit_bytes=limit_bytes)
 
 

@@ -1461,13 +1461,33 @@ class InferenceEngine:
             }
         return self._batch_rows
 
+    def fused_blocks(self) -> dict[str, list[str]]:
+        """By warmed bucket, the blocks its program runs as Pallas kernels:
+        what the fast forward decided while it traced (the same shape
+        arithmetic, models.fused_blocks); empty off the fused path.  A
+        mesh engine's forward sees one shard's rows."""
+        if not self._fast_engaged:
+            return {}
+        from kubernetes_deep_learning_tpu.models import fused_blocks
+
+        shards = 1
+        if self.mesh is not None:
+            from kubernetes_deep_learning_tpu.parallel.mesh import DATA_AXIS
+
+            shards = self.mesh.shape[DATA_AXIS]
+        return {
+            str(b): fused_blocks(self.spec, b // shards)
+            for b in sorted(self._warm_bucket_seconds)
+        }
+
     def device_info(self) -> dict[str, Any]:
         """The status surface that keeps a green boot honest (GET
         /v1/models): the device as JAX reports it, its dense peak (None = a
         device_kind the table does not know), whether the fused path is in
-        the served programs or was degraded away, each bucket's warm-up
-        seconds, and the device's memory as its allocator reports it
-        (absent where the backend reports none, as the CPU's does)."""
+        the served programs or was degraded away and which blocks each
+        warmed bucket's program fuses, each bucket's warm-up seconds, and
+        the device's memory as its allocator reports it (absent where the
+        backend reports none, as the CPU's does)."""
         import jax
 
         info = {
@@ -1479,6 +1499,7 @@ class InferenceEngine:
             ),
             "fast_engaged": bool(self._fast_engaged),
             "fast_degraded": bool(self.fast_degraded),
+            "fused_blocks": self.fused_blocks(),
             "warm": dict(self.warm_report),
         }
         stats = self._device.memory_stats()

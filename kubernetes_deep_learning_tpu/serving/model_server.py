@@ -118,6 +118,15 @@ def resolve_mesh_model_parallel(explicit: int = 0) -> int:
         return 1
 
 
+def _warmed_line(what: str, seconds: float, engine) -> str:
+    """The boot log's line for one warmed model; where the fused path is
+    engaged it names the blocks each bucket's program runs as Pallas
+    kernels (the status page's ``fused_blocks``)."""
+    fused = getattr(engine, "fused_blocks", dict)()
+    tail = f", fused blocks {json.dumps(fused)}" if fused else ""
+    return f"warmed {what}: {seconds:.1f}s{tail}"
+
+
 class ServedModel:
     def __init__(
         self, artifact, buckets, max_delay_ms, registry, use_batcher=True,
@@ -576,7 +585,7 @@ class ModelServer:
                 # the boot's compile record -- with a no-op's.
                 continue
             dt = m.engine.warmup()
-            print(f"warmed {m.artifact.spec.name}: {dt:.1f}s", file=sys.stderr)
+            print(_warmed_line(m.artifact.spec.name, dt, m.engine), file=sys.stderr)
         if self.generate is not None:
             rep = self.generate.warmup()
             total = sum(rep["buckets"].values()) + rep["step_s"]
@@ -665,7 +674,7 @@ class ModelServer:
         )
         try:
             warm_s = fresh.engine.warmup()
-            print(f"warmed {name} v{version}: {warm_s:.1f}s", file=sys.stderr)
+            print(_warmed_line(f"{name} v{version}", warm_s, fresh.engine), file=sys.stderr)
         except Exception:
             # Warmup failed post-construction: the registry skips this
             # version (and retries next poll); the orphaned child registry

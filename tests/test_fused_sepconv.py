@@ -17,7 +17,11 @@ import pytest
 
 from kubernetes_deep_learning_tpu.models import build_forward, init_variables
 from kubernetes_deep_learning_tpu.modelspec import ModelSpec, register_spec
+from kubernetes_deep_learning_tpu.ops import fused_sepconv
 from kubernetes_deep_learning_tpu.ops.fused_sepconv import (
+    CHAIN_VMEM_LIMIT_BYTES,
+    chain_batch_tile,
+    chain_vmem_bytes,
     fold_bn,
     fused_sepconv_block,
     middle_block_weights,
@@ -100,6 +104,127 @@ def test_pick_batch_tile_rules():
     assert pick_batch_tile(6, 19, 19, 728) == 8
     assert pick_batch_tile(12, 19, 19, 728) == 8
     assert pick_batch_tile(1, 19, 19, 728) == 8
+
+
+@pytest.mark.parametrize("batch", [1, 8, 16, 24, 64, 256, 512])
+def test_chain_rule_at_the_cell_shapes(batch):
+    """The deciding function at Xception 299x299 (the benchmark's cell runs
+    batch 512): block 2 reads 64 channels, half the lanes, and stays on XLA
+    at every batch; blocks 3 (74x74, 128 -> 256) and 4 (37x37, 256 -> 728)
+    hold their whole extent at the batch tile 8 and chain from a padded
+    batch of 256 on -- what the chip has timed and seen win; the small
+    buckets (64 runs as 16-image chunks, each deciding for itself) keep
+    the XLA entry flow."""
+    from kubernetes_deep_learning_tpu.models.xception_fast import (
+        chained_entry_blocks,
+        fused_blocks,
+    )
+
+    padded = batch + (-batch) % 8
+    tile = 8 if batch >= 256 else 0
+    assert chain_batch_tile(padded, 147, 147, (64, 128, 128)) == 0
+    assert chain_batch_tile(padded, 74, 74, (128, 256, 256)) == tile
+    assert chain_batch_tile(padded, 37, 37, (256, 728, 728)) == tile
+    assert chained_entry_blocks((299, 299), batch) == ({3: 8, 4: 8} if tile else {})
+    spec = ModelSpec(name="x299", family="xception", input_shape=(299, 299, 3),
+                     labels=("a",), preprocessing="tf")
+    assert fused_blocks(spec, batch) == [f"block{i}" for i in range(3 if tile else 5, 15)]
+
+
+def test_chain_rule_is_the_tile_against_the_vmem_limit():
+    # what the rule compares: tiles in and out twice, weights twice, the
+    # widest float32 value; widths counted in whole 128-lane tiles
+    rows = 37 * 37 * 8
+    weights = 2 * ((9 * 256 * 4 + 256 * 768 * 2 + 2 * 768 * 4)
+                   + (9 * 768 * 4 + 768 * 768 * 2 + 2 * 768 * 4))
+    assert chain_vmem_bytes(37, 37, 8, (256, 728, 728)) == (
+        2 * rows * (256 + 768) * 2 + weights + rows * 768 * 4
+    )
+    # block 3's tile at bt=8 is inside the limit with the rule's 5% to
+    # spare (Mosaic compiles it at 110 MiB and not at 96), its bt=16 tile
+    # and block 2's at full width are far outside
+    assert 1.05 * chain_vmem_bytes(74, 74, 8, (128, 256, 256)) <= CHAIN_VMEM_LIMIT_BYTES
+    assert chain_vmem_bytes(74, 74, 16, (128, 256, 256)) > CHAIN_VMEM_LIMIT_BYTES
+    assert chain_batch_tile(512, 147, 147, (128, 128, 128)) == 0
+    # a small extent takes the larger tile where the batch divides by it
+    assert chain_batch_tile(256, 12, 12, (256, 728, 728)) == 16
+    assert chain_batch_tile(264, 12, 12, (256, 728, 728)) == 8
+    assert chain_batch_tile(248, 12, 12, (256, 728, 728)) == 0  # under the least batch
+    # and an extent between the flagship's blocks 2 and 3 goes back to XLA
+    assert chain_batch_tile(512, 90, 90, (128, 256, 256)) == 0
+
+
+def _downsample_block_weights(rng, c_in, c_out, block="blockX"):
+    """One downsample block's leaves as the flax tree names them."""
+    p, s = {}, {}
+
+    def bn(name, c):
+        p[name] = {"scale": rng.uniform(0.8, 1.2, c).astype(np.float32),
+                   "bias": rng.normal(0, 0.1, c).astype(np.float32)}
+        s[name] = {"mean": rng.normal(0, 0.1, c).astype(np.float32),
+                   "var": rng.uniform(0.5, 1.5, c).astype(np.float32)}
+
+    p[f"{block}_res_conv"] = {
+        "kernel": rng.normal(0, c_in ** -0.5, (1, 1, c_in, c_out)).astype(np.float32)}
+    bn(f"{block}_res_bn", c_out)
+    for j, ci in ((1, c_in), (2, c_out)):
+        p[f"{block}_sepconv{j}"] = {
+            "depthwise": {"kernel": rng.normal(0, 0.3, (3, 3, 1, ci)).astype(np.float32)},
+            "pointwise": {
+                "kernel": rng.normal(0, ci ** -0.5, (1, 1, ci, c_out)).astype(np.float32)},
+        }
+        bn(f"{block}_sepconv{j}_bn", c_out)
+    return p, s
+
+
+def _downsample_block_xla(x, p, s, block="blockX"):
+    """The XLA arrangement of the same block, NHWC bf16, written out here:
+    relu -> sepconv -> bn twice, max_pool SAME 3x3/2, strided 1x1 residual."""
+    import flax.linen as nn
+
+    from kubernetes_deep_learning_tpu.models.layers import KERAS_BN_EPS
+
+    bf = jnp.bfloat16
+
+    def conv(x, kernel, stride=1, groups=1):
+        return jax.lax.conv_general_dilated(
+            x.astype(bf), jnp.asarray(kernel, bf), (stride, stride), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"), feature_group_count=groups)
+
+    def bn(x, name):
+        mean, var = (jnp.asarray(s[name][k], bf) for k in ("mean", "var"))
+        scale, bias = (jnp.asarray(p[name][k], bf) for k in ("scale", "bias"))
+        return (x - mean) * jax.lax.rsqrt(var + jnp.asarray(KERAS_BN_EPS, bf)) * scale + bias
+
+    residual = bn(conv(x, p[f"{block}_res_conv"]["kernel"], stride=2), f"{block}_res_bn")
+    for j in (1, 2):
+        sep = p[f"{block}_sepconv{j}"]
+        x = conv(nn.relu(x), sep["depthwise"]["kernel"], groups=x.shape[-1])
+        x = bn(conv(x, sep["pointwise"]["kernel"]), f"{block}_sepconv{j}_bn")
+    return nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME") + residual
+
+
+@pytest.mark.parametrize("batch", [1, 8, 24])
+@pytest.mark.parametrize("extent", [9, 10])
+def test_downsample_t_matches_the_xla_block(extent, batch):
+    """downsample_t (the fused chain + pool + residual of blocks 3, 4, 13)
+    against the XLA arrangement of the same block, at an odd and an even
+    extent (9 -> 5, 10 -> 5: SAME pooling pads differently) and at batches
+    the kernel pads (1), takes whole (8) and walks in three tiles (24)."""
+    from kubernetes_deep_learning_tpu.models.xception_fast import downsample_t
+
+    rng = np.random.default_rng(31 + extent + batch)
+    c_in, c_out = 128, 256
+    p, s = _downsample_block_weights(rng, c_in, c_out)
+    x = jnp.asarray(rng.normal(0, 1, (batch, extent, extent, c_in)), jnp.bfloat16)
+    want = np.asarray(_downsample_block_xla(x, p, s), np.float32)
+
+    got_t = jax.jit(lambda xt: downsample_t(xt, p, s, "blockX", bt=8, interpret=True))(
+        x.transpose(1, 2, 0, 3))
+    got = np.asarray(got_t.transpose(2, 0, 1, 3), np.float32)
+    assert got.shape == want.shape == (batch, 5, 5, c_out)
+    rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-6)
+    assert rel < 2e-2, f"downsample_t diverges from the XLA block: {rel:.2e}"
 
 
 def test_fused_entry_kernel_matches_reference():
@@ -197,11 +322,43 @@ def fast_spec():
     )
 
 
-def test_fast_forward_matches_flax(fast_spec):
-    """Full fast path (entry/exit lax ops + fused middle, interpret mode)
-    vs the stock flax graph on identical variables with jittered BN stats."""
-    from kubernetes_deep_learning_tpu.models.xception_fast import build_fast_forward
+def _pallas_calls(jaxpr) -> int:
+    """pallas_call equations of a traced program, inner jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                n += _pallas_calls(inner)
+    return n
+
+
+@pytest.mark.parametrize(
+    "entry",
+    [
+        None,       # the rule's own answer at the test spec: blocks 3 and 4 chained
+        {},         # a batch or extent the rule leaves to XLA
+        {3: 8},     # block 3 alone: back to NHWC for block 4, then (H, W, B, C) again
+    ],
+    ids=["rule", "xla-entry", "block3-alone"],
+)
+def test_fast_forward_matches_flax(fast_spec, monkeypatch, entry):
+    """Full fast path (conv1/conv2/block 2 lax ops, entry chains where the
+    rule sends them, fused middle and exit; interpret mode) vs the stock
+    flax graph on identical variables with jittered BN stats."""
+    from kubernetes_deep_learning_tpu.models import xception_fast
     from kubernetes_deep_learning_tpu.ops.preprocess import normalize
+
+    if entry is None:
+        # 96x96: block 3 sees 23x23x128, block 4 12x12x256 -- both chain,
+        # once the batch the interpreter can afford counts as large enough
+        monkeypatch.setattr(fused_sepconv, "CHAIN_MIN_BATCH", 8)
+        assert xception_fast.chained_entry_blocks((96, 96), 2) == {3: 8, 4: 8}
+    else:
+        monkeypatch.setattr(
+            xception_fast, "chained_entry_blocks", lambda hw, batch: dict(entry)
+        )
 
     rng = np.random.default_rng(2)
     variables = jax.tree_util.tree_map(np.asarray, init_variables(fast_spec, seed=3))
@@ -221,12 +378,19 @@ def test_fast_forward_matches_flax(fast_spec):
     ref = jax.jit(build_forward(fast_spec, dtype=jnp.bfloat16, fast=False))
     want = np.asarray(ref(variables, images))
 
-    fast = build_fast_forward(fast_spec, dtype=jnp.bfloat16, interpret=True)
+    fast = xception_fast.build_fast_forward(fast_spec, dtype=jnp.bfloat16, interpret=True)
     x = normalize(jnp.asarray(images), fast_spec.preprocessing)
     got = np.asarray(jax.jit(fast)(variables, x), np.float32)
 
     rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-6)
     assert rel < 1e-2, f"fast path diverges from flax graph: {rel:.2e}"
+
+    # what the forward traced is what the status page will name: one
+    # pallas_call a fused block (8 middle, 13, 14, and the entry chains)
+    traced = _pallas_calls(jax.make_jaxpr(fast)(variables, x).jaxpr)
+    assert traced == len(xception_fast.fused_blocks(fast_spec, 2)) == 10 + len(
+        xception_fast.chained_entry_blocks((96, 96), 2)
+    )
 
 
 def test_chunk_size_rules():
@@ -256,6 +420,7 @@ def test_chunked_fast_forward_matches_monolithic(fast_spec, monkeypatch):
     monkeypatch.setattr(xception_fast, "_TAIL", 1)
     monkeypatch.setattr(xception_fast, "_CHUNK_MIN", 2)
     monkeypatch.setattr(xception_fast, "_CHUNK_MAX", 2)
+    monkeypatch.setattr(fused_sepconv, "CHAIN_MIN_BATCH", 8)  # chains in every chunk
 
     rng = np.random.default_rng(5)
     variables = init_variables(fast_spec, seed=1)
@@ -272,6 +437,12 @@ def test_chunked_fast_forward_matches_monolithic(fast_spec, monkeypatch):
     got = np.asarray(jax.jit(chunked)(variables, x), np.float32)
     assert got.shape == want.shape
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # every chunk decides for itself and runs its own kernels, the entry
+    # chains among them: twice the monolith's pallas_calls, the same names
+    blocks = xception_fast.fused_blocks(fast_spec, 2)
+    assert blocks[:2] == ["block3", "block4"] and len(blocks) == 12
+    assert _pallas_calls(jax.make_jaxpr(chunked)(variables, x).jaxpr) == 2 * len(blocks)
+    assert _pallas_calls(jax.make_jaxpr(mono)(variables, x).jaxpr) == len(blocks)
 
 
 def test_middle_block_weights_shapes(fast_spec):
@@ -292,3 +463,89 @@ def test_build_forward_fast_flag_dispatch(fast_spec):
     variables = init_variables(fast_spec, seed=0)
     out = jax.jit(fwd)(variables, images)
     assert out.shape == (1, fast_spec.num_classes)
+
+
+def test_status_page_names_the_fused_blocks_the_forward_traced(fast_spec, monkeypatch, tmp_path):
+    """GET /v1/models' ``fused_blocks`` (engine.device_info, beside
+    ``fast_engaged``): by warmed bucket, the blocks that run as Pallas
+    kernels -- as many as the bucket's traced program holds pallas_calls,
+    from the function the forward itself asks.  The kernels run in
+    interpret mode here, which the engine never asks for: the test swaps
+    the builder in, as a chip would compile them."""
+    import functools
+
+    from kubernetes_deep_learning_tpu.export import export_model, load_artifact
+    from kubernetes_deep_learning_tpu.export.artifact import version_dir
+    from kubernetes_deep_learning_tpu.models import xception_fast
+    from kubernetes_deep_learning_tpu.ops.preprocess import normalize
+    from kubernetes_deep_learning_tpu.runtime.engine import InferenceEngine
+
+    monkeypatch.setattr(
+        xception_fast, "build_fast_forward",
+        functools.partial(xception_fast.build_fast_forward, interpret=True),
+    )
+    monkeypatch.setattr(fused_sepconv, "CHAIN_MIN_BATCH", 8)
+    variables = init_variables(fast_spec, seed=0)
+    export_model(fast_spec, variables, str(tmp_path), dtype=jnp.bfloat16)
+    artifact = load_artifact(version_dir(str(tmp_path), fast_spec.name, 1))
+    eng = InferenceEngine(artifact, buckets=(1, 8), use_exported=False, fast=True)
+    assert eng.device_info()["fused_blocks"] == {}  # nothing warmed yet
+    eng.warmup()
+    info = eng.device_info()
+    assert info["fast_engaged"] is True and info["fast_degraded"] is False
+    names = ["block3", "block4", *(f"block{i}" for i in range(5, 15))]
+    assert info["fused_blocks"] == {"1": names, "8": names}
+
+    fast = xception_fast.build_fast_forward(fast_spec, dtype=jnp.bfloat16)
+    x = normalize(jnp.zeros((8, *fast_spec.input_shape), jnp.uint8), fast_spec.preprocessing)
+    assert _pallas_calls(jax.make_jaxpr(fast)(variables, x).jaxpr) == len(names)
+
+    # off the fused path the page names none
+    eng._fast_engaged = False
+    assert eng.device_info()["fused_blocks"] == {}
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described v5e: the TPU's compiler without the chip
+    (only ever asked for inside a test: one process may hold libtpu)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.slow  # ~45 s a chain: Mosaic unrolls the whole-extent tile
+@pytest.mark.parametrize(
+    "h,widths", [(74, (128, 256, 256)), (37, (256, 728, 728))], ids=["block3", "block4"]
+)
+def test_entry_chains_compile_for_v5e_at_the_rules_tile(v5e_chip, h, widths):
+    """What the rule admits must compile: a Mosaic refusal at warm-up would
+    degrade the whole engine to the flax graph.  The Xception 299x299 entry
+    chains, at the batch tile and VMEM limit the forward gives them."""
+    from kubernetes_deep_learning_tpu.ops.fused_sepconv import fused_sepconv_chain_t
+
+    batch = 256
+    bt = chain_batch_tile(batch, h, h, widths)
+    assert bt == 8
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=v5e_chip)
+
+    stages = [
+        {"dw": shape((3, 3, ci), jnp.float32), "pw": shape((ci, co), jnp.bfloat16),
+         "scale": shape((co,), jnp.float32), "shift": shape((co,), jnp.float32)}
+        for ci, co in zip(widths, widths[1:])
+    ]
+
+    def chain(x, stages):
+        return fused_sepconv_chain_t(
+            x, [dict(s, pre_relu=True, post_relu=False) for s in stages],
+            bt=bt, vmem_limit_bytes=CHAIN_VMEM_LIMIT_BYTES)
+
+    compiled = jax.jit(chain).lower(shape((h, h, batch, widths[0]), jnp.bfloat16), stages).compile()
+    assert "tpu_custom_call" in compiled.as_text()
