@@ -35,6 +35,13 @@ SPEC_FILE = "spec.json"
 PARAMS_FILE = "params.msgpack"
 MODULE_FILE = "module.stablehlo"
 META_FILE = "metadata.json"
+# Marks a version directory as a decoder's artifact (models.longcat_flash
+# writes and reads it): the image registry passes such a directory by.
+DECODER_FILE = "decoder.json"
+
+
+def is_decoder_dir(directory: str) -> bool:
+    return os.path.exists(os.path.join(directory, DECODER_FILE))
 _PLATFORM_MODULE_RE = re.compile(r"^module\.([a-z0-9_]+)\.stablehlo$")
 
 

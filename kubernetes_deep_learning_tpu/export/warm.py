@@ -36,8 +36,13 @@ import time
 from kubernetes_deep_learning_tpu.utils import compilecache
 
 
-def warm_decode(engine_factory=None) -> dict:
+def warm_decode(engine_factory=None, model_root: str | None = None) -> dict:
     """Warm the generative lane's decode ladder; returns its report dict.
+
+    The engine is built as a pod builds it: the lane's sizes and its
+    prefill ladder from the same environment ($KDLT_DECODE_SLOTS, ...,
+    $KDLT_DECODE_PROMPT_BUCKETS), its decoder from the artifact that
+    $KDLT_DECODE_MODEL names under ``model_root`` (the toy without one).
 
     The decode lane has its own compile grid, disjoint from the image
     bucket ladder: one prefill program per prompt-length bucket, plus the
@@ -56,7 +61,11 @@ def warm_decode(engine_factory=None) -> dict:
     )
 
     model = os.environ.get(DECODE_MODEL_ENV) or DEFAULT_DECODE_MODEL
-    engine = (engine_factory or decode_lib.DecodeEngine)(model=model)
+    if engine_factory is not None:
+        engine = engine_factory(model=model)
+    else:
+        engine = decode_lib.DecodeEngine(
+            model=model, decoder=decode_lib.load_decoder(model_root, model))
     entry = dict(engine.warmup())
     # The learned grid: every (prompt bucket, batch slots) cell the two
     # program families above cover.  Asserted by tests/test_warm.py.
@@ -136,7 +145,7 @@ def warm_models(
     if decode_enabled(decode):
         t0 = time.perf_counter()
         try:
-            report["decode"] = warm_decode(decode_engine_factory)
+            report["decode"] = warm_decode(decode_engine_factory, model_root)
         except Exception as e:  # noqa: BLE001 - image models still warmed
             report["decode"] = {"error": str(e)}
             print(f"kdlt-warm: decode ladder FAILED: {e}", file=sys.stderr)

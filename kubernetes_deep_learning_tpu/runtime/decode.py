@@ -33,11 +33,26 @@ step program serves every batch composition, solo included.  So the
 token stream of a request decoded in a shifting continuous batch is
 bit-identical to the same request decoded alone.
 
-The model itself is a deliberately tiny byte-level causal transformer
-(weights derived deterministically from the model name), standing in for
-a real checkpoint: the contracts under test -- paging, donation,
-continuous batching, streaming, per-token SLOs -- are all shape- and
-schedule-level, not weight-level.
+The model is a *decoder* the engine is handed (``DecodeEngine(decoder=)``):
+its vocabulary, its cache layout (``cache_spec``) and two pure functions,
+``prefill`` and ``decode_step``, that return logits and a few routing
+counts.  Two implement that small interface: ``ToyDecoder`` here, a
+deliberately tiny byte-level causal transformer with per-head K/V pages
+(weights derived deterministically from the model name) -- the default
+when no artifact is named, so the lane's shape- and schedule-level
+contracts are testable without a checkpoint -- and
+``models.longcat_flash.LongcatDecoder``, loaded from an artifact directory
+(``load_decoder``), whose pages hold latents.  The greedy choice, the top
+logits a request may ask for and the packing of everything a step returns
+into ONE device array (so the step's single host sync carries it all) are
+the engine's, once, for every decoder.
+
+The host is not in the step's period.  The token a slot consumes next stays
+on the device (a prefill and a step each leave their greedy choice in
+``DecodeEngine._next_tokens``), and lengths, pages and who is live are known
+without the tokens, so the scheduler's loop dispatches step N + 1 -- and any
+prefill -- before it reads step N (``STEPS_AHEAD``).  The one sync a step is
+still there; it runs beside the next step instead of before it.
 """
 
 from __future__ import annotations
@@ -47,6 +62,7 @@ import os
 import threading
 import time
 import zlib
+from collections import deque
 from dataclasses import dataclass, field
 from queue import Empty, Queue
 
@@ -59,9 +75,9 @@ from kubernetes_deep_learning_tpu.serving.admission.deadline import Deadline
 from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
 from kubernetes_deep_learning_tpu.utils import trace as trace_lib
 
-# Byte-level vocabulary: 256 raw bytes + BOS + EOS.  No tokenizer on the
-# wire -- prompts travel as text and are encoded here, so the protocol
-# carries no vocab contract.
+# The toy's byte-level vocabulary: 256 raw bytes + BOS + EOS.  Text prompts
+# are encoded here; a request may also carry ``token_ids``, which any
+# decoder sees as they are (checked against its vocabulary).
 BOS_TOKEN = 256
 EOS_TOKEN = 257
 VOCAB_SIZE = 258
@@ -73,17 +89,30 @@ SLOTS_ENV = "KDLT_DECODE_SLOTS"
 PAGE_SIZE_ENV = "KDLT_DECODE_PAGE_SIZE"
 MAX_PAGES_ENV = "KDLT_DECODE_MAX_PAGES"
 QUEUE_CAP_ENV = "KDLT_DECODE_QUEUE_CAP"
+PROMPT_BUCKETS_ENV = "KDLT_DECODE_PROMPT_BUCKETS"
 
 DEFAULT_SLOTS = 4
 DEFAULT_PAGE_SIZE = 16
 DEFAULT_MAX_PAGES = 8
 DEFAULT_QUEUE_CAP = 64
 
-# The prefill compile ladder (prompt positions INCLUDING the BOS token,
-# like the image engine's batch buckets): each bucket is one compiled
-# program, prompts pad up to the next rung.  kdlt-warm walks this ladder
-# so scaled pods never pay a prefill compile on their first generation.
+# The DEFAULT prefill compile ladder (prompt positions INCLUDING the toy's
+# BOS token, like the image engine's batch buckets): each bucket is one
+# compiled program, prompts pad up to the next rung.  A deployment states
+# its own in $KDLT_DECODE_PROMPT_BUCKETS ("64,128,256"); kdlt-warm walks
+# the same ladder so scaled pods never pay a prefill compile on their
+# first generation.
 PROMPT_BUCKETS = (16, 32, 64)
+
+# What a step returns beside the tokens: the largest logits of every slot
+# (a request asks for up to this many, serving.protocol caps it there).
+TOP_LOGITS = protocol.GENERATE_TOP_LOGITS_CAP
+# Routing counts a decoder returns with its logits (zeros from one without
+# experts): held / absent / zero-compute assignments, held experts touched.
+N_COUNTS = 4
+# Steps the scheduler keeps dispatched: the one it reads next and the one
+# the device goes on to meanwhile.
+STEPS_AHEAD = 2
 
 
 def _env_int(name: str, default: int) -> int:
@@ -92,6 +121,17 @@ def _env_int(name: str, default: int) -> int:
         return int(raw) if raw.strip() else default
     except ValueError:
         return default
+
+
+def env_prompt_buckets() -> tuple[int, ...] | None:
+    """$KDLT_DECODE_PROMPT_BUCKETS as a ladder, or None where unset or
+    malformed (the default ladder then stands)."""
+    raw = os.environ.get(PROMPT_BUCKETS_ENV, "").strip()
+    try:
+        buckets = tuple(sorted({int(b) for b in raw.split(",") if b.strip()}))
+    except ValueError:
+        return None
+    return buckets if buckets and buckets[0] > 0 else None
 
 
 def encode_prompt(prompt: str) -> list[int]:
@@ -190,9 +230,10 @@ def _decode_step(params, cache, page_table, lengths, last_tokens, active):
 
     Writes each active slot's K/V at logical position ``lengths[s]``,
     attends over positions 0..lengths[s] inclusive, and returns
-    ``(cache, next_tokens)`` -- greedy argmax, so decoding is
-    deterministic.  Per-slot independence is the bit-exactness invariant:
-    no cross-slot reduction anywhere in this function.
+    ``(cache, logits [S, V], counts)`` -- the engine's epilogue takes the
+    greedy argmax, so decoding is deterministic.  Per-slot independence is
+    the bit-exactness invariant: no cross-slot reduction anywhere in this
+    function.
     """
     import jax.numpy as jnp
 
@@ -230,8 +271,7 @@ def _decode_step(params, cache, page_table, lengths, last_tokens, active):
         x = x + attn @ layer["wo"]
         x = x + _mlp(layer, x)
 
-    nxt = jnp.argmax(_logits(params, x), axis=-1).astype(jnp.int32)
-    return cache, nxt
+    return cache, _logits(params, x), jnp.zeros((N_COUNTS,), jnp.int32)
 
 
 def _prefill(params, cache, tokens, length, page_ids):
@@ -243,8 +283,8 @@ def _prefill(params, cache, tokens, length, page_ids):
 
     Full causal self-attention within the prompt (never reads the cache),
     K/V written to the slot's pages (padding positions to the trash
-    page), and the first generated token taken greedily from the last
-    true position's logits.  Returns ``(cache, first_token)``.
+    page).  Returns ``(cache, logits [V] of the last true position,
+    counts)``: the first generated token is their greedy argmax.
     """
     import jax.numpy as jnp
 
@@ -274,8 +314,80 @@ def _prefill(params, cache, tokens, length, page_ids):
         x = x + attn @ layer["wo"]
         x = x + _mlp(layer, x)
 
-    first = jnp.argmax(_logits(params, x[length - 1]), axis=-1)
-    return cache, first.astype(jnp.int32)
+    return cache, _logits(params, x[length - 1]), jnp.zeros((N_COUNTS,), jnp.int32)
+
+
+class ToyDecoder:
+    """The byte-level toy behind the decoder interface: text prompts,
+    BOS/EOS, float32 per-head K/V pages ``[L, 2, P, page, H, Dh]``."""
+
+    family = "toy"
+    text = True
+    eos_token = EOS_TOKEN
+    vocab_size = VOCAB_SIZE
+
+    def __init__(self, seed: int, d_model: int = 32, n_layers: int = 2, n_heads: int = 2):
+        if d_model % n_heads:
+            raise ValueError("d_model must divide into n_heads")
+        self.d_model, self.n_layers, self.n_heads = d_model, n_layers, n_heads
+        self.params = _build_params(seed, d_model, n_layers, n_heads)
+
+    def cache_spec(self, num_pages: int, page_size: int):
+        import jax.numpy as jnp
+
+        return (self.n_layers, 2, num_pages, page_size, self.n_heads,
+                self.d_model // self.n_heads), jnp.float32
+
+    prefill = staticmethod(_prefill)
+    decode_step = staticmethod(_decode_step)
+
+    def describe(self) -> dict:
+        return {"family": self.family, "layers": self.n_layers}
+
+
+def load_decoder(model_root: str | None, model: str):
+    """The decoder of the artifact ``<model_root>/<model>/<highest version>/``
+    (found as image artifacts are found), or None where there is none: the
+    lane then serves the toy."""
+    from kubernetes_deep_learning_tpu.export import artifact as art
+    from kubernetes_deep_learning_tpu.models import longcat_flash
+
+    if not model_root:
+        return None
+    version = art.latest_version(model_root, model)
+    if version is None:
+        return None
+    directory = art.version_dir(model_root, model, version)
+    if not art.is_decoder_dir(directory):
+        return None
+    return longcat_flash.LongcatDecoder.load(directory)
+
+
+def _pack(logits, counts, top: int):
+    """Everything the host reads of a step, as ONE int32 array ``[2 * S + 1,
+    top]``: rows 0..S-1 the ids of each slot's ``top`` largest logits
+    (largest first: column 0 is the greedy token), rows S..2S-1 those
+    logits' float32 bits, the last row the routing counts."""
+    import jax
+    import jax.numpy as jnp
+
+    # The barrier keeps top_k's outputs one consumer each: with a second
+    # reader of the ids (the step's next tokens) the chip's compiler sorts
+    # the whole vocabulary in place of its TopK call (0.71 against 0.39 ms).
+    values, ids = jax.lax.optimization_barrier(
+        jax.lax.top_k(logits.astype(jnp.float32), top))
+    tail = jnp.zeros((1, top), jnp.int32).at[0, :counts.shape[0]].set(counts)
+    return jnp.concatenate(
+        [ids.astype(jnp.int32), jax.lax.bitcast_convert_type(values, jnp.int32), tail])
+
+
+@dataclass
+class StepOutput:
+    """One materialized step (or prefill, S = 1) on the host."""
+    tokens: np.ndarray        # [S] int32, the greedy choice
+    top_ids: np.ndarray       # [S, top] int32
+    top_logits: np.ndarray    # [S, top] float32
+    counts: np.ndarray        # [N_COUNTS] int64
 
 
 # --- the engine -------------------------------------------------------------
@@ -291,7 +403,9 @@ class DecodeEngine:
     ``step_async`` is deliberately dispatch-only (kdlt-lint's
     hot-path-sync pass is rooted there): it enqueues the jitted step and
     returns the unmaterialized token handle.  The ONE host sync per
-    iteration is ``materialize()``, called by the scheduler loop.
+    iteration is ``materialize()``, called by the scheduler loop -- for a
+    step dispatched one iteration earlier, since nothing a dispatch needs
+    waits for a read.
     """
 
     def __init__(
@@ -307,6 +421,7 @@ class DecodeEngine:
         prompt_buckets: tuple[int, ...] | None = None,
         donate: bool | None = None,
         seed: int | None = None,
+        decoder=None,
     ):
         import jax
         import jax.numpy as jnp
@@ -319,7 +434,7 @@ class DecodeEngine:
         )
         self.max_context = self.page_size * self.max_pages_per_seq
         self.prompt_buckets = tuple(sorted(
-            b for b in (prompt_buckets or PROMPT_BUCKETS)
+            b for b in (prompt_buckets or env_prompt_buckets() or PROMPT_BUCKETS)
             if b <= self.max_context
         ))
         if not self.prompt_buckets:
@@ -327,23 +442,25 @@ class DecodeEngine:
                 "no prefill bucket fits inside the "
                 f"{self.max_context}-token context"
             )
-        if d_model % n_heads:
-            raise ValueError("d_model must divide into n_heads")
-        self.d_model, self.n_layers, self.n_heads = d_model, n_layers, n_heads
         self._donate = donation_enabled(donate)
-        self._seed = (
-            seed if seed is not None else zlib.crc32(model.encode()) & 0x7FFFFFFF
-        )
-        self._params = _build_params(self._seed, d_model, n_layers, n_heads)
+        if decoder is None:
+            # No artifact: the toy, its weights keyed by the model name.
+            decoder = ToyDecoder(
+                seed if seed is not None
+                else zlib.crc32(model.encode()) & 0x7FFFFFFF,
+                d_model, n_layers, n_heads,
+            )
+        self.decoder = decoder
+        self.vocab_size = int(decoder.vocab_size)
+        self.top_logits = min(TOP_LOGITS, self.vocab_size)
+        self._params = decoder.params
 
         # Page pool: page 0 is the trash page (inactive-slot and padding
         # writes land there), never allocated.
         self.num_pages = 1 + self.max_slots * self.max_pages_per_seq
-        head_dim = d_model // n_heads
-        self._cache = jnp.zeros(
-            (n_layers, 2, self.num_pages, self.page_size, n_heads, head_dim),
-            jnp.float32,
-        )
+        cache_shape, cache_dtype = decoder.cache_spec(self.num_pages, self.page_size)
+        self._cache = jnp.zeros(cache_shape, cache_dtype)
+        self.cache_bytes = int(self._cache.nbytes)
         self._free_pages = list(range(self.num_pages - 1, 0, -1))
         self._free_slots = list(range(self.max_slots - 1, -1, -1))
         self._slot_pages: dict[int, list[int]] = {}
@@ -354,20 +471,46 @@ class DecodeEngine:
             (self.max_slots, self.max_pages_per_seq), np.int32
         )
         self.lengths = np.zeros((self.max_slots,), np.int32)
-        self.last_tokens = np.zeros((self.max_slots,), np.int32)
         self.active = np.zeros((self.max_slots,), bool)
+        # The token every slot consumes next stays ON THE DEVICE: a prefill
+        # and a step each leave their greedy choice there, so the next step
+        # can be dispatched before the host has read this one.
+        self._next_tokens = jnp.zeros((self.max_slots,), jnp.int32)
 
+        top, slots = self.top_logits, self.max_slots
+
+        def step(params, cache, page_table, lengths, next_tokens, active):
+            cache, logits, counts = decoder.decode_step(
+                params, cache, page_table, lengths, next_tokens, active)
+            packed = _pack(logits, counts, top)
+            return cache, packed, jnp.where(active, packed[:slots, 0], next_tokens)
+
+        def prefill(params, cache, next_tokens, tokens, length, page_ids, slot):
+            cache, logits, counts = decoder.prefill(
+                params, cache, tokens, length, page_ids)
+            packed = _pack(logits[None], counts, top)
+            return cache, packed, next_tokens.at[slot].set(packed[0, 0])
+
+        donated = (1,) if self._donate else ()
         if self._donate:
             import warnings
 
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable"
             )
-            self._step_jit = jax.jit(_decode_step, donate_argnums=(1,))
-            self._prefill_jit = jax.jit(_prefill, donate_argnums=(1,))
-        else:
-            self._step_jit = jax.jit(_decode_step)
-            self._prefill_jit = jax.jit(_prefill)
+        self._step_jit = jax.jit(step, donate_argnums=donated)
+        self._prefill_jit = jax.jit(prefill, donate_argnums=donated)
+
+    def status(self) -> dict:
+        """The ``decode`` block of GET /v1/models: the sizes the lane was
+        booted at and what its decoder says of itself."""
+        return {
+            "slots": self.max_slots, "page_size": self.page_size,
+            "max_pages": self.max_pages_per_seq,
+            "prompt_buckets": list(self.prompt_buckets),
+            "vocab_size": self.vocab_size, "cache_bytes": self.cache_bytes,
+            "top_logits": self.top_logits, **self.decoder.describe(),
+        }
 
     # --- slot/page bookkeeping (host-side) ---------------------------------
 
@@ -405,7 +548,6 @@ class DecodeEngine:
         row[: len(pages)] = pages
         self.page_table[slot] = row
         self.lengths[slot] = 0
-        self.last_tokens[slot] = 0
         self.active[slot] = False  # flips on at prefill
         return slot
 
@@ -420,59 +562,65 @@ class DecodeEngine:
 
     def prefill(self, slot: int, prompt_tokens: list[int]):
         """Dispatch one prompt's prefill into ``slot``; returns the
-        unmaterialized first-token handle.  The slot is live afterwards:
-        its length covers the prompt and the next step consumes the
-        first token (once materialized and stored via ``seed_token``)."""
+        unmaterialized handle of its packed output (S = 1).  The slot is
+        live afterwards: its length covers the prompt and the next step
+        consumes the first token, which the prefill leaves on the device."""
         n = len(prompt_tokens)
         bucket = prompt_bucket(n, self.prompt_buckets)
         padded = np.zeros((bucket,), np.int32)
         padded[:n] = prompt_tokens
-        self._cache, first = self._prefill_jit(
-            self._params, self._cache, padded,
-            np.int32(n), self.page_table[slot],
+        self._cache, first, self._next_tokens = self._prefill_jit(
+            self._params, self._cache, self._next_tokens, padded,
+            np.int32(n), self.page_table[slot].copy(), np.int32(slot),
         )
         self.lengths[slot] = n
         self.active[slot] = True
         return first
 
-    def seed_token(self, slot: int, token: int) -> None:
-        """Store the token the next step consumes for ``slot``."""
-        self.last_tokens[slot] = token
-
     def step_async(self):
         """Dispatch one batched decode step; returns the unmaterialized
-        next-token handle.  No host sync in here -- the scheduler loop
-        materializes exactly once per iteration."""
-        self._cache, nxt = self._step_jit(
-            self._params, self._cache, self.page_table, self.lengths,
-            self.last_tokens, self.active,
+        handle of its packed output.  No host sync in here -- the scheduler
+        loop materializes exactly once per iteration.  The slot tables go
+        in as copies: the caller may change them for the next dispatch
+        while this one has not run (a backend may read a numpy argument in
+        place)."""
+        self._cache, nxt, self._next_tokens = self._step_jit(
+            self._params, self._cache, self.page_table.copy(), self.lengths,
+            self._next_tokens, self.active.copy(),
         )
         self.lengths = self.lengths + self.active.astype(np.int32)
         return nxt
 
-    def materialize(self, handle) -> np.ndarray:
-        """The per-iteration host sync: handle -> host int32 array."""
-        return np.asarray(handle)
+    def materialize(self, handle) -> StepOutput:
+        """The per-iteration host sync: one device array -> the step's
+        tokens, top logits and routing counts on the host."""
+        packed = np.asarray(handle)
+        n = (packed.shape[0] - 1) // 2
+        return StepOutput(
+            tokens=packed[:n, 0], top_ids=packed[:n],
+            top_logits=packed[n:2 * n].view(np.float32),
+            counts=packed[2 * n, :N_COUNTS].astype(np.int64),
+        )
 
     # --- reference + warmup -------------------------------------------------
 
-    def decode_solo(self, prompt: str, max_new_tokens: int) -> list[int]:
-        """The bit-exactness reference: decode one request alone through
-        the SAME compiled programs.  Requires an idle engine."""
+    def decode_solo(self, prompt, max_new_tokens: int) -> list[int]:
+        """The bit-exactness reference: decode one request (text, or a list
+        of token ids) alone through the SAME compiled programs.  Requires
+        an idle engine."""
         if self.active.any() or self._slot_pages:
             raise RuntimeError("decode_solo requires an idle engine")
-        tokens = encode_prompt(prompt)
+        tokens = encode_prompt(prompt) if isinstance(prompt, str) else list(prompt)
         slot = self.acquire_slot(len(tokens) + max_new_tokens)
         if slot is None:
             raise RuntimeError("no capacity for a solo decode")
+        eos = self.decoder.eos_token
         try:
             out: list[int] = []
-            tok = int(self.materialize(self.prefill(slot, tokens)))
+            tok = int(self.materialize(self.prefill(slot, tokens)).tokens[0])
             out.append(tok)
-            while tok != EOS_TOKEN and len(out) < max_new_tokens:
-                self.seed_token(slot, tok)
-                step = self.step_async()
-                tok = int(self.materialize(step)[slot])
+            while tok != eos and len(out) < max_new_tokens:
+                tok = int(self.materialize(self.step_async()).tokens[slot])
                 out.append(tok)
             return out
         finally:
@@ -492,7 +640,7 @@ class DecodeEngine:
             if slot is None:
                 break
             try:
-                self.materialize(self.prefill(slot, [BOS_TOKEN] * b))
+                self.materialize(self.prefill(slot, [0] * b))
             finally:
                 self.release_slot(slot)
             report["buckets"][str(b)] = round(time.perf_counter() - t0, 4)
@@ -500,8 +648,7 @@ class DecodeEngine:
         slot = self.acquire_slot(2)
         if slot is not None:
             try:
-                self.materialize(self.prefill(slot, [BOS_TOKEN]))
-                self.seed_token(slot, BOS_TOKEN)
+                self.materialize(self.prefill(slot, [0]))
                 self.materialize(self.step_async())
             finally:
                 self.release_slot(slot)
@@ -528,12 +675,15 @@ class Generation:
     max_new_tokens: int
     priority: str = protocol.DEFAULT_PRIORITY
     deadline: Deadline | None = None
+    ignore_eos: bool = False
+    top_logits: int = 0      # the largest logits each token event carries
     t_submit: float = field(default_factory=time.perf_counter)
     t_first: float | None = None
     t_last: float | None = None
     tokens: list[int] = field(default_factory=list)
     finish_reason: str | None = None
     slot: int | None = None
+    dispatched: int = 0      # tokens the device was asked for (read or not)
     events: Queue = field(default_factory=Queue)
     _cancel: threading.Event = field(default_factory=threading.Event)
 
@@ -559,8 +709,9 @@ class Generation:
         return (self.t_last - self.t_first) / (len(self.tokens) - 1)
 
     def iter_events(self, timeout_s: float = 60.0):
-        """Drain the event queue: yields ("token", index, id, text) then
-        one ("done", finish_reason); transport-thread side."""
+        """Drain the event queue: yields ("token", index, id, text, top ids
+        or None, top logits or None) then one ("done", finish_reason);
+        transport-thread side."""
         while True:
             try:
                 ev = self.events.get(timeout=timeout_s)
@@ -577,8 +728,9 @@ class DecodeScheduler:
     Continuous batching (the lane's reason to exist): every loop
     iteration first slot-fills freed decode slots from the queue (by
     (priority rank, absolute deadline) order -- same shed order as the
-    image tier), then runs ONE batched step and fans the materialized
-    tokens out to their generations.
+    image tier), dispatches their prefills and ONE batched step behind the
+    step already on the device, then reads the oldest of what is
+    dispatched and fans its tokens out to their generations.
     """
 
     def __init__(
@@ -594,7 +746,11 @@ class DecodeScheduler:
         self.registry = registry
         self.recorder = recorder
         self.tracer = tracer
-        self.queue_cap = queue_cap or _env_int(QUEUE_CAP_ENV, DEFAULT_QUEUE_CAP)
+        # Unset, the cap leaves room for every slot's next request to wait
+        # while the loop prefills: closed-loop callers, one a slot, all
+        # arrive at once at a start and never again.
+        self.queue_cap = queue_cap or _env_int(
+            QUEUE_CAP_ENV, max(DEFAULT_QUEUE_CAP, 2 * engine.max_slots))
         self.metrics = (
             metrics_lib.decode_metrics(registry, engine.model)
             if registry is not None else None
@@ -626,17 +782,38 @@ class DecodeScheduler:
 
     def submit(
         self,
-        prompt: str,
+        prompt: str | None,
         max_new_tokens: int,
         *,
+        token_ids: list[int] | None = None,
+        ignore_eos: bool = False,
+        top_logits: int = 0,
         rid: str = "",
         priority: str | None = None,
         deadline: Deadline | None = None,
     ) -> Generation:
-        """Enqueue one generation; raises QueueFull at the cap (mapped to
-        a retryable 503 by the transports, like the image batcher) and
-        ValueError for prompts that cannot fit (a 400)."""
-        tokens = encode_prompt(prompt)
+        """Enqueue one generation of a text ``prompt`` or of ``token_ids``
+        (the model sees those as they are); raises QueueFull at the cap
+        (mapped to a retryable 503 by the transports, like the image
+        batcher) and ValueError for prompts that cannot fit or ids outside
+        the vocabulary (a 400)."""
+        decoder = self.engine.decoder
+        if token_ids is not None:
+            tokens = [int(t) for t in token_ids]
+            if any(not 0 <= t < self.engine.vocab_size for t in tokens):
+                raise ValueError(
+                    f"token id outside the vocabulary [0, {self.engine.vocab_size})"
+                )
+        elif not decoder.text:
+            raise ValueError(
+                f"model {self.engine.model!r} has no text codec: send token_ids"
+            )
+        else:
+            tokens = encode_prompt(prompt)
+        if top_logits > self.engine.top_logits:
+            raise ValueError(
+                f"top_logits {top_logits} exceeds the lane's {self.engine.top_logits}"
+            )
         total = len(tokens) + max_new_tokens
         if total > self.engine.max_context:
             raise ValueError(
@@ -648,6 +825,7 @@ class DecodeScheduler:
         gen = Generation(
             rid=rid, prompt_tokens=tokens, max_new_tokens=max_new_tokens,
             priority=protocol.parse_priority(priority), deadline=deadline,
+            ignore_eos=ignore_eos, top_logits=top_logits,
         )
         with self._cond:
             if self._closed:
@@ -719,9 +897,15 @@ class DecodeScheduler:
             self.metrics["queue_depth"].set(len(self._queue))
         return admitted
 
-    def _emit(self, gen: Generation, token: int, now: float) -> None:
+    def _take(self, gen: Generation, out: StepOutput, row: int, now: float,
+              outbox: list) -> int:
+        """Book one token of ``gen`` and queue its event in ``outbox``: the
+        loop hands the events to the transport threads (``_flush``) only
+        once the next dispatch is made, so that their framing and socket
+        writes do not take the interpreter from it."""
+        token = int(out.tokens[row])
         idx = len(gen.tokens)
-        gen.tokens.append(int(token))
+        gen.tokens.append(token)
         if gen.t_first is None:
             gen.t_first = now
             if self.tracer is not None:
@@ -730,12 +914,33 @@ class DecodeScheduler:
                     gen.t_submit, now - gen.t_submit,
                 )
         gen.t_last = now
-        text = decode_tokens([int(token)])
-        gen.events.put(("token", idx, int(token), text))
+        top_ids = top_values = None
+        if gen.top_logits:
+            top_ids = out.top_ids[row, :gen.top_logits].tolist()
+            top_values = out.top_logits[row, :gen.top_logits].tolist()
+        text = decode_tokens([token]) if self.engine.decoder.text else ""
+        outbox.append((gen, ("token", idx, token, text, top_ids, top_values)))
         if self.metrics:
             self.metrics["tokens"].inc()
+        return token
 
-    def _retire(self, gen: Generation, reason: str) -> None:
+    @staticmethod
+    def _flush(outbox: list) -> None:
+        for gen, event in outbox:
+            gen.events.put(event)
+        outbox.clear()
+
+    def _count_routing(self, counts: np.ndarray) -> None:
+        held, absent, zero, touched = (int(c) for c in counts)
+        self.metrics["assignments_held"].inc(held)
+        self.metrics["assignments_absent"].inc(absent)
+        self.metrics["assignments_zero"].inc(zero)
+        self.metrics["experts_touched"].inc(touched)
+
+    def _stops(self, gen: Generation, token: int) -> bool:
+        return token == self.engine.decoder.eos_token and not gen.ignore_eos
+
+    def _retire(self, gen: Generation, reason: str, outbox: list) -> None:
         gen.finish_reason = reason
         if gen.slot is not None:
             self.engine.release_slot(gen.slot)
@@ -754,12 +959,28 @@ class DecodeScheduler:
             self.recorder.record(
                 "decode.shed", rid=gen.rid or None, reason="deadline",
             )
-        gen.events.put(("done", reason))
+        outbox.append((gen, ("done", reason)))
 
     def _loop(self) -> None:
+        """Dispatch runs ahead of reading.  A step's input token is on the
+        device (``DecodeEngine._next_tokens``) and everything else a dispatch
+        needs -- lengths, pages, who is live, who has its last token coming
+        -- the host knows without the tokens, so the loop keeps ``STEPS_AHEAD``
+        steps on the device and reads the oldest: the device goes from one
+        program to the next with no host in between, and the host has a
+        whole step's time for the one before.  What was dispatched for a
+        stream that the read then ends (EOS, cancel, deadline) is computed
+        into its own pages and dropped."""
+        outbox: list = []
+        inflight: deque = deque()   # dispatched and not read, oldest first
+        steps_ahead = 0
+        read_at = 0.0               # when the last read returned
         while True:
             with self._cond:
-                while not self._closed and not self._queue and not self._live:
+                if not inflight and not self._live:
+                    self._flush(outbox)
+                while (not inflight and not self._closed and not self._queue
+                       and not self._live):
                     self._cond.wait(timeout=0.5)
                 if self._closed:
                     for gen in self._queue:
@@ -767,57 +988,97 @@ class DecodeScheduler:
                         gen.events.put(("done", FINISH_CANCELLED))
                     self._queue.clear()
                     for gen in list(self._live.values()):
-                        self._retire(gen, FINISH_CANCELLED)
+                        self._retire(gen, FINISH_CANCELLED, outbox)
+                    self._flush(outbox)
                     return
                 admitted = self._admit_locked()
 
-            # Prefill the admissions (one compiled bucket each); the first
-            # token comes straight out of prefill -- that materialization
-            # IS the TTFT moment.
+            # Prefill the admissions (one compiled bucket each), behind
+            # whatever step is running; the first token comes straight out
+            # of each prefill -- reading it IS the TTFT moment.
             for gen in admitted:
-                t0 = time.perf_counter()
-                handle = self.engine.prefill(gen.slot, gen.prompt_tokens)
-                first = int(self.engine.materialize(handle))
-                now = time.perf_counter()
-                if self.metrics:
-                    self.metrics["prefill_seconds"].observe(now - t0)
-                    self.metrics["active_slots"].set(self.engine.active_slots)
-                    self.metrics["pages_in_use"].set(self.engine.pages_in_use)
-                if self.tracer is not None:
-                    self.tracer.record(
-                        gen.rid, trace_lib.SPAN_DECODE_PREFILL, t0, now - t0,
-                    )
                 self._live[gen.slot] = gen
-                self._emit(gen, first, now)
-                if first == EOS_TOKEN or len(gen.tokens) >= gen.max_new_tokens:
-                    self._retire(
-                        gen,
-                        FINISH_STOP if first == EOS_TOKEN else FINISH_LENGTH,
-                    )
-                else:
-                    self.engine.seed_token(gen.slot, first)
-
-            if not self._live:
+                inflight.append(
+                    (gen, time.perf_counter(),
+                     self.engine.prefill(gen.slot, gen.prompt_tokens)))
+                self._dispatched(gen)
+            while steps_ahead < STEPS_AHEAD and self.engine.active.any():
+                inflight.append(self._dispatch_step())
+                steps_ahead += 1
+            # The last read's events go out beside the device's work.
+            self._flush(outbox)
+            if not inflight:
                 continue
 
-            # One batched step: dispatch, then the single host sync.
-            t0 = time.perf_counter()
-            handle = self.engine.step_async()
-            toks = self.engine.materialize(handle)
+            first, dispatched, handle = inflight.popleft()
+            out = self.engine.materialize(handle)      # the one host sync
             now = time.perf_counter()
+            t0, read_at = max(dispatched, read_at), now   # behind another: from its end
+            if isinstance(first, Generation):
+                self._read_prefill(first, out, dispatched, t0, now, outbox)
+                continue
+            steps_ahead -= 1
+            rows, context = first
             if self.metrics:
                 self.metrics["steps"].inc()
                 self.metrics["step_seconds"].observe(now - t0)
-            for slot, gen in list(self._live.items()):
-                tok = int(toks[slot])
-                self._emit(gen, tok, now)
+                self.metrics["context_positions"].inc(context)
+                self._count_routing(out.counts)
+            for slot, gen in rows:
+                if gen.done:
+                    continue            # ended at an earlier read: dropped
+                tok = self._take(gen, out, slot, now, outbox)
                 if gen.cancelled:
-                    self._retire(gen, FINISH_CANCELLED)
-                elif tok == EOS_TOKEN:
-                    self._retire(gen, FINISH_STOP)
+                    self._retire(gen, FINISH_CANCELLED, outbox)
+                elif self._stops(gen, tok):
+                    self._retire(gen, FINISH_STOP, outbox)
                 elif len(gen.tokens) >= gen.max_new_tokens:
-                    self._retire(gen, FINISH_LENGTH)
+                    self._retire(gen, FINISH_LENGTH, outbox)
                 elif gen.deadline is not None and gen.deadline.expired:
-                    self._retire(gen, FINISH_DEADLINE)
-                else:
-                    self.engine.seed_token(slot, tok)
+                    self._retire(gen, FINISH_DEADLINE, outbox)
+
+    def _read_prefill(self, gen: Generation, out: StepOutput, dispatched: float,
+                      t0: float, now: float, outbox: list) -> None:
+        if self.metrics:
+            n = len(gen.prompt_tokens)
+            self.metrics["prefill_seconds"].observe(now - t0)
+            self.metrics["prefill_prompt_tokens"].inc(n)
+            self.metrics["prefill_padding_tokens"].inc(
+                prompt_bucket(n, self.engine.prompt_buckets) - n)
+            self.metrics["active_slots"].set(self.engine.active_slots)
+            self.metrics["pages_in_use"].set(self.engine.pages_in_use)
+        if self.tracer is not None:
+            self.tracer.record(
+                gen.rid, trace_lib.SPAN_DECODE_QUEUE_WAIT,
+                gen.t_submit, dispatched - gen.t_submit,
+            )
+            self.tracer.record(
+                gen.rid, trace_lib.SPAN_DECODE_PREFILL, t0, now - t0,
+            )
+        first = self._take(gen, out, 0, now, outbox)
+        if self._stops(gen, first) or len(gen.tokens) >= gen.max_new_tokens:
+            self._retire(
+                gen,
+                FINISH_STOP if self._stops(gen, first) else FINISH_LENGTH,
+                outbox,
+            )
+
+    def _dispatched(self, gen: Generation) -> None:
+        """One more token of ``gen`` is on the device.  Was it the last it
+        asked for, its slot sits the following steps out (it is released
+        when that token is read)."""
+        gen.dispatched += 1
+        if gen.dispatched >= gen.max_new_tokens:
+            self.engine.active[gen.slot] = False
+
+    def _dispatch_step(self):
+        """((the rows the step decodes, the positions it reads), dispatch
+        time, its unmaterialized output): every live slot's context, the
+        consumed token included."""
+        rows = [(slot, gen) for slot, gen in self._live.items()
+                if self.engine.active[slot]]
+        context = int(self.engine.lengths[self.engine.active].sum()) + len(rows)
+        item = (rows, context), time.perf_counter(), self.engine.step_async()
+        for _, gen in rows:
+            self._dispatched(gen)
+        return item

@@ -335,9 +335,12 @@ def render_slo(payload: dict) -> str:
 
 def generate_stream(
     gateway_url: str,
-    prompt: str,
+    prompt: str | None,
     max_new_tokens: int = 16,
     model: str | None = None,
+    token_ids: list[int] | None = None,
+    ignore_eos: bool = False,
+    top_logits: int = 0,
     deadline_ms: float | None = None,
     priority: str | None = None,
     timeout: float = 120.0,
@@ -347,9 +350,12 @@ def generate_stream(
 
     The generative lane's client half: token events stream out of this
     generator at decode speed (one dict per token: index, token id,
-    text), and the terminal event carries ``done: true`` plus the
+    text, and with ``top_logits`` k > 0 the step's k largest logits as
+    ``top_ids`` / ``top_logits``), and the terminal event carries ``done: true`` plus the
     server-measured TTFT/TPOT for the generation -- the client never has
-    to clock the stream itself.  ``model`` routes to a non-default decode
+    to clock the stream itself.  Exactly one of ``prompt`` (text) and
+    ``token_ids`` (which the model sees as they are) is sent;
+    ``ignore_eos`` decodes on to ``max_new_tokens``.  ``model`` routes to a non-default decode
     model via ``/generate/<model>``; ``deadline_ms`` and ``priority``
     propagate exactly like /predict (a mid-stream deadline expiry ends
     the stream with finish_reason "deadline").  Closing the generator
@@ -374,7 +380,12 @@ def generate_stream(
     path = "/generate" if model is None else f"/generate/{model}"
     r = requests.post(
         f"{gateway_url}{path}",
-        json={"prompt": prompt, "max_new_tokens": max_new_tokens},
+        json={
+            **({"token_ids": token_ids} if token_ids is not None
+               else {"prompt": prompt}),
+            "max_new_tokens": max_new_tokens, "ignore_eos": ignore_eos,
+            "top_logits": top_logits,
+        },
         headers=headers,
         stream=True,
         timeout=timeout,
@@ -535,7 +546,14 @@ def main(argv: list[str] | None = None) -> int:
         help="INSTEAD of predicting: stream a generation for PROMPT from "
         "the gateway's /generate route, printing each token as it "
         "arrives plus the server-measured TTFT/TPOT from the done "
-        "event; --model routes to a non-default decode model",
+        "event; --model routes to a non-default decode model.  PROMPT "
+        "is text, or with --token-ids a comma-separated list of token ids "
+        "(a model without a text codec takes only those)",
+    )
+    p.add_argument(
+        "--token-ids", action="store_true",
+        help="read --stream's PROMPT as comma-separated token ids and print "
+        "the generated ids",
     )
     p.add_argument(
         "--max-new-tokens", type=int, default=16,
@@ -551,8 +569,10 @@ def main(argv: list[str] | None = None) -> int:
     if args.stream is not None:
         stats = {}
         done = None
+        ids = ([int(t) for t in args.stream.split(",")]
+               if args.token_ids else None)
         for ev in generate_stream(
-            args.gateway, args.stream,
+            args.gateway, None if ids else args.stream, token_ids=ids,
             max_new_tokens=args.max_new_tokens, model=args.model,
             deadline_ms=args.deadline_ms, priority=args.priority,
             stats=stats,
@@ -560,7 +580,7 @@ def main(argv: list[str] | None = None) -> int:
             if ev.get("done"):
                 done = ev
                 continue
-            sys.stdout.write(ev.get("text", ""))
+            sys.stdout.write(f"{ev.get('token')} " if ids else ev.get("text", ""))
             sys.stdout.flush()
         print()
         if done is None:

@@ -31,6 +31,7 @@ from kubernetes_deep_learning_tpu.runtime.decode import (
     DecodeEngine,
     DecodeScheduler,
     decode_tokens,
+    load_decoder,
 )
 from kubernetes_deep_learning_tpu.serving import protocol
 from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
@@ -110,12 +111,16 @@ class GenerateLane:
         engine: DecodeEngine | None = None,
         engine_kwargs: dict | None = None,
         queue_cap: int | None = None,
+        model_root: str | None = None,
     ):
         self.model = model or (
             os.environ.get(DECODE_MODEL_ENV, "").strip() or DEFAULT_DECODE_MODEL
         )
+        # A decoder artifact under the models root, found by the lane's
+        # name as image artifacts are; none there -> the toy.
         self.engine = engine or DecodeEngine(
-            self.model, **(engine_kwargs or {})
+            self.model, decoder=load_decoder(model_root, self.model),
+            **(engine_kwargs or {})
         )
         self.slo = slo
         self.tracer = tracer
@@ -169,6 +174,8 @@ class GenerateLane:
         try:
             gen = self.scheduler.submit(
                 req["prompt"], req["max_new_tokens"],
+                token_ids=req["token_ids"], ignore_eos=req["ignore_eos"],
+                top_logits=req["top_logits"],
                 rid=rid, priority=priority, deadline=deadline,
             )
         except ValueError as e:
@@ -200,8 +207,7 @@ class GenerateLane:
         try:
             for ev in gen.iter_events():
                 if ev[0] == "token":
-                    _, idx, tok, text = ev
-                    yield protocol.sse_token_event(idx, tok, text)
+                    yield protocol.sse_token_event(*ev[1:])
                 else:
                     yield protocol.sse_done_event(
                         tokens=len(gen.tokens),
@@ -243,6 +249,10 @@ class GenerateLane:
             self._finish_reasons[gen.finish_reason or "cancelled"] += 1
 
     # --- observability ------------------------------------------------------
+
+    def status(self) -> dict:
+        """The lane's entry of GET /v1/models, under its served name."""
+        return {"ready": True, "decode": self.engine.status()}
 
     def debug_payload(self) -> dict:
         """The /debug/slo "decode" section: per-token latency percentiles

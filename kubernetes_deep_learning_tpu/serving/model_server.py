@@ -559,7 +559,7 @@ class ModelServer:
         if generate_lib.decode_enabled(decode):
             self.generate = generate_lib.GenerateLane(
                 registry=self.registry, slo=self.slo, tracer=self.tracer,
-                recorder=self.recorder,
+                recorder=self.recorder, model_root=model_root,
             )
             self.recorder.add_snapshot_provider(
                 "decode", self.generate.debug_payload
@@ -569,9 +569,14 @@ class ModelServer:
         self._shutdown_done = threading.Event()
         self._profile_lock = threading.Lock()
         self.poll_versions()
-        if not self.models:
+        if not self.models and self.generate is None:
+            # the generative lane alone is a server too
             raise FileNotFoundError(f"no model artifacts under {model_root!r}")
-        self._httpd = ThreadingHTTPServer((host, port), self._make_handler())
+        # The listen backlog: a closed loop of a hundred and more streams
+        # connects all at once, and what the default of 5 turns away waits
+        # for TCP's retransmission, a second and more.
+        httpd_class = type("_Httpd", (ThreadingHTTPServer,), {"request_queue_size": 256})
+        self._httpd = httpd_class((host, port), self._make_handler())
         self._httpd.daemon_threads = True
         self.port = self._httpd.server_address[1]
         self._thread: threading.Thread | None = None
@@ -598,6 +603,14 @@ class ModelServer:
     @property
     def ready(self) -> bool:
         return all(m.engine.ready for m in self.models.values())
+
+    def models_status(self) -> dict:
+        """GET /v1/models: the registry's image models and, under its
+        served name, the generative lane with its ``decode`` block."""
+        status = self.model_registry.status()
+        if self.generate is not None:
+            status[self.generate.model] = self.generate.status()
+        return status
 
     @property
     def fast_degraded(self) -> bool:
@@ -1004,10 +1017,10 @@ class ModelServer:
                     # The registry's multi-model status page: per model
                     # {version, ready, artifact_hash, buckets, family,
                     # labels} -- version/ready keep the original contract.
-                    return self._send_json(200, server.model_registry.status())
+                    return self._send_json(200, server.models_status())
                 m = _STATUS_RE.match(self.path)
                 if m:
-                    status = server.model_registry.model_status(m.group(1))
+                    status = server.models_status().get(m.group(1))
                     if status is None:
                         return self._send_json(
                             404, {"error": f"no model {m.group(1)!r}"}
@@ -1425,9 +1438,15 @@ class ModelServer:
                 metrics_lib.model_request_counter(
                     server.registry, name
                 ).inc()
+                # A stream with no stated deadline has none: the default
+                # budget is a closed request's (20 s), and a generation's
+                # length is bounded by max_new_tokens, its pace held by the
+                # per-token budgets (TTFT/TPOT).  An explicit header is
+                # honoured, mid-stream expiry included.
+                raw_deadline = self.headers.get(DEADLINE_HEADER)
                 deadline = (
-                    Deadline.from_header(self.headers.get(DEADLINE_HEADER))
-                    if server.admission.enabled
+                    Deadline.from_header(raw_deadline)
+                    if server.admission.enabled and raw_deadline
                     else None
                 )
                 priority = protocol.parse_priority(
@@ -1981,11 +2000,15 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument(
         "--decode",
         action="store_true",
-        help="ALSO serve the generative lane (/v1/models/<m>:generate): "
-        "continuous-batching autoregressive decode over a block-paged "
-        "KV-cache with streamed text/event-stream token responses and "
-        "per-token TTFT/TPOT SLOs.  Default $KDLT_DECODE=1; the model "
-        "name is $KDLT_DECODE_MODEL (gen-default)",
+        help="serve the generative lane (/v1/models/<m>:generate), beside "
+        "the image models or alone: continuous-batching autoregressive "
+        "decode over a block-paged cache with streamed text/event-stream "
+        "token responses and per-token TTFT/TPOT SLOs; a body carries "
+        "\"prompt\" or \"token_ids\", \"ignore_eos\", \"top_logits\".  "
+        "Default $KDLT_DECODE=1; the model name is $KDLT_DECODE_MODEL "
+        "(gen-default): a decoder artifact of that name under --models, "
+        "else the byte-level toy; sizes from $KDLT_DECODE_SLOTS, "
+        "_PAGE_SIZE, _MAX_PAGES, _PROMPT_BUCKETS",
     )
     p.add_argument(
         "--aot-warm",

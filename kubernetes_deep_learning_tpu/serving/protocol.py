@@ -364,42 +364,68 @@ def decode_predict_response(body: bytes, content_type: str) -> tuple[np.ndarray,
 
 
 # --- generative lane --------------------------------------------------------
-# JSON request, SSE response.  The request schema is deliberately tiny:
-# prompts are text (byte-level tokenization happens in the decode engine,
-# so there is no tokenizer contract on the wire), and every knob has a
-# server-side cap.
+# JSON request, SSE response.  A request carries exactly one of ``prompt``
+# (text: byte-level tokenization happens in the decode engine, for a model
+# that has a text codec) and ``token_ids`` (the model sees them as they
+# are: nothing is put before them; the lane checks them against its
+# vocabulary).  Every knob has a server-side cap.
 
 GENERATE_MAX_NEW_TOKENS_CAP = 1024
+GENERATE_TOP_LOGITS_CAP = 32
+GENERATE_MAX_PROMPT_IDS = 1 << 17
+
+
+def _int_field(msg: dict, name: str, default: int, low: int, high: int) -> int:
+    raw = msg.get(name, default)
+    if isinstance(raw, bool):
+        raise ValueError(f'"{name}" must be an integer')
+    try:
+        n = int(raw)
+    except (TypeError, ValueError) as e:
+        raise ValueError(f'"{name}" must be an integer') from e
+    if n < low or n > high:
+        raise ValueError(f'"{name}" must be in [{low}, {high}]')
+    return n
 
 
 def decode_generate_request(body: bytes) -> dict[str, Any]:
     """Parse and validate a /generate JSON body.
 
-    Returns ``{"prompt": str, "max_new_tokens": int, "stream": bool}``.
-    Raises ValueError on anything malformed -- the transports map that to
-    a 400, same as a bad /predict body.
+    Returns ``{"prompt": str | None, "token_ids": list[int] | None,
+    "max_new_tokens": int, "ignore_eos": bool, "top_logits": int, "stream":
+    bool}``: exactly one of ``prompt`` and ``token_ids`` is set;
+    ``top_logits`` k > 0 asks every token frame for the k largest logits of
+    its step with their ids.  Raises ValueError on anything malformed --
+    the transports map that to a 400, same as a bad /predict body.
     """
     try:
         msg = json.loads(body)
     except Exception as e:  # noqa: BLE001 - mapped to 400 by the caller
         raise ValueError(f"invalid JSON body: {e}") from e
-    if not isinstance(msg, dict) or "prompt" not in msg:
-        raise ValueError('generate body must be a JSON object with "prompt"')
-    prompt = msg["prompt"]
-    if not isinstance(prompt, str) or not prompt:
-        raise ValueError('"prompt" must be a non-empty string')
-    raw_n = msg.get("max_new_tokens", 16)
-    try:
-        n = int(raw_n)
-    except (TypeError, ValueError) as e:
-        raise ValueError('"max_new_tokens" must be an integer') from e
-    if n < 1 or n > GENERATE_MAX_NEW_TOKENS_CAP:
+    if not isinstance(msg, dict) or ("prompt" in msg) == ("token_ids" in msg):
         raise ValueError(
-            f'"max_new_tokens" must be in [1, {GENERATE_MAX_NEW_TOKENS_CAP}]'
+            'generate body must be a JSON object with exactly one of "prompt" '
+            'and "token_ids"'
         )
+    prompt = msg.get("prompt")
+    token_ids = msg.get("token_ids")
+    if token_ids is None:
+        if not isinstance(prompt, str) or not prompt:
+            raise ValueError('"prompt" must be a non-empty string')
+    else:
+        if (not isinstance(token_ids, list) or not token_ids
+                or len(token_ids) > GENERATE_MAX_PROMPT_IDS):
+            raise ValueError('"token_ids" must be a non-empty list of token ids')
+        if any(isinstance(t, bool) or not isinstance(t, int) or t < 0
+               for t in token_ids):
+            raise ValueError('"token_ids" must hold non-negative integers')
     return {
         "prompt": prompt,
-        "max_new_tokens": n,
+        "token_ids": token_ids,
+        "max_new_tokens": _int_field(
+            msg, "max_new_tokens", 16, 1, GENERATE_MAX_NEW_TOKENS_CAP),
+        "ignore_eos": bool(msg.get("ignore_eos", False)),
+        "top_logits": _int_field(msg, "top_logits", 0, 0, GENERATE_TOP_LOGITS_CAP),
         "stream": bool(msg.get("stream", True)),
     }
 
@@ -409,9 +435,16 @@ def sse_event(payload: dict[str, Any]) -> bytes:
     return b"data: " + json.dumps(payload, separators=(",", ":")).encode() + b"\n\n"
 
 
-def sse_token_event(index: int, token: int, text: str) -> bytes:
-    """A per-token event: position, token id, and its decoded text."""
-    return sse_event({"index": index, "token": token, "text": text})
+def sse_token_event(index: int, token: int, text: str, top_ids=None,
+                    top_logits=None) -> bytes:
+    """A per-token event: position, token id, its decoded text and, where
+    the request asked for them, the step's largest logits with their ids
+    (largest first, so greedy decoding has ``top_ids[0] == token``)."""
+    payload = {"index": index, "token": token, "text": text}
+    if top_ids is not None:
+        payload["top_ids"] = top_ids
+        payload["top_logits"] = top_logits
+    return sse_event(payload)
 
 
 def sse_done_event(

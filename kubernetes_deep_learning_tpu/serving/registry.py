@@ -73,9 +73,12 @@ def iter_latest_versions(model_root: str) -> list[tuple[str, int, str]]:
         version = art.latest_version(model_root, name)
         if version is None:
             continue
-        out.append(
-            (name, version, art.version_dir(model_root, name, version))
-        )
+        directory = art.version_dir(model_root, name, version)
+        if art.is_decoder_dir(directory):
+            # a decoder's artifact: the generative lane's to load
+            # (runtime.decode.load_decoder), not an image model
+            continue
+        out.append((name, version, directory))
     return out
 
 

@@ -1079,6 +1079,43 @@ def decode_metrics(registry: "Registry", model: str) -> dict:
                 "kdlt_decode_kv_pages_in_use",
                 "KV-cache pages currently allocated to live generations",
             ),
+            # Routing of a decoder with an expert layer (zeros otherwise):
+            # the top-k assignments of live slots, summed over expert layers
+            # and decode steps (prefills are not counted), by where the
+            # chosen expert lives.
+            # One series a kind, not a ``kind`` label: whoever reads a
+            # /metrics page by series name alone can still tell them apart.
+            "assignments_held": c.counter(
+                "kdlt_decode_expert_held_assignments_total",
+                "router assignments to a real expert this replica holds",
+            ),
+            "assignments_absent": c.counter(
+                "kdlt_decode_expert_absent_assignments_total",
+                "router assignments to a real expert another replica holds "
+                "(their part of the result is left out here)",
+            ),
+            "assignments_zero": c.counter(
+                "kdlt_decode_expert_zero_assignments_total",
+                "router assignments to a zero-compute (identity) expert",
+            ),
+            "experts_touched": c.counter(
+                "kdlt_decode_experts_touched_total",
+                "held experts with at least one live slot's token, summed "
+                "over expert layers and decode steps",
+            ),
+            "context_positions": c.counter(
+                "kdlt_decode_context_positions_total",
+                "cached positions a decode step attends over, summed over "
+                "live slots and steps",
+            ),
+            "prefill_prompt_tokens": c.counter(
+                "kdlt_decode_prefill_prompt_tokens_total",
+                "prompt tokens prefilled",
+            ),
+            "prefill_padding_tokens": c.counter(
+                "kdlt_decode_prefill_padding_tokens_total",
+                "padding positions prefilled (bucket size minus prompt)",
+            ),
         }
 
     return _memo_on_child(child, "_kdlt_decode", mint)

@@ -81,7 +81,8 @@ def test_sse_events_round_trip_through_the_parser():
 
 def test_decode_generate_request_validation():
     ok = protocol.decode_generate_request(b'{"prompt": "hi"}')
-    assert ok == {"prompt": "hi", "max_new_tokens": 16, "stream": True}
+    assert ok == {"prompt": "hi", "token_ids": None, "max_new_tokens": 16,
+                  "ignore_eos": False, "top_logits": 0, "stream": True}
     ok = protocol.decode_generate_request(
         b'{"prompt": "hi", "max_new_tokens": 3, "stream": false}'
     )
@@ -256,3 +257,147 @@ def test_cancel_stops_a_queued_generation(engine):
         time.sleep(0.01)
     sched.close()
     assert gen.finish_reason == decode_lib.FINISH_CANCELLED
+
+
+# --- dispatch ahead of the reads -----------------------------------------------
+
+
+def _prefill_then_steps(engine, prompt, steps):
+    """One slot's prefill and ``steps`` steps, the host naming no token."""
+    slot = engine.acquire_slot(len(prompt) + steps + 1)
+    try:
+        out = [int(engine.materialize(engine.prefill(slot, prompt)).tokens[0])]
+        for _ in range(steps):
+            out.append(int(engine.materialize(engine.step_async()).tokens[slot]))
+        return out
+    finally:
+        engine.release_slot(slot)
+
+
+@pytest.mark.parametrize("prompt, budget", [
+    ("ahead", 9), ("x", 12), ("a much longer prompt string", 4)])
+def test_a_step_consumes_the_token_the_device_left(engine, prompt, budget):
+    """The prefill's and each step's greedy choice stays on the device and
+    feeds the next step; dispatching every step before reading any gives
+    the stream that reading each first gives."""
+    tokens = decode_lib.encode_prompt(prompt)
+    one_by_one = _prefill_then_steps(engine, tokens, budget - 1)
+    slot = engine.acquire_slot(len(tokens) + budget)
+    try:
+        handles = [engine.prefill(slot, tokens)]
+        handles += [engine.step_async() for _ in range(budget - 1)]
+        outs = [engine.materialize(h) for h in handles]
+    finally:
+        engine.release_slot(slot)
+    ahead = [int(outs[0].tokens[0])] + [int(o.tokens[slot]) for o in outs[1:]]
+    assert ahead == one_by_one
+    solo = engine.decode_solo(prompt, budget)       # stops at EOS
+    assert one_by_one[:len(solo)] == solo
+
+
+def test_a_slot_that_sits_steps_out_keeps_its_token(engine):
+    """A step leaves the next token of a slot it does not decode as it
+    was: the slot goes on, two steps later, where it stood."""
+    stays = decode_lib.encode_prompt("it waits")
+    want = _prefill_then_steps(engine, stays, 4)
+    a = engine.acquire_slot(len(stays) + 5)
+    b = engine.acquire_slot(10)
+    try:
+        got = [int(engine.materialize(engine.prefill(a, stays)).tokens[0])]
+        engine.materialize(engine.prefill(b, decode_lib.encode_prompt("busy")))
+        got.append(int(engine.materialize(engine.step_async()).tokens[a]))
+        engine.active[a] = False
+        for _ in range(2):
+            engine.materialize(engine.step_async())     # only b moves
+        engine.active[a] = True
+        for _ in range(3):
+            got.append(int(engine.materialize(engine.step_async()).tokens[a]))
+    finally:
+        engine.release_slot(a)
+        engine.release_slot(b)
+    assert got == want
+
+
+def _series(registry):
+    out = {}
+    for line in registry.render().splitlines():
+        if line.startswith("kdlt_decode_"):
+            name = line.split("{", 1)[0].split(" ", 1)[0]
+            out[name] = out.get(name, 0.0) + float(line.rsplit(" ", 1)[1])
+    return out
+
+
+@pytest.mark.parametrize("budget", [1, 2, 7])
+def test_a_slot_whose_last_token_is_dispatched_sits_the_next_steps_out(engine, budget):
+    """The loop dispatches ahead, but never a step for a stream whose last
+    token is already on the device: ``budget`` tokens cost one prefill and
+    ``budget - 1`` steps, and each step reads the context it should."""
+    from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
+
+    registry = metrics_lib.Registry()
+    sched = decode_lib.DecodeScheduler(engine, registry=registry)
+    sched.start()
+    try:
+        gen = sched.submit(None, budget, token_ids=[1, 2, 3, 4, 5], ignore_eos=True)
+        events = list(gen.iter_events(timeout_s=60.0))
+    finally:
+        sched.close()
+    assert [e[0] for e in events] == ["token"] * budget + ["done"]
+    assert [e[1] for e in events[:-1]] == list(range(budget))
+    series = _series(registry)
+    assert series["kdlt_decode_steps_total"] == budget - 1
+    assert series["kdlt_decode_tokens_total"] == budget
+    # step j reads the 5 prompt positions, the j tokens before and its own
+    assert series["kdlt_decode_context_positions_total"] == sum(
+        5 + j + 1 for j in range(budget - 1))
+    assert engine.pages_in_use == 0 and engine.active_slots == 0
+
+
+def test_step_and_prefill_seconds_are_read_to_read(engine):
+    """With two steps dispatched, a step's dispatch-to-read time would
+    count its predecessor's too; the histograms take a program's time from
+    the read before it, so their sums cannot exceed the wall clock."""
+    from kubernetes_deep_learning_tpu.utils import metrics as metrics_lib
+
+    registry = metrics_lib.Registry()
+    sched = decode_lib.DecodeScheduler(engine, registry=registry)
+    t0 = time.perf_counter()
+    sched.start()
+    try:
+        gens = [sched.submit(None, n, token_ids=[7] * p, ignore_eos=True)
+                for p, n in ((3, 12), (9, 6), (4, 9))]
+        for gen in gens:
+            assert list(gen.iter_events(timeout_s=60.0))[-1] == ("done", "length")
+    finally:
+        sched.close()
+    wall = time.perf_counter() - t0
+    series = _series(registry)
+    assert series["kdlt_decode_step_seconds_count"] == series["kdlt_decode_steps_total"]
+    assert series["kdlt_decode_prefill_seconds_count"] == 3
+    assert 0 < series["kdlt_decode_step_seconds_sum"] \
+        + series["kdlt_decode_prefill_seconds_sum"] <= wall
+
+
+def test_a_stream_cancelled_with_steps_ahead_leaves_its_neighbour_exact(engine):
+    """A cancel is seen at a read, when the next step is already on the
+    device with that slot live: what it computes there is dropped, the slot
+    and its pages come back, the neighbour's stream is the solo decode's,
+    and the next stream in the freed slot is too."""
+    sched = decode_lib.DecodeScheduler(engine)
+    sched.start()
+    try:
+        keeper = sched.submit("the one that stays", 12, rid="keep")
+        goner = sched.submit("gone", 25, rid="gone")
+        it = goner.iter_events(timeout_s=60.0)
+        next(it)
+        goner.cancel()
+        assert list(it)[-1] == ("done", decode_lib.FINISH_CANCELLED)
+        after = sched.submit("after", 6, rid="after")
+        kept = [e[2] for e in keeper.iter_events(timeout_s=60.0) if e[0] == "token"]
+        late = [e[2] for e in after.iter_events(timeout_s=60.0) if e[0] == "token"]
+    finally:
+        sched.close()
+    assert len(goner.tokens) < 25
+    assert kept == engine.decode_solo("the one that stays", 12)
+    assert late == engine.decode_solo("after", 6)
+    assert engine.pages_in_use == 0 and len(engine._free_slots) == engine.max_slots
