@@ -549,3 +549,29 @@ def test_entry_chains_compile_for_v5e_at_the_rules_tile(v5e_chip, h, widths):
 
     compiled = jax.jit(chain).lower(shape((h, h, batch, widths[0]), jnp.bfloat16), stages).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("slots", [128, 127], ids=["two-slots-a-step", "one-slot-a-step"])
+def test_the_latent_attention_kernel_compiles_for_v5e_at_the_token_cells_shapes(v5e_chip, slots):
+    """The generative lane's paged latent-attention kernel (``ops/mla_decode.py``;
+    here because one test file may hold the TPU's compiler) at the shapes of
+    ``longcat-agent-decode-closed128``: a Mosaic refusal would surface only at
+    the lane's warm-up on the chip.  The result and the first operand are what
+    the benchmark's ``mla_decode_roofline.lc`` finds the call by."""
+    from kubernetes_deep_learning_tpu.ops.mla_decode import paged_mla_attention
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=v5e_chip)
+
+    heads, width, rank, page, max_pages = 64, 640, 512, 16, 96
+    compiled = jax.jit(lambda q, cache, table, n: paged_mla_attention(
+        q, cache, 3, table, n, rank=rank)).lower(
+        shape((slots, heads, width), jnp.bfloat16),
+        shape((8, 1 + slots * max_pages, page, width), jnp.bfloat16),
+        shape((slots, max_pages), jnp.int32), shape((slots,), jnp.int32)).compile()
+    lines = [ln.strip() for ln in compiled.as_text().splitlines()]
+    call = [ln for ln in lines if "custom-call(" in ln]
+    assert len(call) == 1 and "tpu_custom_call" in call[0]
+    assert f"= f32[{slots},{heads},{rank}]" in call[0]
+    first_operand = call[0].split("custom-call(")[1].split(",")[0]
+    assert any(ln.startswith(f"{first_operand} = s32[{slots * max_pages}]") for ln in lines)

@@ -231,23 +231,55 @@ def test_absorbed_attention_is_the_expanded_form_reassociated(models_root):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("max_pages, lengths", [
-    (8, [40, 128, 0, 1]), (32, [500, 17, 256, 129]), (6, [96, 95, 1, 33])])
-def test_the_kernel_is_the_gather(max_pages, lengths):
-    """Pages in any order through the page table, several chunks a slot, an
-    idle slot, a context that ends inside a page."""
-    rng = np.random.default_rng(max_pages)
-    slots, heads, width, rank, page = len(lengths), 8, 128, 64, 16
+def _kernel_case(max_pages, lengths, seed=None):
+    """Operands of one call: pages in any order through the page table."""
+    rng = np.random.default_rng(max_pages if seed is None else seed)
+    slots, heads, width, page = len(lengths), 8, 128, 16
     pool = 1 + slots * max_pages
     cache = jnp.asarray(rng.standard_normal((2, pool, page, width)), jnp.bfloat16)
     q = jnp.asarray(rng.standard_normal((slots, heads, width)) * 0.3, jnp.bfloat16)
     table = rng.permutation(np.arange(1, pool)).reshape(slots, max_pages).astype(np.int32)
-    args = (q, cache, 1, jnp.asarray(table), jnp.asarray(lengths, jnp.int32))
-    want = mla_decode.paged_mla_attention(*args, rank=rank, impl="gather")
-    got = mla_decode.paged_mla_attention(*args, rank=rank, impl="interpret")
+    return q, cache, 1, jnp.asarray(table), jnp.asarray(lengths, jnp.int32)
+
+
+@pytest.mark.parametrize("max_pages, lengths", [
+    (8, [40, 128, 0, 1]), (32, [500, 17, 256, 129]), (6, [96, 95, 1, 33]),
+    # what a pipeline that runs on from slot to slot can get wrong (64 pages
+    # a slot: up to four chunks of 256 positions)
+    (64, [0, 77, 600]),                 # the first slot idle
+    (64, [300, 0, 0, 31, 1024]),        # two idle slots in a row between live ones
+    (64, [128, 700, 0]),                # the last slot idle
+    (8, [0, 0, 0]),                     # every slot idle
+    (64, [697]),                        # one slot alone
+    (64, [256, 512, 768, 1024]),        # lengths that are whole chunks
+    (64, [9, 1024, 300, 2]),            # one chunk, then many, then fewer
+    (64, [1000, 3, 0, 700, 255, 257]),  # many chunks, then one; odd and even counts
+], ids=lambda v: "-".join(map(str, v)) if isinstance(v, list) else str(v))
+def test_the_kernel_is_the_gather(max_pages, lengths):
+    """Pages in any order through the page table, several chunks a slot, an
+    idle slot, a context that ends inside a page; the next slot's first
+    chunk in flight while the last one's is scored."""
+    args = _kernel_case(max_pages, lengths)
+    want = mla_decode.paged_mla_attention(*args, rank=64, impl="gather")
+    got = mla_decode.paged_mla_attention(*args, rank=64, impl="interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
     assert not np.asarray(got)[np.asarray(lengths) == 0].any()
     assert mla_decode.pages_per_chunk(max_pages) in (8, 16, 6)
+
+
+@pytest.mark.parametrize("order", [[5, 4, 3, 2, 1, 0], [2, 0, 5, 1, 4, 3]],
+                         ids=["reversed", "shuffled"])
+def test_a_slots_row_does_not_depend_on_its_neighbours(order):
+    """The same slots in another order give the same rows bit for bit:
+    nothing of one slot's buffer, or of its place in the pipeline (which
+    buffer of the ring its first chunk lands in), reaches its result."""
+    q, cache, sub, table, lengths = _kernel_case(64, [130, 0, 7, 600, 256, 1000], seed=33)
+    got = np.asarray(mla_decode.paged_mla_attention(
+        q, cache, sub, table, lengths, rank=64, impl="interpret"))
+    order = np.asarray(order)
+    moved = np.asarray(mla_decode.paged_mla_attention(
+        q[order], cache, sub, table[order], lengths[order], rank=64, impl="interpret"))
+    np.testing.assert_array_equal(moved, got[order])
 
 
 # --- the lane's counters ----------------------------------------------------------------------
