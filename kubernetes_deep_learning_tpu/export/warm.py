@@ -45,7 +45,9 @@ def warm_decode(engine_factory=None, model_root: str | None = None) -> dict:
     $KDLT_DECODE_MODEL names under ``model_root`` (the toy without one).
 
     The decode lane has its own compile grid, disjoint from the image
-    bucket ladder: one prefill program per prompt-length bucket, plus the
+    bucket ladder: one prefill program per prompt-length bucket up to the
+    lane's chunk size (and, where the ladder admits longer prompts, one
+    more each for a chunk that follows others), plus the
     single fixed-width step program that serves every batch-slot
     composition (continuous batching admits into a fixed [S]-slot step,
     so slot count never recompiles -- the grid is buckets x slots wide

@@ -35,17 +35,32 @@ bit-identical to the same request decoded alone.
 
 The model is a *decoder* the engine is handed (``DecodeEngine(decoder=)``):
 its vocabulary, its cache layout (``cache_spec``) and two pure functions,
-``prefill`` and ``decode_step``, that return logits and a few routing
-counts.  Two implement that small interface: ``ToyDecoder`` here, a
+``prefill`` and ``decode_step``, that return logits and a few counts
+(``N_COUNTS``).  ``ToyDecoder`` here implements that small interface: a
 deliberately tiny byte-level causal transformer with per-head K/V pages
 (weights derived deterministically from the model name) -- the default
 when no artifact is named, so the lane's shape- and schedule-level
-contracts are testable without a checkpoint -- and
-``models.longcat_flash.LongcatDecoder``, loaded from an artifact directory
-(``load_decoder``), whose pages hold latents.  The greedy choice, the top
-logits a request may ask for and the packing of everything a step returns
-into ONE device array (so the step's single host sync carries it all) are
-the engine's, once, for every decoder.
+contracts are testable without a checkpoint.  The others are loaded from an
+artifact directory by the ``family`` its index file states
+(``load_decoder``: the module of ``models/`` of that name), and their pages
+hold latents.  The greedy choice, the top logits a request may ask for and
+the packing of everything a step returns into ONE device array (so the
+step's single host sync carries it all) are the engine's, once, for every
+decoder.
+
+**Chunked prefill** (Sarathi-Serve, OSDI '24).  ``prefill(params, cache,
+tokens, start, length, page_ids)`` computes one *chunk* of a prompt: rows at
+positions ``start .. start + T``, true below ``length``; it writes their
+K/V (or latents) and attends over ``[0, start + T)`` -- the pages already
+written and the chunk itself.  A prompt's first chunk is handed the Python
+int ``start = 0`` and attends to itself alone: a prompt that fits one
+program is exactly that one chunk.  A prompt longer than ``PREFILL_CHUNK``
+is full chunks of that many rows and one last chunk at the smallest rung of
+the ladder that holds the remainder, and the scheduler dispatches at most
+one chunk between two decode steps: a long prompt holds the live slots for
+one chunk's time, not for its whole length.  ``prompt_buckets`` is the
+ladder a chunk pads to and the longest prompt admitted; rungs above the
+chunk size admit long prompts and compile nothing.
 
 The host is not in the step's period.  The token a slot consumes next stays
 on the device (a prefill and a step each leave their greedy choice in
@@ -107,9 +122,24 @@ PROMPT_BUCKETS = (16, 32, 64)
 # What a step returns beside the tokens: the largest logits of every slot
 # (a request asks for up to this many, serving.protocol caps it there).
 TOP_LOGITS = protocol.GENERATE_TOP_LOGITS_CAP
-# Routing counts a decoder returns with its logits (zeros from one without
-# experts): held / absent / zero-compute assignments, held experts touched.
-N_COUNTS = 4
+# Counts a decoder returns with its logits (zeros from one without experts),
+# over the call's true rows and summed over its expert layers: held / absent /
+# zero-compute assignments, held experts touched, (row, held expert) products
+# computed (padding and masked rows included), rows a shared expert met.
+N_COUNTS = 6
+# Rows of one prefill program at the most: a longer prompt is prefilled in
+# chunks of this many, one between two decode steps.  Measured on a v5e with
+# the second decoder at its published widths (``exp/kimi_lane.py``; PERF.md
+# section 6, PR 34), an 8,192-token prompt chunk by chunk: chunks of 512 rows
+# take 15-47 ms each (16.1k prompt tokens/s), of 1,024 rows 41-125 ms
+# (12.5k), of 2,048 rows 111-258 ms (11.3k), beside a decode step of 14.8 ms.
+# A smaller chunk prefills faster alone (the chunk's own scores grow with
+# its square) but with one chunk between two steps a prompt of n tokens
+# costs n / chunk rounds of a chunk and a step: 1,024 rows is the least
+# device time a request at that cell's lengths (3,460-token prompts, 192
+# tokens out), and holds the live slots for a tenth of a second at the most.
+# Where prompts are chunked it has to be a whole number of pages.
+PREFILL_CHUNK = 1024
 # Steps the scheduler keeps dispatched: the one it reads next and the one
 # the device goes on to meanwhile.
 STEPS_AHEAD = 2
@@ -274,17 +304,21 @@ def _decode_step(params, cache, page_table, lengths, last_tokens, active):
     return cache, _logits(params, x), jnp.zeros((N_COUNTS,), jnp.int32)
 
 
-def _prefill(params, cache, tokens, length, page_ids):
-    """One prompt's prefill at one bucket shape T = len(tokens).
+def _prefill(params, cache, tokens, start, length, page_ids):
+    """One chunk of a prompt at one compiled shape T = len(tokens).
 
-    ``tokens``   [T] int32  (BOS + prompt bytes, padded to the bucket)
-    ``length``   scalar int32 (true token count)
+    ``tokens``   [T] int32  rows at positions ``start ..`` (BOS + prompt
+                 bytes, padded to the shape)
+    ``start``    the Python int 0 for a prompt's first chunk, else a
+                 traced int32: the positions already in the slot's pages
+    ``length``   scalar int32: rows at positions below it are true
     ``page_ids`` [max_pages] int32 -- this slot's page list
 
-    Full causal self-attention within the prompt (never reads the cache),
-    K/V written to the slot's pages (padding positions to the trash
-    page).  Returns ``(cache, logits [V] of the last true position,
-    counts)``: the first generated token is their greedy argmax.
+    K/V written to the slot's pages (padding positions to the trash page);
+    causal attention over ``[0, start + T)`` -- a first chunk within itself
+    (it never reads the cache), a later one over the slot's gathered pages.
+    Returns ``(cache, logits [V] of the last true position, counts)``: the
+    first generated token is the last chunk's greedy argmax.
     """
     import jax.numpy as jnp
 
@@ -292,13 +326,20 @@ def _prefill(params, cache, tokens, length, page_ids):
     page = cache.shape[3]
     n_heads = cache.shape[4]
     t_len = tokens.shape[0]
+    first = isinstance(start, int) and start == 0
 
-    pos = jnp.arange(t_len, dtype=jnp.int32)
+    pos = start + jnp.arange(t_len, dtype=jnp.int32)
     x = params["embed"][tokens] + params["pos"][pos]                # [T, D]
     real = pos < length
-    write_page = jnp.where(real, page_ids[pos // page], 0)
+    slot_page = jnp.minimum(pos // page, page_ids.shape[0] - 1)
+    write_page = jnp.where(real, page_ids[slot_page], 0)
     write_off = pos % page
-    causal = (pos[None, :] <= pos[:, None]) & real[None, :]         # [T, T]
+    if first:
+        keys = pos
+        visible = (pos[None, :] <= pos[:, None]) & real[None, :]    # [T, T]
+    else:
+        keys = jnp.arange(page_ids.shape[0] * page, dtype=jnp.int32)
+        visible = (keys[None, :] <= pos[:, None]) & (keys[None, :] < length)
 
     for li in range(n_layers):
         layer = params["layers"][li]
@@ -306,15 +347,19 @@ def _prefill(params, cache, tokens, length, page_ids):
         cache = cache.at[li, 0, write_page, write_off].set(k)
         cache = cache.at[li, 1, write_page, write_off].set(v)
         head_dim = q.shape[-1]
+        if not first:                                               # the slot's whole context
+            k = cache[li, 0][page_ids].reshape(keys.shape[0], n_heads, head_dim)
+            v = cache[li, 1][page_ids].reshape(keys.shape[0], n_heads, head_dim)
         scores = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(head_dim)
-        scores = jnp.where(causal[None, :, :], scores, -1e9)
+        scores = jnp.where(visible[None, :, :], scores, -1e9)
         w = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
         w = w / w.sum(axis=-1, keepdims=True)
         attn = jnp.einsum("hqk,khd->qhd", w, v).reshape(t_len, -1)
         x = x + attn @ layer["wo"]
         x = x + _mlp(layer, x)
 
-    return cache, _logits(params, x[length - 1]), jnp.zeros((N_COUNTS,), jnp.int32)
+    return (cache, _logits(params, x[length - 1 - start]),
+            jnp.zeros((N_COUNTS,), jnp.int32))
 
 
 class ToyDecoder:
@@ -348,9 +393,13 @@ class ToyDecoder:
 def load_decoder(model_root: str | None, model: str):
     """The decoder of the artifact ``<model_root>/<model>/<highest version>/``
     (found as image artifacts are found), or None where there is none: the
-    lane then serves the toy."""
+    lane then serves the toy.  The artifact's index file states its
+    ``family``: the module of ``models/`` of that name serves it, through
+    the class it calls ``DECODER``."""
+    import importlib
+
     from kubernetes_deep_learning_tpu.export import artifact as art
-    from kubernetes_deep_learning_tpu.models import longcat_flash
+    from kubernetes_deep_learning_tpu.models import latent_attention
 
     if not model_root:
         return None
@@ -360,7 +409,17 @@ def load_decoder(model_root: str | None, model: str):
     directory = art.version_dir(model_root, model, version)
     if not art.is_decoder_dir(directory):
         return None
-    return longcat_flash.LongcatDecoder.load(directory)
+    family = str(latent_attention.read_meta(directory).get("family", ""))
+    module = None
+    if family.isidentifier():
+        try:
+            module = importlib.import_module(
+                f"kubernetes_deep_learning_tpu.models.{family}")
+        except ImportError:
+            pass
+    if getattr(module, "DECODER", None) is None:
+        raise ValueError(f"{directory}: no decoder of family {family!r} under models/")
+    return module.DECODER.load(directory)
 
 
 def _pack(logits, counts, top: int):
@@ -442,6 +501,20 @@ class DecodeEngine:
                 "no prefill bucket fits inside the "
                 f"{self.max_context}-token context"
             )
+        # The shapes a prefill program is compiled at: the rungs up to the
+        # chunk size, and the chunk size itself where a longer rung admits
+        # prompts that are prefilled in chunks.
+        self.prefill_chunk = PREFILL_CHUNK
+        self.chunked = self.prompt_buckets[-1] > self.prefill_chunk
+        if self.chunked and self.prefill_chunk % self.page_size:
+            raise ValueError(
+                f"prompts over {self.prefill_chunk} tokens are prefilled in chunks "
+                f"of that many, which the page size {self.page_size} has to divide"
+            )
+        self.chunk_shapes = tuple(
+            b for b in self.prompt_buckets if b < self.prefill_chunk
+        ) + ((self.prefill_chunk,) if self.prompt_buckets[-1] >= self.prefill_chunk
+             else ())
         self._donate = donation_enabled(donate)
         if decoder is None:
             # No artifact: the toy, its weights keyed by the model name.
@@ -485,11 +558,14 @@ class DecodeEngine:
             packed = _pack(logits, counts, top)
             return cache, packed, jnp.where(active, packed[:slots, 0], next_tokens)
 
-        def prefill(params, cache, next_tokens, tokens, length, page_ids, slot):
+        def prefill(params, cache, next_tokens, tokens, start, length, page_ids, slot):
             cache, logits, counts = decoder.prefill(
-                params, cache, tokens, length, page_ids)
+                params, cache, tokens, start, length, page_ids)
             packed = _pack(logits[None], counts, top)
             return cache, packed, next_tokens.at[slot].set(packed[0, 0])
+
+        def prefill_first(params, cache, next_tokens, tokens, length, page_ids, slot):
+            return prefill(params, cache, next_tokens, tokens, 0, length, page_ids, slot)
 
         donated = (1,) if self._donate else ()
         if self._donate:
@@ -499,7 +575,10 @@ class DecodeEngine:
                 "ignore", message="Some donated buffers were not usable"
             )
         self._step_jit = jax.jit(step, donate_argnums=donated)
-        self._prefill_jit = jax.jit(prefill, donate_argnums=donated)
+        # A prompt's first chunk (start 0, nothing cached: it attends to
+        # itself) and a later chunk (start traced) are two programs a shape.
+        self._prefill_jit = jax.jit(prefill_first, donate_argnums=donated)
+        self._prefill_next_jit = jax.jit(prefill, donate_argnums=donated)
 
     def status(self) -> dict:
         """The ``decode`` block of GET /v1/models: the sizes the lane was
@@ -508,6 +587,7 @@ class DecodeEngine:
             "slots": self.max_slots, "page_size": self.page_size,
             "max_pages": self.max_pages_per_seq,
             "prompt_buckets": list(self.prompt_buckets),
+            "prefill_chunk": self.prefill_chunk,
             "vocab_size": self.vocab_size, "cache_bytes": self.cache_bytes,
             "top_logits": self.top_logits, **self.decoder.describe(),
         }
@@ -560,22 +640,46 @@ class DecodeEngine:
 
     # --- device dispatch ----------------------------------------------------
 
-    def prefill(self, slot: int, prompt_tokens: list[int]):
-        """Dispatch one prompt's prefill into ``slot``; returns the
-        unmaterialized handle of its packed output (S = 1).  The slot is
-        live afterwards: its length covers the prompt and the next step
-        consumes the first token, which the prefill leaves on the device."""
+    def chunk_at(self, n: int, start: int) -> tuple[int, int]:
+        """(true rows, compiled shape) of the chunk of an ``n``-token prompt
+        that begins at ``start``: a full chunk, or the rest of the prompt at
+        the smallest shape that holds it."""
+        rows = min(n - start, self.prefill_chunk)
+        return rows, prompt_bucket(rows, self.chunk_shapes)
+
+    def prefill_chunk_async(self, slot: int, prompt_tokens: list[int], start: int):
+        """Dispatch the chunk of the prompt that begins at ``start`` into
+        ``slot``; returns (the unmaterialized handle of its packed output,
+        S = 1; true rows; compiled shape).  With its last chunk the slot is
+        live: its length covers the prompt and the next step consumes the
+        first token, which that chunk leaves on the device."""
         n = len(prompt_tokens)
-        bucket = prompt_bucket(n, self.prompt_buckets)
-        padded = np.zeros((bucket,), np.int32)
-        padded[:n] = prompt_tokens
-        self._cache, first, self._next_tokens = self._prefill_jit(
-            self._params, self._cache, self._next_tokens, padded,
-            np.int32(n), self.page_table[slot].copy(), np.int32(slot),
-        )
-        self.lengths[slot] = n
-        self.active[slot] = True
-        return first
+        rows, shape = self.chunk_at(n, start)
+        padded = np.zeros((shape,), np.int32)
+        padded[:rows] = prompt_tokens[start:start + rows]
+        args = (np.int32(start + rows), self.page_table[slot].copy(), np.int32(slot))
+        if start == 0:
+            self._cache, out, self._next_tokens = self._prefill_jit(
+                self._params, self._cache, self._next_tokens, padded, *args)
+        else:
+            self._cache, out, self._next_tokens = self._prefill_next_jit(
+                self._params, self._cache, self._next_tokens, padded,
+                np.int32(start), *args)
+        if start + rows == n:
+            self.lengths[slot] = n
+            self.active[slot] = True
+        return out, rows, shape
+
+    def prefill(self, slot: int, prompt_tokens: list[int]):
+        """Dispatch one prompt's prefill into ``slot``, every chunk of it
+        back to back; returns the handle of the last chunk's packed output,
+        whose greedy choice is the first token."""
+        start = 0
+        while True:
+            out, rows, _ = self.prefill_chunk_async(slot, prompt_tokens, start)
+            start += rows
+            if start >= len(prompt_tokens):
+                return out
 
     def step_async(self):
         """Dispatch one batched decode step; returns the unmaterialized
@@ -627,23 +731,40 @@ class DecodeEngine:
             self.release_slot(slot)
 
     def warmup(self, buckets: tuple[int, ...] | None = None) -> dict:
-        """Compile the decode ladder: every prefill bucket plus the step
-        program (the prompt-length x batch-slot grid is one step compile
-        wide -- the step runs at fixed width by construction).  Returns
-        the per-program wall times for kdlt-warm's report."""
-        report = {"model": self.model, "buckets": {}, "step_s": 0.0}
+        """Compile the decode ladder: every shape a prompt's first chunk
+        can take (the rungs up to the chunk size), every shape a later
+        chunk can take where prompts are chunked, plus the step program
+        (the prompt-length x batch-slot grid is one step compile wide --
+        the step runs at fixed width by construction).  Rungs above the
+        chunk size compile nothing.  Returns the per-program wall times for
+        kdlt-warm's report."""
+        report = {"model": self.model, "buckets": {}, "chunks": {}, "step_s": 0.0}
+
+        def run(n: int) -> float | None:
+            t0 = time.perf_counter()
+            slot = self.acquire_slot(min(n + 1, self.max_context))
+            if slot is None:
+                return None
+            try:
+                self.materialize(self.prefill(slot, [0] * n))
+            finally:
+                self.release_slot(slot)
+            return round(time.perf_counter() - t0, 4)
+
         for b in buckets or self.prompt_buckets:
             if b > self.max_context:
                 continue
-            t0 = time.perf_counter()
-            slot = self.acquire_slot(min(b + 1, self.max_context))
-            if slot is None:
+            took = run(min(b, self.prefill_chunk))
+            if took is None:
                 break
-            try:
-                self.materialize(self.prefill(slot, [0] * b))
-            finally:
-                self.release_slot(slot)
-            report["buckets"][str(b)] = round(time.perf_counter() - t0, 4)
+            report["buckets"][str(b)] = took
+        if self.chunked:
+            for b in self.chunk_shapes:     # a full chunk, then the rest at shape b
+                if self.prefill_chunk + b > self.max_context:
+                    continue
+                took = run(self.prefill_chunk + b)
+                if took is not None:
+                    report["chunks"][str(b)] = took
         t0 = time.perf_counter()
         slot = self.acquire_slot(2)
         if slot is not None:
@@ -683,6 +804,9 @@ class Generation:
     tokens: list[int] = field(default_factory=list)
     finish_reason: str | None = None
     slot: int | None = None
+    prefilled: int = 0       # prompt positions whose chunks are dispatched
+    t_prefill: float | None = None   # when its first chunk began on the device
+    prefill_span: str = ""           # the span its chunks' spans hang under
     dispatched: int = 0      # tokens the device was asked for (read or not)
     events: Queue = field(default_factory=Queue)
     _cancel: threading.Event = field(default_factory=threading.Event)
@@ -722,15 +846,26 @@ class Generation:
                 return
 
 
+@dataclass
+class Chunk:
+    """One dispatched chunk of a generation's prompt."""
+    gen: Generation
+    start: int      # the position of its first row
+    rows: int       # true prompt positions in it
+    shape: int      # rows computed: the compiled shape it was padded to
+    last: bool      # the prompt's last chunk: its output is the first token
+
+
 class DecodeScheduler:
     """The per-step scheduler: admission queue in, token events out.
 
     Continuous batching (the lane's reason to exist): every loop
     iteration first slot-fills freed decode slots from the queue (by
     (priority rank, absolute deadline) order -- same shed order as the
-    image tier), dispatches their prefills and ONE batched step behind the
-    step already on the device, then reads the oldest of what is
-    dispatched and fans its tokens out to their generations.
+    image tier), dispatches ONE chunk of the oldest admitted prompt and ONE
+    batched step behind what is already on the device, then reads the
+    oldest of what is dispatched and fans its tokens out to their
+    generations.
     """
 
     def __init__(
@@ -757,6 +892,7 @@ class DecodeScheduler:
         )
         self._queue: list[Generation] = []
         self._live: dict[int, Generation] = {}
+        self._prefilling: deque[Generation] = deque()   # admitted, chunks to go
         self._cond = threading.Condition()
         self._seq = 0
         self._closed = False
@@ -931,11 +1067,12 @@ class DecodeScheduler:
         outbox.clear()
 
     def _count_routing(self, counts: np.ndarray) -> None:
-        held, absent, zero, touched = (int(c) for c in counts)
+        held, absent, zero, touched = (int(c) for c in counts[:4])
         self.metrics["assignments_held"].inc(held)
         self.metrics["assignments_absent"].inc(absent)
         self.metrics["assignments_zero"].inc(zero)
         self.metrics["experts_touched"].inc(touched)
+        self.metrics["shared_expert_tokens"].inc(int(counts[5]))
 
     def _stops(self, gen: Generation, token: int) -> bool:
         return token == self.engine.decoder.eos_token and not gen.ignore_eos
@@ -966,14 +1103,17 @@ class DecodeScheduler:
         device (``DecodeEngine._next_tokens``) and everything else a dispatch
         needs -- lengths, pages, who is live, who has its last token coming
         -- the host knows without the tokens, so the loop keeps ``STEPS_AHEAD``
-        steps on the device and reads the oldest: the device goes from one
+        rounds on the device and reads the oldest: the device goes from one
         program to the next with no host in between, and the host has a
-        whole step's time for the one before.  What was dispatched for a
-        stream that the read then ends (EOS, cancel, deadline) is computed
-        into its own pages and dropped."""
+        whole step's time for the one before.  A round is one chunk of the
+        oldest admitted prompt, where one waits, then one step, where a slot
+        is live: a prompt of many chunks holds the live slots for one
+        chunk's time at once.  What was dispatched for a stream that the
+        read then ends (EOS, cancel, deadline) is computed into its own
+        pages and dropped."""
         outbox: list = []
         inflight: deque = deque()   # dispatched and not read, oldest first
-        steps_ahead = 0
+        rounds_ahead = 0
         read_at = 0.0               # when the last read returned
         while True:
             with self._cond:
@@ -993,31 +1133,29 @@ class DecodeScheduler:
                     return
                 admitted = self._admit_locked()
 
-            # Prefill the admissions (one compiled bucket each), behind
-            # whatever step is running; the first token comes straight out
-            # of each prefill -- reading it IS the TTFT moment.
             for gen in admitted:
                 self._live[gen.slot] = gen
-                inflight.append(
-                    (gen, time.perf_counter(),
-                     self.engine.prefill(gen.slot, gen.prompt_tokens)))
-                self._dispatched(gen)
-            while steps_ahead < STEPS_AHEAD and self.engine.active.any():
-                inflight.append(self._dispatch_step())
-                steps_ahead += 1
+                self._prefilling.append(gen)
+            while rounds_ahead < STEPS_AHEAD:
+                items = self._dispatch_round(outbox)
+                if not items:
+                    break
+                inflight.extend(
+                    (*item, i == len(items) - 1) for i, item in enumerate(items))
+                rounds_ahead += 1
             # The last read's events go out beside the device's work.
             self._flush(outbox)
             if not inflight:
                 continue
 
-            first, dispatched, handle = inflight.popleft()
+            first, dispatched, handle, ends_round = inflight.popleft()
             out = self.engine.materialize(handle)      # the one host sync
             now = time.perf_counter()
             t0, read_at = max(dispatched, read_at), now   # behind another: from its end
-            if isinstance(first, Generation):
-                self._read_prefill(first, out, dispatched, t0, now, outbox)
+            rounds_ahead -= ends_round
+            if isinstance(first, Chunk):
+                self._read_chunk(first, out, dispatched, t0, now, outbox)
                 continue
-            steps_ahead -= 1
             rows, context = first
             if self.metrics:
                 self.metrics["steps"].inc()
@@ -1037,26 +1175,81 @@ class DecodeScheduler:
                 elif gen.deadline is not None and gen.deadline.expired:
                     self._retire(gen, FINISH_DEADLINE, outbox)
 
-    def _read_prefill(self, gen: Generation, out: StepOutput, dispatched: float,
-                      t0: float, now: float, outbox: list) -> None:
+    def _dispatch_round(self, outbox: list) -> list:
+        """One chunk of the oldest admitted prompt that still has chunks to
+        go (a stream cancelled or out of time between two of its chunks
+        gives its slot and pages back instead), then one step over the live
+        slots.  Returns what was dispatched, in the device's order, as
+        (what, dispatch time, unmaterialized output); nothing where neither
+        was there to do."""
+        items = []
+        while self._prefilling:
+            gen = self._prefilling[0]
+            if not gen.done and not gen.cancelled and not (
+                    gen.deadline is not None and gen.deadline.expired):
+                break
+            self._prefilling.popleft()
+            if not gen.done:
+                self._retire(gen, FINISH_CANCELLED if gen.cancelled else FINISH_DEADLINE,
+                             outbox)
+        if self._prefilling:
+            gen = self._prefilling[0]
+            start, t = gen.prefilled, time.perf_counter()
+            handle, rows, shape = self.engine.prefill_chunk_async(
+                gen.slot, gen.prompt_tokens, start)
+            gen.prefilled += rows
+            last = gen.prefilled >= len(gen.prompt_tokens)
+            if last:        # the first token is on the device
+                self._prefilling.popleft()
+                self._dispatched(gen)
+            items.append((Chunk(gen, start, rows, shape, last), t, handle))
+        if self.engine.active.any():
+            items.append(self._dispatch_step())
+        return items
+
+    def _read_chunk(self, chunk: "Chunk", out: StepOutput, dispatched: float,
+                    t0: float, now: float, outbox: list) -> None:
+        """One chunk's program has run: ``t0`` to ``now`` was its time on the
+        device (behind another program: from that one's read)."""
+        gen = chunk.gen
         if self.metrics:
-            n = len(gen.prompt_tokens)
-            self.metrics["prefill_seconds"].observe(now - t0)
-            self.metrics["prefill_prompt_tokens"].inc(n)
-            self.metrics["prefill_padding_tokens"].inc(
-                prompt_bucket(n, self.engine.prompt_buckets) - n)
-            self.metrics["active_slots"].set(self.engine.active_slots)
-            self.metrics["pages_in_use"].set(self.engine.pages_in_use)
+            m = self.metrics
+            m["prefill_seconds"].observe(now - t0)
+            m["prefill_chunks"].inc()
+            m["prefill_tokens"].inc(chunk.rows)
+            m["prefill_padded_tokens"].inc(chunk.shape)
+            m["prefill_prompt_tokens"].inc(chunk.rows)
+            m["prefill_padding_tokens"].inc(chunk.shape - chunk.rows)
+            m["prefill_attended_pairs"].inc(
+                chunk.rows * chunk.start + chunk.rows * (chunk.rows + 1) // 2)
+            m["prefill_routed_rows"].inc(int(out.counts[0]))
+            m["prefill_expert_rows"].inc(int(out.counts[4]))
+            m["shared_expert_tokens"].inc(int(out.counts[5]))
+            m["active_slots"].set(self.engine.active_slots)
+            m["pages_in_use"].set(self.engine.pages_in_use)
+        if gen.t_prefill is None:
+            gen.t_prefill, gen.prefill_span = t0, trace_lib.new_span_id()
+            if self.tracer is not None:
+                self.tracer.record(
+                    gen.rid, trace_lib.SPAN_DECODE_QUEUE_WAIT,
+                    gen.t_submit, dispatched - gen.t_submit,
+                )
         if self.tracer is not None:
             self.tracer.record(
-                gen.rid, trace_lib.SPAN_DECODE_QUEUE_WAIT,
-                gen.t_submit, dispatched - gen.t_submit,
+                gen.rid, trace_lib.SPAN_DECODE_PREFILL_CHUNK, t0, now - t0,
+                parent_id=gen.prefill_span, start=chunk.start, rows=chunk.rows,
             )
-            self.tracer.record(
-                gen.rid, trace_lib.SPAN_DECODE_PREFILL, t0, now - t0,
-            )
+            if chunk.last:
+                self.tracer.record(
+                    gen.rid, trace_lib.SPAN_DECODE_PREFILL, gen.t_prefill,
+                    now - gen.t_prefill, span_id=gen.prefill_span,
+                )
+        if not chunk.last or gen.done:
+            return
         first = self._take(gen, out, 0, now, outbox)
-        if self._stops(gen, first) or len(gen.tokens) >= gen.max_new_tokens:
+        if gen.cancelled:
+            self._retire(gen, FINISH_CANCELLED, outbox)
+        elif self._stops(gen, first) or len(gen.tokens) >= gen.max_new_tokens:
             self._retire(
                 gen,
                 FINISH_STOP if self._stops(gen, first) else FINISH_LENGTH,

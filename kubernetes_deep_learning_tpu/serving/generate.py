@@ -10,6 +10,13 @@ outcomes.  A decode-lane burn therefore moves the same burn-rate gauges
 and the same brownout ladder: stage >= 3 sheds best-effort generations
 exactly like best-effort image predicts.
 
+A prompt may be as long as the lane's largest prompt bucket
+($KDLT_DECODE_PROMPT_BUCKETS; longer is a 400 at submit).  Past the lane's
+chunk size (``runtime.decode.PREFILL_CHUNK``, 1,024 rows) it is prefilled in
+chunks between the decode steps of the streams already live, so its TTFT
+grows with its length while theirs' TPOT grows by one chunk's time a step;
+the buckets up to the chunk size are the compiled shapes a chunk pads to.
+
 Streamed responses are iterators of SSE frames, never complete bodies --
 which is why the response cache's store predicate refuses
 ``text/event-stream`` outright (serving.cache.storable_response): a

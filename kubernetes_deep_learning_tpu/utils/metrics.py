@@ -1064,7 +1064,8 @@ def decode_metrics(registry: "Registry", model: str) -> dict:
             ),
             "prefill_seconds": c.histogram(
                 "kdlt_decode_prefill_seconds",
-                "wall time of one prompt prefill (bucketed compile ladder)",
+                "wall time of one prefill program: one chunk of a prompt "
+                "(a prompt that fits one program is one chunk)",
                 buckets=PIPELINE_STAGE_BUCKETS,
             ),
             "active_slots": c.gauge(
@@ -1115,6 +1116,42 @@ def decode_metrics(registry: "Registry", model: str) -> dict:
             "prefill_padding_tokens": c.counter(
                 "kdlt_decode_prefill_padding_tokens_total",
                 "padding positions prefilled (bucket size minus prompt)",
+            ),
+            # Chunked prefill, one series a kind as above.
+            "prefill_chunks": c.counter(
+                "kdlt_decode_prefill_chunks_total",
+                "prefill programs run: chunks of prompts",
+            ),
+            "prefill_tokens": c.counter(
+                "kdlt_decode_prefill_tokens_total",
+                "true prompt positions prefilled",
+            ),
+            "prefill_padded_tokens": c.counter(
+                "kdlt_decode_prefill_padded_tokens_total",
+                "positions the prefill programs computed: every chunk's "
+                "compiled shape, padding included",
+            ),
+            "prefill_attended_pairs": c.counter(
+                "kdlt_decode_prefill_attended_pairs_total",
+                "(query, key) pairs of true prompt positions the prefill "
+                "chunks attended over, causally: n (n + 1) / 2 a prompt of n",
+            ),
+            "prefill_routed_rows": c.counter(
+                "kdlt_decode_prefill_routed_rows_total",
+                "router assignments of true prompt positions to a real "
+                "expert this replica holds, summed over expert layers "
+                "(prefills only: the decode steps' are the held assignments)",
+            ),
+            "prefill_expert_rows": c.counter(
+                "kdlt_decode_prefill_expert_rows_total",
+                "(row, held expert) products the prefill programs computed, "
+                "summed over expert layers: equal to the routed rows where "
+                "nothing is computed for a row an expert was not routed",
+            ),
+            "shared_expert_tokens": c.counter(
+                "kdlt_decode_shared_expert_tokens_total",
+                "true rows a shared expert computed, summed over expert "
+                "layers, prefill chunks and decode steps",
             ),
         }
 

@@ -95,6 +95,10 @@ SPAN_GATEWAY_GENERATE = "gateway.generate"
 SPAN_SERVER_GENERATE = "server.generate"
 SPAN_DECODE_QUEUE_WAIT = "decode.queue_wait"
 SPAN_DECODE_PREFILL = "decode.prefill"
+# One chunk of a prompt's prefill, a child of decode.prefill (which runs from
+# the first chunk's start on the device to the last one's read), tagged with
+# the position of its first row (``start``) and its true ``rows``.
+SPAN_DECODE_PREFILL_CHUNK = "decode.prefill_chunk"
 SPAN_DECODE_FIRST_TOKEN = "decode.first_token"
 SPAN_DECODE_STREAM = "decode.stream"
 
@@ -127,6 +131,7 @@ SPAN_NAMES = frozenset({
     SPAN_SERVER_GENERATE,
     SPAN_DECODE_QUEUE_WAIT,
     SPAN_DECODE_PREFILL,
+    SPAN_DECODE_PREFILL_CHUNK,
     SPAN_DECODE_FIRST_TOKEN,
     SPAN_DECODE_STREAM,
 })

@@ -401,3 +401,173 @@ def test_a_stream_cancelled_with_steps_ahead_leaves_its_neighbour_exact(engine):
     assert kept == engine.decode_solo("the one that stays", 12)
     assert late == engine.decode_solo("after", 6)
     assert engine.pages_in_use == 0 and len(engine._free_slots) == engine.max_slots
+
+
+# --- chunked prefill ---------------------------------------------------------------
+
+
+CHUNK = 16      # the lane's chunk size in these tests (PREFILL_CHUNK is 1,024)
+
+
+@pytest.fixture(scope="module")
+def chunked_engine():
+    """The toy at a chunk size of 16 rows: rungs 8 and 16 compile, the rung
+    of 64 admits prompts that take up to four chunks."""
+    saved, decode_lib.PREFILL_CHUNK = decode_lib.PREFILL_CHUNK, CHUNK
+    try:
+        return decode_lib.DecodeEngine(
+            "gen-chunked", max_slots=3, page_size=8, max_pages_per_seq=10,
+            prompt_buckets=(8, 16, 64))
+    finally:
+        decode_lib.PREFILL_CHUNK = saved
+
+
+@pytest.mark.parametrize("buckets, chunk, shapes, chunked", [
+    ((16, 32, 64), 1024, (16, 32, 64), False),     # every prompt is one chunk
+    ((8, 16, 64), 16, (8, 16), True),              # the chunk size is a rung
+    ((8, 24, 64), 16, (8, 16), True),              # ... or it is not: it compiles all the same
+    ((256, 512, 1024, 2048, 4096, 8192), 1024, (256, 512, 1024), True),
+])
+def test_the_shapes_a_prefill_program_is_compiled_at(monkeypatch, buckets, chunk, shapes,
+                                                     chunked):
+    monkeypatch.setattr(decode_lib, "PREFILL_CHUNK", chunk)
+    engine = decode_lib.DecodeEngine("gen-shapes", max_slots=1, page_size=8,
+                                     max_pages_per_seq=1100, prompt_buckets=buckets)
+    assert engine.chunk_shapes == shapes and engine.chunked is chunked
+    assert engine.status()["prompt_buckets"] == list(buckets)
+    assert engine.status()["prefill_chunk"] == chunk
+
+
+@pytest.mark.parametrize("n, plan", [
+    (5, [(0, 5, 8)]), (16, [(0, 16, 16)]), (17, [(0, 16, 16), (16, 1, 8)]),
+    (41, [(0, 16, 16), (16, 16, 16), (32, 9, 16)]), (64, [(s, 16, 16) for s in (0, 16, 32, 48)]),
+])
+def test_a_prompt_is_full_chunks_and_a_rest_at_the_smallest_rung(chunked_engine, n, plan):
+    got, start = [], 0
+    while start < n:
+        rows, shape = chunked_engine.chunk_at(n, start)
+        got.append((start, rows, shape))
+        start += rows
+    assert got == plan
+
+
+def test_a_page_size_that_does_not_divide_the_chunk_is_refused(monkeypatch):
+    monkeypatch.setattr(decode_lib, "PREFILL_CHUNK", 20)
+    with pytest.raises(ValueError, match="page size"):
+        decode_lib.DecodeEngine("gen-odd", max_slots=1, page_size=8, max_pages_per_seq=10,
+                                prompt_buckets=(8, 64))
+
+
+@pytest.mark.parametrize("n", [17, 24, 33, 40, 49, 64], ids=lambda n: f"{n}-tokens")
+def test_the_toy_in_chunks_gives_the_logits_of_one_program(chunked_engine, n):
+    """A rest in each rung after one, two and three full chunks.  The toy
+    is float32: a later chunk's softmax over the gathered context against
+    one softmax differ by rounding alone (1e-5 of the largest logit)."""
+    import numpy as np
+
+    whole = decode_lib.DecodeEngine(
+        "gen-chunked", max_slots=3, page_size=8, max_pages_per_seq=10,
+        prompt_buckets=(8, 16, 64))
+    assert not whole.chunked and chunked_engine.chunked
+    prompt = [decode_lib.BOS_TOKEN] + [(7 * i + n) % 256 for i in range(n - 1)]
+    rows = []
+    for engine in (whole, chunked_engine):
+        slot = engine.acquire_slot(n + 4)
+        try:
+            outs = [engine.materialize(engine.prefill(slot, prompt))]
+            outs += [engine.materialize(engine.step_async()) for _ in range(3)]
+        finally:
+            engine.release_slot(slot)
+        rows.append((np.stack([outs[0].top_ids[0]] + [o.top_ids[slot] for o in outs[1:]]),
+                     np.stack([outs[0].top_logits[0]] + [o.top_logits[slot] for o in outs[1:]])))
+    (ids, logits), (ids_c, logits_c) = rows
+    assert (ids_c[:, 0] == ids[:, 0]).all()
+    assert float(np.abs(logits_c - logits).max()) < 1e-5 * float(np.abs(logits).max())
+
+
+def test_at_most_one_chunk_goes_between_two_steps(chunked_engine, monkeypatch):
+    """With a stream live, a prompt of four chunks arrives: the device's
+    order is chunk, step, chunk, step, never two chunks running; the live
+    stream is the solo decode's all the same, and so is the long one."""
+    order = []
+    chunk_async, step_async = chunked_engine.prefill_chunk_async, chunked_engine.step_async
+    monkeypatch.setattr(chunked_engine, "prefill_chunk_async",
+                        lambda *a: (order.append("chunk"), chunk_async(*a))[1])
+    monkeypatch.setattr(chunked_engine, "step_async",
+                        lambda: (order.append("step"), step_async())[1])
+    long_prompt = [decode_lib.BOS_TOKEN] + [(3 * i) % 256 for i in range(60)]
+    sched = decode_lib.DecodeScheduler(chunked_engine)
+    sched.start()
+    try:
+        live = sched.submit("stays live", 30, ignore_eos=True)
+        it = live.iter_events(timeout_s=60.0)
+        next(it)
+        late = sched.submit(None, 5, token_ids=long_prompt, ignore_eos=True)
+        late_tokens = [e[2] for e in late.iter_events(timeout_s=60.0) if e[0] == "token"]
+        live_tokens = [live.tokens[0]] + [e[2] for e in it if e[0] == "token"]
+    finally:
+        sched.close()
+    assert order.count("chunk") == 1 + 4      # "stays live" is BOS + 10 bytes: one chunk
+    first_late = order.index("chunk", 1)      # past the live stream's own
+    window = order[first_late:]
+    window = window[:len(window) - window[::-1].index("chunk")]     # to the last chunk
+    assert "step" in window and all(
+        not (a == b == "chunk") for a, b in zip(window, window[1:]))
+    monkeypatch.undo()
+    solo = chunked_engine.decode_solo("stays live", 30)          # stops at EOS
+    assert len(live_tokens) == 30 and live_tokens[:len(solo)] == solo
+    solo = chunked_engine.decode_solo(long_prompt, 5)
+    assert len(late_tokens) == 5 and late_tokens[:len(solo)] == solo
+
+
+def test_a_stream_cancelled_between_two_of_its_chunks_frees_slot_and_pages(chunked_engine):
+    """The cancel is seen when the prompt's next chunk is due: no more of it
+    is dispatched, slot and pages come back, the neighbour's stream is the
+    solo decode's, and the next stream in the freed slot is too."""
+    dispatched = []
+    chunk_async = chunked_engine.prefill_chunk_async
+    goner_prompt = [decode_lib.BOS_TOKEN] + [(5 * i) % 256 for i in range(63)]
+
+    def watched(slot, tokens, start):
+        dispatched.append((len(tokens), start))
+        if len(tokens) == 64 and start == 16:
+            goner.cancel()              # between its second chunk and its third
+        return chunk_async(slot, tokens, start)
+
+    chunked_engine.prefill_chunk_async = watched
+    sched = decode_lib.DecodeScheduler(chunked_engine)
+    sched.start()
+    try:
+        keeper = sched.submit("the one that stays", 12, rid="keep")
+        goner = sched.submit(None, 8, token_ids=goner_prompt, rid="gone")
+        assert list(goner.iter_events(timeout_s=60.0)) == [("done", decode_lib.FINISH_CANCELLED)]
+        after = sched.submit("after", 6, rid="after")
+        kept = [e[2] for e in keeper.iter_events(timeout_s=60.0) if e[0] == "token"]
+        late = [e[2] for e in after.iter_events(timeout_s=60.0) if e[0] == "token"]
+    finally:
+        sched.close()
+        del chunked_engine.prefill_chunk_async
+    assert [d for d in dispatched if d[0] == 64] == [(64, 0), (64, 16)]
+    assert goner.tokens == [] and goner.slot is None
+    assert kept == chunked_engine.decode_solo("the one that stays", 12)
+    assert late == chunked_engine.decode_solo("after", 6)
+    assert chunked_engine.pages_in_use == 0
+    assert len(chunked_engine._free_slots) == chunked_engine.max_slots
+
+
+def test_no_program_is_compiled_after_the_warm_up(monkeypatch):
+    """``warmup`` compiles every shape a prompt can meet -- a first chunk
+    and a later chunk at each rung up to the chunk size, and the step --
+    so prompts in every rung, of one chunk and of several, compile nothing."""
+    monkeypatch.setattr(decode_lib, "PREFILL_CHUNK", CHUNK)
+    engine = decode_lib.DecodeEngine("gen-warm", max_slots=2, page_size=8,
+                                     max_pages_per_seq=10, prompt_buckets=(8, 16, 32, 64))
+    report = engine.warmup()
+    assert sorted(report["buckets"]) == ["16", "32", "64", "8"]
+    assert sorted(report["chunks"]) == ["16", "8"]
+    programs = (engine._prefill_jit, engine._prefill_next_jit, engine._step_jit)
+    compiled = [p._cache_size() for p in programs]
+    assert compiled == [2, 2, 1]
+    for n in (1, 8, 9, 16, 17, 24, 25, 32, 40, 41, 63, 64):
+        engine.decode_solo([decode_lib.BOS_TOKEN] + [65] * (n - 1), 3)
+    assert [p._cache_size() for p in programs] == compiled

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from kubernetes_deep_learning_tpu.models import latent_attention as la
 from kubernetes_deep_learning_tpu.models import longcat_flash as lf
 from kubernetes_deep_learning_tpu.ops import mla_decode
 from kubernetes_deep_learning_tpu.runtime import decode as decode_lib
@@ -204,7 +205,7 @@ def test_a_token_routed_only_to_zero_experts_returns_its_weighted_self():
     assert bool((chosen >= 8).all())
     np.testing.assert_allclose(np.asarray(y), np.asarray(gates.sum(-1, keepdims=True) * x),
                                rtol=1e-6, atol=1e-6)
-    assert counts.tolist() == [0, 0, 5 * TOPK, 0]
+    assert counts.tolist() == [0, 0, 5 * TOPK, 0, 5 * 4, 0]    # 5 rows x 4 held experts
 
 
 # --- attention ------------------------------------------------------------------------------
@@ -218,14 +219,14 @@ def test_absorbed_attention_is_the_expanded_form_reassociated(models_root):
     t, page, max_pages = 21, 8, 4
     x = jnp.asarray(np.random.default_rng(4).standard_normal((t, 64)), jnp.float32)
     pos = jnp.arange(t)
-    cos, sin = lf._rope_angles(cfg, pos)
-    q_nope, q_rope, latent = lf._queries_and_latent(cfg, a, x, cos, sin)
-    expanded = lf.expanded_attention(cfg, a, q_nope, q_rope, latent,
+    cos, sin = la.rope_angles(cfg.mla, pos)
+    q_nope, q_rope, latent = la.queries_and_latent(cfg.mla, a, x, cos, sin)
+    expanded = la.expanded_attention(cfg.mla, a, q_nope, q_rope, latent,
                                      pos[None, :] <= pos[:, None])[-1]
     page_ids = jnp.asarray([3, 1, 5, 0])
     cache = jnp.zeros((cfg.sublayers, 7, page, cfg.cache_width), jnp.float32)
     cache = cache.at[1, page_ids[pos // page], pos % page].set(latent)
-    absorbed = lf.absorbed_attention(cfg, a, q_nope[-1:], q_rope[-1:], cache, 1,
+    absorbed = la.absorbed_attention(cfg.mla, a, q_nope[-1:], q_rope[-1:], cache, 1,
                                      page_ids[None, :max_pages], jnp.asarray([t]), "gather")[0]
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded),
                                rtol=2e-5, atol=2e-5)
@@ -467,3 +468,33 @@ def test_the_image_registry_passes_a_decoder_artifact_by(models_root):
     assert iter_latest_versions(models_root) == []
     assert decode_lib.load_decoder(models_root, "no-such-model") is None
     assert decode_lib.load_decoder(None, "lc-tiny") is None
+
+
+# --- the served logits are the parent's, bit for bit ----------------------------------------
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_the_served_logits_are_bit_for_bit_what_they_were(tmp_path, compute_dtype):
+    """``tests/golden/longcat_lane_logits.json`` holds what the lane served
+    at this size on seed 7 before the latent-attention code moved into
+    ``models/latent_attention.py`` and the prefill learned chunks (PR 34,
+    written by the parent commit's code): a prefill of 13 tokens and six
+    steps, the eight largest logits of each and their ids, as float32 bits."""
+    with open(os.path.join(os.path.dirname(__file__), "golden",
+                           "longcat_lane_logits.json")) as f:
+        golden = json.load(f)[compute_dtype]
+    root = str(tmp_path)
+    write_artifact(root, compute_dtype=compute_dtype)
+    engine = make_engine(root, "gather")
+    prompt = golden["prompt"]
+    assert prompt == np.random.default_rng(11).integers(0, 64, 13).tolist()
+    slot = engine.acquire_slot(len(prompt) + 8)
+    out = engine.materialize(engine.prefill(slot, prompt))
+    ids, logits = [out.top_ids[0]], [out.top_logits[0]]
+    for _ in range(6):
+        out = engine.materialize(engine.step_async())
+        ids.append(out.top_ids[slot])
+        logits.append(out.top_logits[slot])
+    assert np.asarray(ids)[:, :8].tolist() == golden["top_ids"]
+    bits = np.asarray(logits, np.float32)[:, :8].view(np.uint32)
+    assert bits.tolist() == golden["top_logit_bits"]
