@@ -1,16 +1,22 @@
 #!/usr/bin/env python3
-"""Why the chip waits, read off one /debug/profile capture (PR 24).
+"""Why the chip waits, read off one /debug/profile capture (PR 24; the
+token cells' lane since PR 37).
 
     python3 exp/profile_gaps.py capture --workload <cell> --seed N --out DIR [--annotations]
     python3 exp/profile_gaps.py analyze <trace dir or *.xplane.pb> [--out F.json]
 
 ``capture`` boots a cell of BENCHMARK.json as perfbench does (same artifact,
-server flags, bodies and closed-loop callers), and while the traffic runs
-asks the program's own ``GET /debug/profile?seconds=2`` for a trace: the
-device's planes and, with ``--annotations``, the model tier's live spans as
-host annotations.  It reports what the capture cost (start/stop seconds,
-bytes, pictures answered per second before, during and after it), each
-pipeline stage's mean over the run, and then runs ``analyze``.
+server flags, bodies and closed-loop callers, through the cell's own entry:
+tensors, or token streams for a ``server-generate`` cell), and while the
+traffic runs asks the program's own ``GET /debug/profile?seconds=2`` for a
+trace: the device's planes and, with ``--annotations``, the model tier's
+live spans, the decode loop's phases and the GC pauses as host annotations.
+It reports what the capture cost (start/stop seconds, bytes, pictures or
+tokens answered per second before, during and after it), the program's
+counters over the capture (the dispatcher's three, or the decode loop's
+phases, dry-ups, CPU seconds and GC pauses, scraped just before and after
+it), and then runs ``analyze``; for a token cell it sets the loop's dry
+seconds beside the trace's idle between programs.
 
 ``analyze`` is the hand-made prototype of what a later ``benchmark`` PR
 puts into ``perfbench/reduce_trace.py``:
@@ -21,9 +27,11 @@ puts into ``perfbench/reduce_trace.py``:
 - launch to start: from the end of each ``pipeline.dispatch`` annotation
   to the start of the next device program -- how long the device waits for
   a batch the host already counts as in flight (its input's transfer);
+- the lane's clock check: end of each ``decode.loop.read`` minus end of
+  the program it waited for;
 - the ten longest gaps between device programs, each with the annotation
-  open meanwhile on the dispatching thread, the readback thread and the
-  handler threads;
+  open meanwhile on the dispatching thread, the readback thread, the
+  decode loop's thread and the handler threads, and the GC pauses on any;
 - how many events the host plane holds, by name.
 
 The parent never imports jax (one process per chip): ``analyze`` runs as a
@@ -47,6 +55,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 DISPATCH, READBACK = "pipeline.dispatch", "pipeline.readback"
+LOOP, LOOP_READ, GC_PAUSE = "decode.loop.", "decode.loop.read", "gc.pause"
 TOP = 10
 
 
@@ -114,20 +123,22 @@ def analyze_profile(profile) -> dict:
     # a stall at the capture's start would show here (and in first_second)
     out["first_device_event_after_host_s"] = (t0 - host_first) / 1e9 if host_first else None
 
-    # the clock check, over every readback that ended inside the device window
+    # the clock checks, over every readback (the image dispatcher's) and
+    # every read (the decode loop's) that ended inside the device window
     ends = [m[1] for m in modules]
-    deltas = []
-    for _, spans in threads:
-        for s, e, name in spans:
-            if name != READBACK or e < ends[0]:
-                continue
-            done = max(m for m in ends if m <= e)
-            deltas.append((e - done) / 1e3)
-    if deltas:
-        out["readback_end_minus_module_end_us"] = {
-            "n": len(deltas), "median": statistics.median(deltas),
-            "min": min(deltas), "max": max(deltas),
-            "quartiles": statistics.quantiles(deltas, n=4) if len(deltas) > 3 else None}
+    for key, sync in (("readback", READBACK), ("loop_read", LOOP_READ)):
+        deltas = []
+        for _, spans in threads:
+            for s, e, name in spans:
+                if name != sync or e < ends[0]:
+                    continue
+                done = max(m for m in ends if m <= e)
+                deltas.append((e - done) / 1e3)
+        if deltas:
+            out[f"{key}_end_minus_module_end_us"] = {
+                "n": len(deltas), "median": statistics.median(deltas),
+                "min": min(deltas), "max": max(deltas),
+                "quartiles": statistics.quantiles(deltas, n=4) if len(deltas) > 3 else None}
 
     # launch to start: the next program's start after each dispatch returned
     starts = [m[0] for m in modules]
@@ -148,6 +159,8 @@ def analyze_profile(profile) -> dict:
         names = {n for _, _, n in spans}
         if DISPATCH in names:
             return "dispatch"
+        if any(n.startswith(LOOP) for n in names):
+            return "loop"
         return "readback" if READBACK in names else "handler"
 
     gaps = sorted(((b0 - a1, a1, b0) for (_, a1), (b0, _) in zip(busy, busy[1:])),
@@ -155,25 +168,33 @@ def analyze_profile(profile) -> dict:
     table = []
     for length, a, b in gaps:
         row = {"at_ms": (a - t0) / 1e6, "gap_ms": length / 1e6,
-               "dispatch": {}, "readback": {}, "handlers": {}}
+               "dispatch": {}, "readback": {}, "loop": {}, "handlers": {}, "gc_ms": 0.0}
         for _, spans in threads:
             r = role(spans)
             for s, e, name in spans:
                 ov = _overlap(s, e, a, b)
                 if ov <= 0:
                     continue
-                if r == "handler":
+                if name == GC_PAUSE:
+                    # a pause stops every thread, whichever one it ran on
+                    row["gc_ms"] += ov / 1e6
+                elif r == "handler":
                     # thread-milliseconds of the gap under each annotation,
                     # summed over handler threads (nested spans both count)
                     row["handlers"][name] = row["handlers"].get(name, 0.0) + ov / 1e6
                 else:
                     row[r][name] = row[r].get(name, 0.0) + 100.0 * ov / length
         table.append(row)
-    out["gaps_total_ms"] = sum(b0 - a1 for (_, a1), (b0, _) in zip(busy, busy[1:])) / 1e6
-    # over the whole device window: the share of it the dispatching and the
-    # readback thread spend under each annotation, and the mean number of
-    # handler threads under each
-    whole: dict = {"dispatch": {}, "readback": {}, "handler": {}}
+    gap_lengths = [b0 - a1 for (_, a1), (b0, _) in zip(busy, busy[1:])]
+    out["gaps_total_ms"] = sum(gap_lengths) / 1e6
+    # what launch to launch costs with the next program queued is tens of
+    # microseconds; a gap of a millisecond or more is the device left without one
+    out["gaps_over_1ms"] = {"n": sum(g >= 1e6 for g in gap_lengths),
+                            "total_ms": sum(g for g in gap_lengths if g >= 1e6) / 1e6}
+    # over the whole device window: the share of it the dispatching, the
+    # readback and the decode loop's thread spend under each annotation, and
+    # the mean number of handler threads under each
+    whole: dict = {"dispatch": {}, "readback": {}, "loop": {}, "handler": {}}
     for _, spans in threads:
         r = role(spans)
         for s, e, name in spans:
@@ -186,10 +207,34 @@ def analyze_profile(profile) -> dict:
 # --- capture -----------------------------------------------------------------
 
 
+# the decode loop's counters (runtime.decode.LoopClock), summed over labels
+START_S = 0.05      # /debug/profile's start of the trace, from the call
+LOOP_PHASES = ("wait", "admit", "dispatch", "flush", "read", "book")
+LANE = ([f"kdlt_decode_loop_{p}_seconds_total" for p in LOOP_PHASES]
+        + ["kdlt_decode_loop_cpu_seconds_total", "kdlt_decode_dry_seconds_total",
+           "kdlt_decode_dry_total", "kdlt_gc_pause_seconds_total",
+           "kdlt_decode_steps_total", "kdlt_decode_prefill_chunks_total"])
+PIPELINE = ["kdlt_pipeline_idle_dispatch_seconds_total",
+            "kdlt_pipeline_idle_no_batch_seconds_total",
+            "kdlt_pipeline_inflight_seconds_total"]
+
+
+def _lane_reading(d: dict) -> dict:
+    """The four per-layer quantities of the token cells over a counter delta."""
+    life = sum(d[f"kdlt_decode_loop_{p}_seconds_total"] for p in LOOP_PHASES)
+    host = sum(d[f"kdlt_decode_loop_{p}_seconds_total"]
+               for p in ("admit", "dispatch", "flush", "book"))
+    reads = d["kdlt_decode_steps_total"] + d["kdlt_decode_prefill_chunks_total"]
+    return dict(d, life_s=life, decode_dry_pct=100 * d["kdlt_decode_dry_seconds_total"] / life,
+                decode_host_ms=1000 * host / max(reads, 1.0),
+                decode_offcpu_pct=100 * (host - d["kdlt_decode_loop_cpu_seconds_total"]) / host,
+                gc_pause_pct=100 * d["kdlt_gc_pause_seconds_total"] / life)
+
+
 def capture(workload: str, seed: int, out_dir: str, seconds: float, window: float,
             annotations: bool = False, root: str = ROOT, platform: str = "tpu") -> dict:
     from perfbench import manifest as manifest_lib
-    from perfbench import procs, traffic
+    from perfbench import procs, tokens, traffic
     from perfbench import run as run_lib
 
     os.makedirs(out_dir, exist_ok=True)
@@ -197,40 +242,55 @@ def capture(workload: str, seed: int, out_dir: str, seconds: float, window: floa
     run = run_lib.CellRun(manifest, manifest.cell(workload), seed, window, False,
                           platform=platform)
     report: dict = {"workload": workload, "seed": seed}
+    lead = float(run.mix["lead_in_s"])
     try:
         run.prepare()
         st = run.boot_server()
         report["status_memory"] = st.get("memory")
         run.warm()
         run.mark("warm")
-        entry = traffic.ServerTensor(run.server, run.model, run.bodies)
+        lane = hasattr(run, "pool")     # the token wire's entry made a pool of prompts
+        if lane:
+            entry = tokens.ServerGenerate(run.server, run.model, run.bodies)
+            rows, names = [(i,) for i in range(len(run.pool))], LANE
+        else:
+            entry = traffic.ServerTensor(run.server, run.model, run.bodies)
+            rows, names = run.body_rows, PIPELINE
         box: dict = {}
 
         def drive():
             box["outcomes"], box["t_zero"] = traffic.run_closed(
-                entry, run.mix, seed, 3.0, window, run.body_rows)
+                entry, run.mix, seed, lead, window, rows)
 
-        three = ["kdlt_pipeline_idle_dispatch_seconds_total",
-                 "kdlt_pipeline_idle_no_batch_seconds_total",
-                 "kdlt_pipeline_inflight_seconds_total"]
-
-        def three_now():
+        def counters_now():
             page = procs.parse_metrics(procs.scrape(run.server))
-            return time.monotonic(), {s: page[s] for s in three}
+            return time.monotonic(), {s: page.get(s, 0.0) for s in names}
 
         th = threading.Thread(target=drive)
         th.start()
-        time.sleep(3.0)
+        time.sleep(lead)
         first_page = procs.parse_metrics(procs.scrape(run.server))
-        t_first, first = three_now()
+        t_first, first = counters_now()
         time.sleep(window * 0.4)
         before = procs.parse_metrics(procs.scrape(run.server))
+        inside: list = []
+
+        def scrape_inside(at):
+            # the trace's own seconds: the profiler starts ~50 ms into the
+            # call, and the process may stand still at its stop
+            for t in (at + START_S, at + START_S + seconds):
+                time.sleep(max(0.0, t - time.monotonic()))
+                inside.append((time.monotonic() - at, counters_now()[1]))
+
         p0 = time.monotonic()
+        scraper = threading.Thread(target=scrape_inside, args=(p0,))
+        scraper.start()
         reply = procs.get_json(
             run.server,
             f"/debug/profile?seconds={seconds}&annotations={int(annotations)}",
             timeout=seconds + 900)
         p1 = time.monotonic()
+        scraper.join()
         after = procs.parse_metrics(procs.scrape(run.server))
         th.join(timeout=window + 1200)
         t_zero = box["t_zero"]
@@ -239,33 +299,44 @@ def capture(workload: str, seed: int, out_dir: str, seconds: float, window: floa
         report["status_after"] = procs.get_json(run.server, "/v1/models")[run.model].get("memory")
 
         def rate(a, b):
+            if lane:   # token frames as they arrived (monotonic stamps)
+                return sum(a <= x - t_zero < b for o in box["outcomes"]
+                           if getattr(o, "stream", None) for x in o.stream.arrivals) / (b - a)
             done = [o for o in box["outcomes"] if o.status == 200 and a <= o.done_s < b]
             return sum(len(o.rows) for o in done) / (b - a)
 
         a, b = p0 - t_zero, p1 - t_zero
-        report["images_per_s"] = {
+        report["tokens_per_s" if lane else "images_per_s"] = {
             "before": rate(1.0, a), "during": rate(a, b), "after": rate(b, window),
             "whole": rate(0.0, window), "capture_from_s": a, "capture_to_s": b}
         report["failed"] = sum(1 for o in box["outcomes"] if o.status != 200)
-        d = {s: after[s] - before[s] for s in three}
-        report["counters_during_capture"] = dict(
-            d, sum_s=sum(d.values()),
-            starved_pct=100.0 * (1 - d[three[2]] / sum(d.values())))
-        # every instant is booked to one of the three: between two scrapes
-        # their sum advances by the wall seconds that passed (a scrape lags
-        # by the time since the dispatcher's last transition, a batch at most)
-        t_last, last = three_now()
+        d = {s: after.get(s, 0.0) - before.get(s, 0.0) for s in names}
+        if lane:
+            report["counters_during_capture"] = _lane_reading(d)
+        else:
+            report["counters_during_capture"] = dict(
+                d, sum_s=sum(d.values()),
+                starved_pct=100.0 * (1 - d[names[2]] / sum(d.values())))
+        # every instant is booked to one phase or cause: between two scrapes
+        # their sum advances by the wall seconds that passed (a scrape lags by
+        # the time since the last transition: a batch, a read or 0.5 s idle)
+        t_last, last = counters_now()
+        booked = LANE[:len(LOOP_PHASES)] if lane else names
         report["counters_window"] = {
             "wall_s": t_last - t_first,
-            "sum_s": sum(last[s] - first[s] for s in three),
+            "sum_s": sum(last[s] - first[s] for s in booked),
             "totals": last}
-        # each pipeline stage's mean over the run, from the histograms
-        report["stage_ms"] = {
-            st: 1000.0 * (after[f"kdlt_pipeline_{st}_seconds_sum"]
-                          - first_page[f"kdlt_pipeline_{st}_seconds_sum"])
-            / max(1.0, after[f"kdlt_pipeline_{st}_seconds_count"]
-                  - first_page[f"kdlt_pipeline_{st}_seconds_count"])
-            for st in ("enqueue_wait", "dispatch", "execute", "readback")}
+        if lane:
+            report["counters_window"]["reading"] = _lane_reading(
+                {s: last[s] - first[s] for s in names})
+        else:
+            # each pipeline stage's mean over the run, from the histograms
+            report["stage_ms"] = {
+                st: 1000.0 * (after[f"kdlt_pipeline_{st}_seconds_sum"]
+                              - first_page[f"kdlt_pipeline_{st}_seconds_sum"])
+                / max(1.0, after[f"kdlt_pipeline_{st}_seconds_count"]
+                      - first_page[f"kdlt_pipeline_{st}_seconds_count"])
+                for st in ("enqueue_wait", "dispatch", "execute", "readback")}
         # by label set, at the run's end: the engine's input paths (PR 29;
         # whole buckets sent as views of the body should all read "view")
         # and the compile requests, which stand still once the server is warm
@@ -273,7 +344,8 @@ def capture(workload: str, seed: int, out_dir: str, seconds: float, window: floa
             series: float(value)
             for series, _, value in (line.rpartition(" ")
                                      for line in procs.scrape(run.server).splitlines())
-            if series.startswith(("kdlt_engine_input_total{", "kdlt_engine_batches_total"))}
+            if series.startswith(("kdlt_engine_input_total{", "kdlt_engine_batches_total",
+                                  "kdlt_decode_dry_seconds_total{"))}
         report["compile_requests"] = {
             "at_warm": first_page.get("kdlt_xla_compile_requests_total"),
             "at_end": after.get("kdlt_xla_compile_requests_total")}
@@ -284,9 +356,10 @@ def capture(workload: str, seed: int, out_dir: str, seconds: float, window: floa
         # read the trace where it lies (the run's directory is emptied when
         # the next run starts); keep a copy only if it is small enough to
         # bring back from the chip's machine
+        analysis_path = os.path.join(out_dir, "analysis.json")
         child = subprocess.run(
             [sys.executable, os.path.abspath(__file__), "analyze", reply["trace_dir"],
-             "--out", os.path.join(out_dir, "analysis.json")],
+             "--out", analysis_path],
             env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True, text=True)
         if report["trace_bytes"] < 16 << 20:
             shutil.copytree(reply["trace_dir"], os.path.join(out_dir, "trace"),
@@ -296,6 +369,23 @@ def capture(workload: str, seed: int, out_dir: str, seconds: float, window: floa
     report["analyze_rc"] = child.returncode
     if child.returncode:
         report["analyze_err"] = child.stderr[-2000:]
+    elif lane:
+        # the program's dry seconds over the capture beside the trace's idle
+        # between programs: the scrapes hold the start and stop of the capture
+        # besides its seconds, and a dry-up is booked when it ends
+        with open(analysis_path) as f:
+            found = json.load(f)
+        (a0, c0), (a1, c1) = inside
+        dry = {s: c1[s] - c0[s] for s in names}
+        report["dry_vs_trace"] = {
+            "dry_ms": 1000 * dry["kdlt_decode_dry_seconds_total"],
+            "dry_ups": dry["kdlt_decode_dry_total"],
+            "scraped_at_s": [a0, a1], "reading": _lane_reading(dry),
+            "dry_ms_scrape_to_scrape": 1000 * d["kdlt_decode_dry_seconds_total"],
+            "trace_gaps_ms": found.get("gaps_total_ms"),
+            "trace_gaps_over_1ms": found.get("gaps_over_1ms"),
+            "trace_window_s": found.get("device_window_s"),
+            "scrape_to_scrape_s": p1 - p0}
     with open(os.path.join(out_dir, "capture.json"), "w") as f:
         json.dump(report, f, indent=1)
     return report

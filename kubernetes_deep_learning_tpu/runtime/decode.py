@@ -879,6 +879,129 @@ class Chunk:
     kernel: bool    # its attention ran in the chunk kernel
 
 
+_LOOP_SPANS = {
+    "wait": trace_lib.SPAN_DECODE_LOOP_WAIT,
+    "admit": trace_lib.SPAN_DECODE_LOOP_ADMIT,
+    "dispatch": trace_lib.SPAN_DECODE_LOOP_DISPATCH,
+    "flush": trace_lib.SPAN_DECODE_LOOP_FLUSH,
+    "read": trace_lib.SPAN_DECODE_LOOP_READ,
+    "book": trace_lib.SPAN_DECODE_LOOP_BOOK,
+}
+
+
+class LoopClock:
+    """The decode loop's own account of its time, kept on its thread.
+
+    Every instant from the loop's start to its end is in exactly one phase
+    of ``metrics_lib.DECODE_LOOP_PHASES`` (``enter`` moves from one to the
+    next), and each phase is a profiler annotation on the loop's thread
+    while it lasts.  At each boundary the newest dispatched output is
+    probed (``is_ready``): the device runs programs in the order they were
+    dispatched, so a ready one means nothing is queued behind it.  A dry-up
+    runs from the last probe that found the device busy to the next
+    dispatch -- an upper bound by at most the phase it began in -- or, found
+    at a read's return, from that return: the read had waited for its
+    program, so there it is a lower bound by however late the read came
+    back.  It counts while the loop has something the device could be
+    given (``has_work``: a live slot still stepping, a prompt with chunks to
+    go or one queued), and is booked to the phase the device ran dry in.
+    Sums are kept in attributes and published once a read (``publish``),
+    not once a row."""
+
+    def __init__(self, metrics: dict | None, annotate, has_work):
+        self.metrics, self.annotate, self.has_work = metrics, annotate, has_work
+        self.born = self._t = time.perf_counter()
+        self.ended: float | None = None
+        self._cpu_t = time.thread_time()
+        self.phase = "admit"
+        self._scope = None
+        self._open_scope()
+        self.wall = dict.fromkeys(_LOOP_SPANS, 0.0)   # not yet published
+        self.cpu = 0.0
+        self.newest = None          # the newest dispatched output, unread or not
+        self.busy_at = self.born    # the last instant it was known queued
+        self.dry_since: float | None = None
+        self.dry_phase = ""
+
+    def _open_scope(self) -> None:
+        if self.annotate is not None:
+            self._scope = self.annotate(_LOOP_SPANS[self.phase])
+            self._scope.__enter__()
+
+    def enter(self, phase: str, now: float | None = None) -> None:
+        """Close the open phase at ``now`` and open ``phase``."""
+        if phase == self.phase:
+            return
+        now = time.perf_counter() if now is None else now
+        ended = self.phase
+        self.wall[ended] += now - self._t
+        self._t, self.phase = now, phase
+        host = ended in metrics_lib.DECODE_HOST_PHASES
+        if host != (phase in metrics_lib.DECODE_HOST_PHASES):
+            cpu = time.thread_time()
+            if host:
+                self.cpu += cpu - self._cpu_t
+            self._cpu_t = cpu
+        if self._scope is not None:
+            self._scope.__exit__(None, None, None)
+        self._open_scope()
+        if self.dry_since is not None:
+            if phase == "wait" or not self.has_work():
+                self._end_dry(now)      # the lane emptied: no longer dry
+                self.newest = None
+        elif self.newest is not None:
+            if self.newest.is_ready():
+                if self.has_work():
+                    # a read returns only once its program has run: it
+                    # bounds the device's last busy instant better than the
+                    # probe before it, a whole step earlier
+                    self.dry_since = now if ended == "read" else self.busy_at
+                    self.dry_phase = ended
+                else:
+                    self.newest = None  # idle with nothing to do: not dry
+            else:
+                self.busy_at = now
+
+    def dispatched(self, t: float, newest) -> None:
+        """A round went to the device at ``t``; ``newest`` is its last output."""
+        if self.dry_since is not None:
+            self._end_dry(t)
+        self.newest, self.busy_at = newest, t
+
+    def _end_dry(self, t: float) -> None:
+        if self.metrics:
+            self.metrics["dry_seconds"][self.dry_phase].inc(max(0.0, t - self.dry_since))
+            self.metrics["dry"][self.dry_phase].inc()
+        self.dry_since = None
+
+    def publish(self) -> None:
+        """Hand the sums kept since the last call, the open phase's wall
+        time so far included, to the counters (its CPU time goes with the
+        phase's end: a read publishes just after one)."""
+        now = time.perf_counter()
+        self.wall[self.phase] += now - self._t
+        self._t = now
+        if self.metrics:
+            for phase, seconds in self.wall.items():
+                if seconds:
+                    self.metrics["loop_" + phase].inc(seconds)
+            self.metrics["loop_cpu"].inc(self.cpu)
+        self.wall = dict.fromkeys(_LOOP_SPANS, 0.0)
+        self.cpu = 0.0
+
+    def close(self) -> None:
+        """The loop ends: its last phase, and any open dry-up, end now."""
+        if self.phase in metrics_lib.DECODE_HOST_PHASES:
+            self.cpu += time.thread_time() - self._cpu_t
+        self.publish()
+        self.ended = self._t
+        if self.dry_since is not None:
+            self._end_dry(self.ended)
+        if self._scope is not None:
+            self._scope.__exit__(None, None, None)
+            self._scope = None
+
+
 class DecodeScheduler:
     """The per-step scheduler: admission queue in, token events out.
 
@@ -1138,13 +1261,21 @@ class DecodeScheduler:
         inflight: deque = deque()   # dispatched and not read, oldest first
         rounds_ahead = 0
         read_at = 0.0               # when the last read returned
+        clock = self.clock = LoopClock(
+            self.metrics, self.tracer.annotate if self.tracer is not None else None,
+            lambda: bool(self._queue or self._prefilling) or bool(self.engine.active.any()))
         while True:
+            if not inflight and not self._live:
+                clock.enter("flush")
+                self._flush(outbox)
+            clock.enter("admit")
             with self._cond:
-                if not inflight and not self._live:
-                    self._flush(outbox)
                 while (not inflight and not self._closed and not self._queue
                        and not self._live):
+                    clock.enter("wait")
                     self._cond.wait(timeout=0.5)
+                    clock.publish()
+                clock.enter("admit")
                 if self._closed:
                     for gen in self._queue:
                         gen.finish_reason = FINISH_CANCELLED
@@ -1153,27 +1284,34 @@ class DecodeScheduler:
                     for gen in list(self._live.values()):
                         self._retire(gen, FINISH_CANCELLED, outbox)
                     self._flush(outbox)
+                    clock.close()
                     return
                 admitted = self._admit_locked()
 
             for gen in admitted:
                 self._live[gen.slot] = gen
                 self._prefilling.append(gen)
+            clock.enter("dispatch")
             while rounds_ahead < STEPS_AHEAD:
                 items = self._dispatch_round(outbox)
                 if not items:
                     break
+                clock.dispatched(items[0][1], items[-1][2])
                 inflight.extend(
                     (*item, i == len(items) - 1) for i, item in enumerate(items))
                 rounds_ahead += 1
             # The last read's events go out beside the device's work.
+            clock.enter("flush")
             self._flush(outbox)
             if not inflight:
                 continue
 
+            clock.enter("read")
             first, dispatched, handle, ends_round = inflight.popleft()
             out = self.engine.materialize(handle)      # the one host sync
             now = time.perf_counter()
+            clock.enter("book", now)
+            clock.publish()                             # once a read
             t0, read_at = max(dispatched, read_at), now   # behind another: from its end
             rounds_ahead -= ends_round
             if isinstance(first, Chunk):
@@ -1244,7 +1382,6 @@ class DecodeScheduler:
             m["prefill_tokens"].inc(chunk.rows)
             m["prefill_padded_tokens"].inc(chunk.shape)
             m["prefill_prompt_tokens"].inc(chunk.rows)
-            m["prefill_padding_tokens"].inc(chunk.shape - chunk.rows)
             m["prefill_attended_pairs"].inc(
                 chunk.rows * chunk.start + chunk.rows * (chunk.rows + 1) // 2)
             m["prefill_routed_rows"].inc(int(out.counts[0]))

@@ -425,6 +425,11 @@ class ModelServer:
         self.tracer = trace_lib.Tracer(
             "model-server", registry=self.registry, annotate=TraceAnnotation
         )
+        # The interpreter's garbage-collection pauses, process-wide (one
+        # hook however many servers a process builds): seconds by
+        # generation on this page, each pause a ``gc.pause`` annotation.
+        metrics_lib.gc_pause_counters(
+            self.registry, trace_lib.watch_gc_pauses(TraceAnnotation))
         # SLO engine (utils.slo): per-model sliding-window goodput and
         # multi-window burn rates against $KDLT_SLO_TARGET, fed from the
         # same handler boundary as kdlt_server_request_seconds; serves

@@ -1025,6 +1025,22 @@ DECODE_TPOT_BUCKETS = (
 )
 
 
+# The decode loop's phases (runtime.decode.LoopClock): every instant of the
+# loop thread's life is in exactly one, so the six counters sum to its age.
+# One NAME a phase, as the dispatcher's causes: a reader that sums a series
+# over its label sets would otherwise read back the age.
+DECODE_LOOP_PHASES = (
+    ("wait", "waiting for work: nothing in flight, live or queued"),
+    ("admit", "taking the lock, admitting queued generations into slots"),
+    ("dispatch", "dispatching a round: one prompt chunk and one step"),
+    ("flush", "handing a read's token events to the transport threads"),
+    ("read", "in the one host sync, waiting for the oldest program's output"),
+    ("book", "booking a read: tokens fanned out, streams retired, metrics"),
+)
+# The phases the loop's own host work runs in (the cpu counter's scope).
+DECODE_HOST_PHASES = ("admit", "dispatch", "flush", "book")
+
+
 def decode_metrics(registry: "Registry", model: str) -> dict:
     """One generative model's decode-lane series (bounded model label,
     memoized per child like every model-labeled helper)."""
@@ -1113,10 +1129,6 @@ def decode_metrics(registry: "Registry", model: str) -> dict:
                 "kdlt_decode_prefill_prompt_tokens_total",
                 "prompt tokens prefilled",
             ),
-            "prefill_padding_tokens": c.counter(
-                "kdlt_decode_prefill_padding_tokens_total",
-                "padding positions prefilled (bucket size minus prompt)",
-            ),
             # Chunked prefill, one series a kind as above.
             "prefill_chunks": c.counter(
                 "kdlt_decode_prefill_chunks_total",
@@ -1158,9 +1170,63 @@ def decode_metrics(registry: "Registry", model: str) -> dict:
                 "true rows a shared expert computed, summed over expert "
                 "layers, prefill chunks and decode steps",
             ),
+            # The loop's own account of its time (a phase a series, above).
+            **{
+                f"loop_{phase}": c.counter(
+                    f"kdlt_decode_loop_{phase}_seconds_total",
+                    f"decode loop wall seconds {help}",
+                )
+                for phase, help in DECODE_LOOP_PHASES
+            },
+            "loop_cpu": c.counter(
+                "kdlt_decode_loop_cpu_seconds_total",
+                "the decode loop thread's CPU seconds in its host phases "
+                "(admit, dispatch, flush, book): their wall seconds less "
+                "these were the thread runnable and not running (the "
+                "interpreter's lock, the OS)",
+            ),
+            # Dry-ups by the phase the device ran dry in (every phase but
+            # wait: an empty lane is not dry).
+            "dry_seconds": {
+                phase: c.with_labels(phase=phase).counter(
+                    "kdlt_decode_dry_seconds_total",
+                    "seconds the device had nothing dispatched while the "
+                    "loop had work for it (a live slot still stepping, a "
+                    "prompt to prefill or queued), to the next dispatch from "
+                    "the last probe that found it busy -- an upper bound by "
+                    "at most the length of the phase it began in -- or from "
+                    "the return of a read after which nothing was left "
+                    "running: a lower bound by how late that read returned",
+                )
+                for phase, _ in DECODE_LOOP_PHASES[1:]
+            },
+            "dry": {
+                phase: c.with_labels(phase=phase).counter(
+                    "kdlt_decode_dry_total",
+                    "dry-ups of the device (kdlt_decode_dry_seconds_total), "
+                    "by the phase it ran dry in",
+                )
+                for phase, _ in DECODE_LOOP_PHASES[1:]
+            },
         }
 
     return _memo_on_child(child, "_kdlt_decode", mint)
+
+
+def gc_pause_counters(registry: "Registry", pauses) -> dict:
+    """kdlt_gc_pause_seconds_total{generation}: seconds the interpreter's
+    garbage collector held the process, read from ``pauses.seconds``
+    (utils.trace.GcPauses: one hook a process) when the page renders."""
+    out = {}
+    for gen in range(len(pauses.seconds)):
+        child = registry.with_labels(generation=str(gen))
+        out[gen] = child._add(ReadCounter(
+            "kdlt_gc_pause_seconds_total",
+            "seconds the interpreter's garbage collector held the process "
+            "(every thread stands still), by generation collected",
+            labels=child._labels, read=lambda gen=gen: pauses.seconds[gen],
+        ))
+    return out
 
 
 # --- OpenMetrics exemplars ---------------------------------------------------
@@ -1229,6 +1295,22 @@ class Counter:
             f"# TYPE {self.name} {self.kind}\n"
             + "\n".join(self.sample_lines()) + "\n"
         )
+
+
+class ReadCounter(Counter):
+    """A counter whose value something else keeps: ``read()`` at render."""
+
+    def __init__(self, name: str, help: str = "", labels: dict[str, str] | None = None,
+                 read=None):
+        super().__init__(name, help, labels)
+        self._read = read
+
+    @property
+    def value(self) -> float:
+        return self._read()
+
+    def sample_lines(self) -> list[str]:
+        return [f"{self.name}{_fmt_labels(self.labels)} {self._read()}"]
 
 
 class Gauge(Counter):

@@ -30,6 +30,7 @@ non-overlapping in the waterfall.
 
 from __future__ import annotations
 
+import gc
 import re
 import threading
 import time
@@ -101,6 +102,19 @@ SPAN_DECODE_PREFILL = "decode.prefill"
 SPAN_DECODE_PREFILL_CHUNK = "decode.prefill_chunk"
 SPAN_DECODE_FIRST_TOKEN = "decode.first_token"
 SPAN_DECODE_STREAM = "decode.stream"
+# The decode loop's own phases (runtime.decode.LoopClock), entered as
+# profiler annotations on its thread and never recorded into the ring: every
+# instant of the loop is in exactly one, so each device gap of an annotated
+# capture sits beside what the loop was doing meanwhile.
+SPAN_DECODE_LOOP_WAIT = "decode.loop.wait"
+SPAN_DECODE_LOOP_ADMIT = "decode.loop.admit"
+SPAN_DECODE_LOOP_DISPATCH = "decode.loop.dispatch"
+SPAN_DECODE_LOOP_FLUSH = "decode.loop.flush"
+SPAN_DECODE_LOOP_READ = "decode.loop.read"
+SPAN_DECODE_LOOP_BOOK = "decode.loop.book"
+# One garbage-collection pause of the interpreter, on whichever thread
+# triggered it (``watch_gc_pauses``): every thread stands still meanwhile.
+SPAN_GC_PAUSE = "gc.pause"
 
 SPAN_NAMES = frozenset({
     SPAN_GATEWAY_REQUEST,
@@ -134,6 +148,13 @@ SPAN_NAMES = frozenset({
     SPAN_DECODE_PREFILL_CHUNK,
     SPAN_DECODE_FIRST_TOKEN,
     SPAN_DECODE_STREAM,
+    SPAN_DECODE_LOOP_WAIT,
+    SPAN_DECODE_LOOP_ADMIT,
+    SPAN_DECODE_LOOP_DISPATCH,
+    SPAN_DECODE_LOOP_FLUSH,
+    SPAN_DECODE_LOOP_READ,
+    SPAN_DECODE_LOOP_BOOK,
+    SPAN_GC_PAUSE,
 })
 
 # One wall-anchored monotonic clock per process: perf_counter deltas on a
@@ -146,6 +167,50 @@ _PERF0 = time.perf_counter()
 def now_s() -> float:
     """Current wall time on the process's monotonic-anchored clock."""
     return _WALL0 + (time.perf_counter() - _PERF0)
+
+
+# --- the interpreter's pauses -------------------------------------------------
+
+
+class GcPauses:
+    """Seconds the interpreter's garbage collector held the process, by
+    generation, summed over the process's life: one ``gc.callbacks`` hook a
+    process (``watch_gc_pauses``), whatever registry shows the sums
+    (``metrics_lib.gc_pause_counters``).  With an ``annotate`` factory each
+    pause is also a ``gc.pause`` annotation on the thread it stopped."""
+
+    def __init__(self):
+        self.seconds = [0.0, 0.0, 0.0]
+        self.annotate = None
+        self._t0 = 0.0
+        self._scope = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if self.annotate is not None:
+                self._scope = self.annotate(SPAN_GC_PAUSE)
+                self._scope.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        self.seconds[info["generation"]] += time.perf_counter() - self._t0
+        if self._scope is not None:
+            self._scope.__exit__(None, None, None)
+            self._scope = None
+
+
+GC_PAUSES = GcPauses()
+_gc_lock = threading.Lock()
+
+
+def watch_gc_pauses(annotate=None) -> GcPauses:
+    """Install the process's pause hook (once, however many servers a
+    process builds); a later call may hand it the annotate factory."""
+    with _gc_lock:
+        if annotate is not None:
+            GC_PAUSES.annotate = annotate
+        if GC_PAUSES not in gc.callbacks:
+            gc.callbacks.append(GC_PAUSES)
+    return GC_PAUSES
 
 
 def new_span_id() -> str:

@@ -313,7 +313,8 @@ def test_assignments_sum_to_topk_times_live_slots_times_expert_layers(models_roo
     assert assigned == TOPK * rows * EXPERT_LAYERS
     assert series["kdlt_decode_tokens_total"] == 14
     assert series["kdlt_decode_prefill_prompt_tokens_total"] == 27
-    assert series["kdlt_decode_prefill_padding_tokens_total"] == (16 - 7) + (32 - 20)
+    assert (series["kdlt_decode_prefill_padded_tokens_total"]
+            - series["kdlt_decode_prefill_tokens_total"]) == (16 - 7) + (32 - 20)
     assert 0 < series["kdlt_decode_experts_touched_total"] <= 4 * EXPERT_LAYERS * (
         series["kdlt_decode_steps_total"])
     # a step reads every live slot's context, the consumed token included
