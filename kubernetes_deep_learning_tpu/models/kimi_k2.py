@@ -45,6 +45,7 @@ float32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 from kubernetes_deep_learning_tpu.models import latent_attention as la
 
@@ -234,6 +235,17 @@ def grouped_experts(cfg: KimiConfig, e: dict, u, held_index, weights, tile: int 
     return y, total * tile
 
 
+@functools.lru_cache(maxsize=None)
+def _grouped_jit():
+    """``grouped_experts`` under a ``jit`` of its own: the expert layers of a
+    program are one traced loop, traced and lowered once a program and not
+    once a layer (a server's boot pays that even where the compile cache
+    holds the program)."""
+    import jax
+
+    return jax.jit(grouped_experts, static_argnums=(0, 5))
+
+
 def moe(cfg: KimiConfig, layer: dict, u, live, grouped: bool | None = None):
     """The held share of the expert layer over ``u`` [N, D] float32 -- the
     held experts' terms and the shared expert -- and the lane's counts
@@ -241,7 +253,11 @@ def moe(cfg: KimiConfig, layer: dict, u, live, grouped: bool | None = None):
     (no zero-compute experts), held experts with at least one live token],
     then the (row, held expert) products computed and the live rows the
     shared expert met.  ``grouped``: the form of the held experts' products
-    (by the rows of the call where not given)."""
+    (by the rows of the call where not given).  ``live`` may be a tuple of
+    masks, one a part of the rows in their order (``la.round_forward``):
+    the counts are then [parts, N_COUNTS], each part's products as the
+    call's form computes them for its rows alone (all of them through every
+    held expert, or its assignments in whole tiles)."""
     import jax.numpy as jnp
 
     n = u.shape[0]
@@ -252,16 +268,23 @@ def moe(cfg: KimiConfig, layer: dict, u, live, grouped: bool | None = None):
     if grouped is None:
         grouped = n >= GROUPED_FROM_ROWS
     if grouped:
-        y, computed = grouped_experts(cfg, layer["experts"], u, chosen - lo, weights)
+        y, _ = _grouped_jit()(cfg, layer["experts"], u, chosen - lo, weights)
     else:
         per_expert = jnp.where(hit, weights[:, :, None], 0.0).sum(axis=1)    # [N, E]
-        y, computed = masked_experts(cfg, layer["experts"], u, per_expert), n * (hi - lo)
+        y = masked_experts(cfg, layer["experts"], u, per_expert)
     y = y + la.ffn(cfg, layer["shared"], u)
-    alive = live[:, None]
-    touched = (hit & alive[:, :, None]).any(axis=(0, 1)).sum()
-    counts = jnp.stack([(is_held & alive).sum(), (~is_held & alive).sum(), 0, touched,
-                        computed, live.sum()]).astype(jnp.int32)
-    return y, counts
+
+    def tally(rows, live):
+        h, held, alive = hit[rows], is_held[rows], live[:, None]
+        touched = (h & alive[:, :, None]).any(axis=(0, 1)).sum()
+        if grouped:     # whole tiles an expert the rows reached
+            computed = ((h.sum(axis=(0, 1)) + GROUP_TILE - 1) // GROUP_TILE).sum() * GROUP_TILE
+        else:           # every row through every held expert
+            computed = h.shape[0] * (hi - lo)
+        return jnp.stack([(held & alive).sum(), (~held & alive).sum(), 0, touched, computed,
+                          live.sum()]).astype(jnp.int32)
+
+    return y, la.tally_parts(tally, live)
 
 
 # --- the stack ---------------------------------------------------------------------------
@@ -269,10 +292,11 @@ def moe(cfg: KimiConfig, layer: dict, u, live, grouped: bool | None = None):
 
 def _layers(cfg: KimiConfig, params: dict, x, cache, live, attend):
     """The stack over ``x`` [N, D] float32.  ``attend(a, sub, x, cache)`` ->
-    (cache, the layer's attention output)."""
+    (cache, the layer's attention output).  ``live``: a mask, or a tuple of
+    them by part (``moe``)."""
     import jax.numpy as jnp
 
-    counts = jnp.zeros((6,), jnp.int32)
+    counts = jnp.zeros(((len(live), 6) if isinstance(live, tuple) else (6,)), jnp.int32)
     for i, layer in enumerate(params["layers"]):
         cache, out = attend(layer["attn"], i, x, cache)
         x = x + out
@@ -370,6 +394,13 @@ class KimiDecoder:
     def decode_step(self, params, cache, page_table, lengths, last_tokens, active):
         return decode_step(self.cfg, params, cache, page_table, lengths, last_tokens,
                            active, attention=self.attention)
+
+    def prefill_and_step(self, params, cache, tokens, start, length, page_ids, page_table,
+                         lengths, last_tokens, active):
+        """A chunk and a step as one program (``la.round_forward``)."""
+        return la.round_forward(self.cfg, params, _layers, cache, tokens, start, length,
+                                page_ids, page_table, lengths, last_tokens, active,
+                                self.attention)
 
     def describe(self) -> dict:
         cfg = self.cfg

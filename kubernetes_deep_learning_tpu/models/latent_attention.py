@@ -27,6 +27,9 @@ multiple of the chip's 128 lanes.  Four forms of the same attention:
   the latent space and scored against the cache as it lies
   (``ops.mla_decode``).
 
+``round_forward`` runs a prefill chunk and a decode step as one forward:
+every product reads its weights once for both, and only attention is split.
+
 Weights and matmul operands are ``compute_dtype`` (bfloat16 as served) with
 float32 accumulation; norms' statistics, rotations and softmaxes are
 float32.  ``cfg`` below is anything with a ``compute_dtype``: a model's
@@ -340,9 +343,17 @@ def _expanded_scores(spec: LatentSpec, a: dict, q_nope, q_rope, latent):
     return scores, v
 
 
-def expanded_attention(spec: LatentSpec, a: dict, q_nope, q_rope, latent, mask):
+def project(spec: LatentSpec, a: dict, heads, wo: bool):
+    """The heads' values [N, H * v] through the output projection, or as
+    they are where ``wo`` is false (the caller projects them, with others)."""
+    return mm(spec, heads, a["wo"]) if wo else heads
+
+
+def expanded_attention(spec: LatentSpec, a: dict, q_nope, q_rope, latent, mask,
+                       wo: bool = True):
     """Causal attention within one sequence in the expanded form: per-head
-    keys and values from the latent [T, cache_width]; ``mask`` [T, T]."""
+    keys and values from the latent [T, cache_width]; ``mask`` [T, T].  The
+    output projection is ``project``'s (so in the three forms below)."""
     import jax.numpy as jnp
 
     scores, v = _expanded_scores(spec, a, q_nope, q_rope, latent)
@@ -350,11 +361,11 @@ def expanded_attention(spec: LatentSpec, a: dict, q_nope, q_rope, latent, mask):
     w = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
     w = w / w.sum(axis=-1, keepdims=True)
     out = contract(spec, "htu,uhv->thv", w, v)
-    return mm(spec, out.reshape(out.shape[0], -1), a["wo"])
+    return project(spec, a, out.reshape(out.shape[0], -1), wo)
 
 
 def paged_chunk_attention(spec: LatentSpec, a: dict, q_nope, q_rope, latent, mask, cache,
-                          sub: int, page_ids, start):
+                          sub: int, page_ids, start, wo: bool = True):
     """A later chunk of a prompt: its queries [T, ...] against positions
     [0, ``start``) of the slot's pages (all of them visible to every row of
     the chunk) and, under ``mask`` [T, T], against the chunk's own
@@ -392,11 +403,11 @@ def paged_chunk_attention(spec: LatentSpec, a: dict, q_nope, q_rope, latent, mas
 
     _, total, acc = jax.lax.fori_loop(0, (start + block - 1) // block, body, carry0)
     out = (acc / jnp.where(total > 0, total, 1.0)).transpose(1, 0, 2)
-    return mm(spec, out.reshape(out.shape[0], -1), a["wo"])
+    return project(spec, a, out.reshape(out.shape[0], -1), wo)
 
 
 def absorbed_attention(spec: LatentSpec, a: dict, q_nope, q_rope, cache, sub: int,
-                       page_table, n_ctx, impl: str):
+                       page_table, n_ctx, impl: str, wo: bool = True):
     """One decode step's attention over the paged latent cache."""
     import jax.numpy as jnp
 
@@ -411,7 +422,7 @@ def absorbed_attention(spec: LatentSpec, a: dict, q_nope, q_rope, cache, sub: in
     o_lat = paged_mla_attention(q.astype(cache.dtype), cache, sub, page_table, n_ctx,
                                 rank=spec.kv_lora_rank, impl=impl)
     out = contract(spec, "shc,hcv->shv", o_lat, a["w_uv"])
-    return mm(spec, out.reshape(s_slots, -1), a["wo"])
+    return project(spec, a, out.reshape(s_slots, -1), wo)
 
 
 def chunk_positions(cache, tokens, start, length, page_ids):
@@ -443,7 +454,7 @@ def chunk_attention_form(rows: int, first: bool, impl: str) -> str:
 
 
 def kernel_chunk_attention(spec: LatentSpec, a: dict, q_nope, q_rope, cache, sub: int,
-                           page_ids, start, length, impl: str):
+                           page_ids, start, length, impl: str, wo: bool = True):
     """A chunk's queries [T, ...] against positions [0, ``length``) of the
     slot's pages, its own included (already written), in ``ops.mla_chunk``'s
     kernel."""
@@ -459,11 +470,11 @@ def kernel_chunk_attention(spec: LatentSpec, a: dict, q_nope, q_rope, cache, sub
     out = chunk_mla_attention(q.transpose(1, 0, 2), a["w_uk"], a["w_uv"], cache, sub,
                               page_ids, start, length, rank=spec.kv_lora_rank,
                               scale=spec.score_scale, impl=impl)
-    return mm(spec, out, a["wo"])
+    return project(spec, a, out, wo)
 
 
 def attend_chunk(spec: LatentSpec, a: dict, sub: int, x, cache, geometry, cos, sin,
-                 page_ids, start, impl: str = "gather"):
+                 page_ids, start, impl: str = "gather", wo: bool = True):
     """One sublayer of a prefill chunk: write the rows' latents, attend.
     ``start`` is the Python int 0 for a prompt's first chunk (nothing of it
     is cached yet: the sequence attends to itself) or a traced scalar."""
@@ -474,15 +485,15 @@ def attend_chunk(spec: LatentSpec, a: dict, sub: int, x, cache, geometry, cos, s
     form = chunk_attention_form(x.shape[0], first, impl)
     if form == "kernel":
         return cache, kernel_chunk_attention(spec, a, q_nope, q_rope, cache, sub, page_ids,
-                                             start, length, impl)
+                                             start, length, impl, wo)
     if form == "expanded":
-        return cache, expanded_attention(spec, a, q_nope, q_rope, latent, mask)
+        return cache, expanded_attention(spec, a, q_nope, q_rope, latent, mask, wo)
     return cache, paged_chunk_attention(spec, a, q_nope, q_rope, latent, mask, cache,
-                                        sub, page_ids, start)
+                                        sub, page_ids, start, wo)
 
 
 def attend_step(spec: LatentSpec, a: dict, sub: int, x, cache, page_table, lengths, active,
-                cos, sin, impl: str):
+                cos, sin, impl: str, wo: bool = True):
     """One sublayer of a decode step: every live slot's consumed token is
     written at position ``lengths[s]`` and attends over 0..lengths[s]."""
     import jax.numpy as jnp
@@ -494,4 +505,63 @@ def attend_step(spec: LatentSpec, a: dict, sub: int, x, cache, page_table, lengt
     cache = cache.at[sub, write_page, lengths % page].set(latent)
     n_ctx = jnp.where(active, lengths + 1, 0)
     return cache, absorbed_attention(spec, a, q_nope, q_rope, cache, sub, page_table,
-                                     n_ctx, impl)
+                                     n_ctx, impl, wo)
+
+
+def tally_parts(tally, live):
+    """An expert layer's counts: ``tally(rows, live)`` over all its rows for
+    one ``live`` mask, or, for a tuple of masks (one a part of the rows, in
+    their order), stacked by part."""
+    import jax.numpy as jnp
+
+    if not isinstance(live, tuple):
+        return tally(slice(None), live)
+    parts, at = [], 0
+    for mask in live:
+        parts.append(tally(slice(at, at + mask.shape[0]), mask))
+        at += mask.shape[0]
+    return jnp.stack(parts)
+
+
+def round_forward(cfg, params: dict, layers, cache, tokens, start, length, page_ids,
+                  page_table, lengths, last_tokens, active, impl: str):
+    """A prefill chunk and a decode step as one forward (``runtime.decode``'s
+    round contract).  Every layer runs once over the chunk's ``T`` rows
+    followed by the step's ``S``: embedding, norms, projections, FFNs and
+    experts read their weights once for both.  Only attention is split: the
+    chunk's rows take ``attend_chunk``, the step's ``attend_step``, and their
+    cache writes land on disjoint pages (the prefilling slot is not live in
+    the step; padding and idle slots write the trash page).  ``layers(cfg,
+    params, x, cache, live, attend)`` is the decoder's stack, here given
+    ``live`` as the pair (the chunk's true rows, the live slots): it counts
+    each apart (``tally_parts``).  Returns (cache, the chunk's last true
+    row's logits [V], the step's logits [S, V], counts [2, N_COUNTS]: the
+    chunk's, then the step's)."""
+    import jax.numpy as jnp
+
+    spec = cfg.mla
+    t = tokens.shape[0]
+    geometry = chunk_positions(cache, tokens, start, length, page_ids)
+    pos, real = geometry[:2]
+    cos, sin = rope_angles(spec, jnp.concatenate([pos, lengths]))
+
+    # The step's attention first, then the chunk's, and the heads of both
+    # through one output projection, which reads ``wo`` once and joins the
+    # residual and the next norm as the chunk's own does.  On a v5e at the
+    # long-prompt cell's sizes (a chunk of 1,024 rows beside 64 slots), with
+    # the chunk's attention first and a projection each the compiler
+    # recomputed the chunk's projection to hold it across the step's
+    # attention, and the round took 8.5 ms more than the chunk alone; with
+    # the step's first, 3.6 ms more, 0.63 ms a layer in a projection apart.
+    def attend(a, sub, x, cache):
+        cache, step = attend_step(spec, a, sub, x[t:], cache, page_table, lengths, active,
+                                  cos[t:], sin[t:], impl, wo=False)
+        cache, own = attend_chunk(spec, a, sub, x[:t], cache, geometry, cos[:t], sin[:t],
+                                  page_ids, start, impl, wo=False)
+        return cache, mm(spec, jnp.concatenate([own, step]), a["wo"])
+
+    x = params["embed"][jnp.concatenate([tokens, last_tokens])].astype(jnp.float32)
+    cache, x, counts = layers(cfg, params, x, cache, (real, active), attend)
+    rows = jnp.concatenate([x[length - 1 - start][None], x[t:]])
+    logits = mm(cfg, rms(rows, params["final_norm"], cfg.rms_norm_eps), params["head"])
+    return cache, logits[0], logits[1:], counts

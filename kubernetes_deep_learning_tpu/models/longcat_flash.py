@@ -39,7 +39,8 @@ stream, the norms' statistics, the router and the softmaxes are float32.
 
 The interface the lane asks of a decoder (``runtime.decode.DecodeEngine``):
 ``vocab_size``, ``eos_token``, ``text`` (whether prompts may be text),
-``params``, ``cache_spec``, ``prefill``, ``decode_step``, ``describe``.
+``params``, ``cache_spec``, ``prefill``, ``decode_step``, ``describe``, and
+the one this decoder offers beside them, ``prefill_and_step``.
 """
 
 from __future__ import annotations
@@ -184,7 +185,9 @@ def moe(cfg: LongcatConfig, layer: dict, u, live):
     (``runtime.decode.N_COUNTS``): over the ``live`` rows [held, absent, zero
     assignments, held experts with at least one live token], then the (row,
     held expert) products computed -- every row through every held expert --
-    and the rows a shared expert met (none here)."""
+    and the rows a shared expert met (none here).  ``live`` may be a tuple
+    of masks, one a part of the rows in their order: the counts are then
+    [parts, N_COUNTS]."""
     import jax
     import jax.numpy as jnp
 
@@ -200,16 +203,21 @@ def moe(cfg: LongcatConfig, layer: dict, u, live):
     mid = jax.nn.silu(la.mm(cfg, u, e["w_gate"])) * la.mm(cfg, u, e["w_up"])     # [N, E * F]
     mid = (mid.reshape(n, hi - lo, -1) * per_expert[:, :, None]).reshape(n, -1)
     y = la.mm(cfg, mid, e["w_down"]) + zero_weight[:, None] * u
-    alive = live[:, None]
-    touched = (hit & alive[:, :, None]).any(axis=(0, 1)).sum()
-    counts = jnp.stack([(is_held & alive).sum(), (~is_held & ~is_zero & alive).sum(),
-                        (is_zero & alive).sum(), touched, n * (hi - lo), 0]).astype(jnp.int32)
-    return y, counts
+
+    def tally(rows, live):
+        h, held, zero, alive = hit[rows], is_held[rows], is_zero[rows], live[:, None]
+        touched = (h & alive[:, :, None]).any(axis=(0, 1)).sum()
+        return jnp.stack([(held & alive).sum(), (~held & ~zero & alive).sum(),
+                          (zero & alive).sum(), touched, h.shape[0] * (hi - lo),
+                          0]).astype(jnp.int32)
+
+    return y, la.tally_parts(tally, live)
 
 
 def _layers(cfg: LongcatConfig, params: dict, x, cache, live, attend):
     """The stack over ``x`` [N, D] float32.  ``attend(a, sub, x, cache)`` ->
-    (cache, the sublayer's attention output)."""
+    (cache, the sublayer's attention output).  ``live``: a mask, or a tuple
+    of them by part (``moe``)."""
     counts = 0        # summed over the expert layers (``moe``)
     for i, layer in enumerate(params["layers"]):
         shortcut = None
@@ -313,6 +321,13 @@ class LongcatDecoder:
     def decode_step(self, params, cache, page_table, lengths, last_tokens, active):
         return decode_step(self.cfg, params, cache, page_table, lengths, last_tokens,
                            active, attention=self.attention)
+
+    def prefill_and_step(self, params, cache, tokens, start, length, page_ids, page_table,
+                         lengths, last_tokens, active):
+        """A chunk and a step as one program (``la.round_forward``)."""
+        return la.round_forward(self.cfg, params, _layers, cache, tokens, start, length,
+                                page_ids, page_table, lengths, last_tokens, active,
+                                self.attention)
 
     def describe(self) -> dict:
         cfg = self.cfg

@@ -15,7 +15,7 @@ again).  Same arithmetic as the expanded form, reassociated.
                              width is rank + rope padded by the caller to
                              the chip's 128 lanes (zeros in the padding)
     cache    [sublayers, P, page, width]   the whole paged cache
-    sub      which sublayer's plane (static)
+    sub      which sublayer's plane
     page_table [S, max_pages] int32, n_ctx [S] int32 (0 for an idle slot)
     ->       [S, H, rank] float32: softmax(q . latent) @ latent[:rank]
 
@@ -85,13 +85,14 @@ def gather_mla_attention(q, cache, sub: int, page_table, n_ctx, rank: int):
     return out / jnp.where(total > 0, total, 1.0)
 
 
-def _kernel(pt_ref, n_ref, q_ref, cache_ref, o_ref, buf, sem, state, *, sub: int,
-            page: int, chunk_pages: int, max_pages: int, rank: int):
+def _kernel(pt_ref, n_ref, q_ref, cache_ref, o_ref, buf, sem, state, *, page: int,
+            chunk_pages: int, max_pages: int, rank: int):
     """The chunks of all slots are one stream, copied ``DEPTH`` chunks ahead
-    of the scoring into a ring of ``DEPTH + 1`` buffers.  ``state`` (scalar
-    memory, kept from grid step to grid step): [0] the buffer the next chunk
-    to score lands in; [1], [2] the slot and chunk to fetch next (slot =
-    slots once the stream has run out)."""
+    of the scoring into a ring of ``DEPTH + 1`` buffers.  ``n_ref`` holds
+    each slot's context and, last, the sublayer.  ``state`` (scalar memory,
+    kept from grid step to grid step): [0] the buffer the next chunk to
+    score lands in; [1], [2] the slot and chunk to fetch next (slot = slots
+    once the stream has run out)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -100,6 +101,7 @@ def _kernel(pt_ref, n_ref, q_ref, cache_ref, o_ref, buf, sem, state, *, sub: int
     g = pl.program_id(0)
     slots_a_step, heads = q_ref.shape[:2]
     slots = pl.num_programs(0) * slots_a_step
+    sub = n_ref[slots]
     chunk_len = page * chunk_pages
     ring = DEPTH + 1
 
@@ -190,23 +192,21 @@ def _kernel(pt_ref, n_ref, q_ref, cache_ref, o_ref, buf, sem, state, *, sub: int
                 c.wait()
 
 
-def paged_mla_attention(q, cache, sub: int, page_table, n_ctx, *, rank: int,
-                        impl: str = "kernel"):
-    if impl == "gather":
-        return gather_mla_attention(q, cache, sub, page_table, n_ctx, rank)
-    if impl not in ("kernel", "interpret"):
-        raise ValueError(f"unknown latent-attention implementation {impl!r}")
+def mla_paged_decode(pages, n_sub, q, cache, *, max_pages: int, rank: int,
+                     interpret: bool):
+    """The kernel's call: ``pages`` the flat page table, ``n_sub`` each
+    slot's context and, last, the sublayer.  (The chip's trace names a
+    jitted function's custom call after the function: ``%mla_paged_decode.N``.)"""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s_slots, heads, width = q.shape
-    max_pages = page_table.shape[1]
     page = cache.shape[2]
     chunk_pages = pages_per_chunk(max_pages)
     slots_a_step = SLOTS_A_STEP if s_slots % SLOTS_A_STEP == 0 else 1
-    kernel = functools.partial(_kernel, sub=sub, page=page, chunk_pages=chunk_pages,
+    kernel = functools.partial(_kernel, page=page, chunk_pages=chunk_pages,
                                max_pages=max_pages, rank=rank)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -227,7 +227,32 @@ def paged_mla_attention(q, cache, sub: int, page_table, n_ctx, *, rank: int,
         out_shape=jax.ShapeDtypeStruct((s_slots, heads, rank), jnp.float32),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=impl == "interpret",
+        interpret=interpret,
         name=KERNEL_NAME,
-    )(page_table.reshape(-1).astype(jnp.int32), n_ctx.astype(jnp.int32),
-      q.astype(cache.dtype), cache)
+    )(pages, n_sub, q, cache)
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_call():
+    """``mla_paged_decode`` under a ``jit`` of its own: the sublayer is an
+    operand, so the sublayers of one program are one traced call and the
+    kernel is traced and lowered once a program, not once a sublayer (a
+    server's boot pays that even where the compile cache holds the program,
+    and a round program holds a step's calls beside a chunk's)."""
+    import jax
+
+    return jax.jit(mla_paged_decode, static_argnames=("max_pages", "rank", "interpret"))
+
+
+def paged_mla_attention(q, cache, sub: int, page_table, n_ctx, *, rank: int,
+                        impl: str = "kernel"):
+    if impl == "gather":
+        return gather_mla_attention(q, cache, sub, page_table, n_ctx, rank)
+    if impl not in ("kernel", "interpret"):
+        raise ValueError(f"unknown latent-attention implementation {impl!r}")
+    import jax.numpy as jnp
+
+    n_sub = jnp.concatenate([n_ctx.astype(jnp.int32), jnp.full((1,), sub, jnp.int32)])
+    return _jitted_call()(page_table.reshape(-1).astype(jnp.int32), n_sub,
+                          q.astype(cache.dtype), cache, max_pages=page_table.shape[1],
+                          rank=rank, interpret=impl == "interpret")

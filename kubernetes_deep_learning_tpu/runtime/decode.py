@@ -31,7 +31,11 @@ own page list, its own length, and its own last token; masked (garbage)
 context positions get exactly-zero softmax weight; and the SAME compiled
 step program serves every batch composition, solo included.  So the
 token stream of a request decoded in a shifting continuous batch is
-bit-identical to the same request decoded alone.
+bit-identical to the same request decoded alone -- where no chunk rode in
+its steps' programs (below).  A step that rode computes the same rows in a
+program of another shape: its stream is equal to the solo decode's within
+rounding, as a prompt's chunks are to one program.  The toy offers no such
+program, and its streams stay bit-identical.
 
 The model is a *decoder* the engine is handed (``DecodeEngine(decoder=)``):
 its vocabulary, its cache layout (``cache_spec``) and two pure functions,
@@ -61,6 +65,17 @@ one chunk between two decode steps: a long prompt holds the live slots for
 one chunk's time, not for its whole length.  ``prompt_buckets`` is the
 ladder a chunk pads to and the longest prompt admitted; rungs above the
 chunk size admit long prompts and compile nothing.
+
+**A round as one program.**  A decoder that offers ``prefill_and_step`` (a
+chunk and a step as one forward: every layer runs once over both sets of
+rows, so the weights are read once, and only attention is split) gets a
+second pair of chunk programs with the step's inputs added
+(``round_async``), and the scheduler dispatches every chunk through them:
+the live slots' step rides in the chunk's program, and a round without a
+chunk is a step alone.  The slot a chunk prefills is not live in its own
+round's step; after its last chunk it joins the next round's, which
+consumes the first token that chunk left on the device.  ``prefill`` and
+``decode_solo`` keep the standalone programs.
 
 The host is not in the step's period.  The token a slot consumes next stays
 on the device (a prefill and a step each leave their greedy choice in
@@ -431,7 +446,8 @@ def _pack(logits, counts, top: int):
     """Everything the host reads of a step, as ONE int32 array ``[2 * S + 1,
     top]``: rows 0..S-1 the ids of each slot's ``top`` largest logits
     (largest first: column 0 is the greedy token), rows S..2S-1 those
-    logits' float32 bits, the last row the routing counts."""
+    logits' float32 bits, the last row the routing counts (a round's two
+    sets, the chunk's then the step's, one after the other)."""
     import jax
     import jax.numpy as jnp
 
@@ -440,7 +456,7 @@ def _pack(logits, counts, top: int):
     # the whole vocabulary in place of its TopK call (0.71 against 0.39 ms).
     values, ids = jax.lax.optimization_barrier(
         jax.lax.top_k(logits.astype(jnp.float32), top))
-    tail = jnp.zeros((1, top), jnp.int32).at[0, :counts.shape[0]].set(counts)
+    tail = jnp.zeros((1, top), jnp.int32).at[0, :counts.size].set(counts.reshape(-1))
     return jnp.concatenate(
         [ids.astype(jnp.int32), jax.lax.bitcast_convert_type(values, jnp.int32), tail])
 
@@ -572,6 +588,20 @@ class DecodeEngine:
         def prefill_first(params, cache, next_tokens, tokens, length, page_ids, slot):
             return prefill(params, cache, next_tokens, tokens, 0, length, page_ids, slot)
 
+        def round_next(params, cache, next_tokens, page_table, lengths, active,
+                       tokens, start, length, page_ids, slot):
+            cache, chunk_logits, step_logits, counts = decoder.prefill_and_step(
+                params, cache, tokens, start, length, page_ids, page_table, lengths,
+                next_tokens, active)
+            packed = _pack(jnp.concatenate([chunk_logits[None], step_logits]), counts, top)
+            nxt = jnp.where(active, packed[1:slots + 1, 0], next_tokens)
+            return cache, packed, nxt.at[slot].set(packed[0, 0])
+
+        def round_first(params, cache, next_tokens, page_table, lengths, active,
+                        tokens, length, page_ids, slot):
+            return round_next(params, cache, next_tokens, page_table, lengths, active,
+                              tokens, 0, length, page_ids, slot)
+
         donated = (1,) if self._donate else ()
         if self._donate:
             import warnings
@@ -584,6 +614,14 @@ class DecodeEngine:
         # itself) and a later chunk (start traced) are two programs a shape.
         self._prefill_jit = jax.jit(prefill_first, donate_argnums=donated)
         self._prefill_next_jit = jax.jit(prefill, donate_argnums=donated)
+        # A decoder that offers the chunk and the step as one forward gets
+        # those two again with the step's inputs added: the scheduler
+        # dispatches every chunk through them, the live slots' step riding
+        # in the chunk's program (``round_async``).
+        self.rides = callable(getattr(decoder, "prefill_and_step", None))
+        if self.rides:
+            self._round_first_jit = jax.jit(round_first, donate_argnums=donated)
+            self._round_next_jit = jax.jit(round_next, donate_argnums=donated)
 
     def status(self) -> dict:
         """The ``decode`` block of GET /v1/models: the sizes the lane was
@@ -669,17 +707,29 @@ class DecodeEngine:
         rows = min(n - start, self.prefill_chunk)
         return rows, prompt_bucket(rows, self.chunk_shapes)
 
+    def _chunk(self, slot: int, prompt_tokens: list[int], start: int):
+        """(true rows, compiled shape, the padded rows, (length, pages, slot))
+        of a chunk: what its program takes beside the weights and the cache."""
+        rows, shape = self.chunk_at(len(prompt_tokens), start)
+        padded = np.zeros((shape,), np.int32)
+        padded[:rows] = prompt_tokens[start:start + rows]
+        return rows, shape, padded, (np.int32(start + rows), self.page_table[slot].copy(),
+                                     np.int32(slot))
+
+    def _prefilled(self, slot: int, n: int, end: int) -> None:
+        """A chunk ending at ``end`` of an ``n``-token prompt is dispatched:
+        with its last the slot is live -- its length covers the prompt and
+        the next step consumes the first token, which that chunk leaves on
+        the device."""
+        if end == n:
+            self.lengths[slot] = n
+            self.active[slot] = True
+
     def prefill_chunk_async(self, slot: int, prompt_tokens: list[int], start: int):
         """Dispatch the chunk of the prompt that begins at ``start`` into
         ``slot``; returns (the unmaterialized handle of its packed output,
-        S = 1; true rows; compiled shape).  With its last chunk the slot is
-        live: its length covers the prompt and the next step consumes the
-        first token, which that chunk leaves on the device."""
-        n = len(prompt_tokens)
-        rows, shape = self.chunk_at(n, start)
-        padded = np.zeros((shape,), np.int32)
-        padded[:rows] = prompt_tokens[start:start + rows]
-        args = (np.int32(start + rows), self.page_table[slot].copy(), np.int32(slot))
+        S = 1; true rows; compiled shape)."""
+        rows, shape, padded, args = self._chunk(slot, prompt_tokens, start)
         if start == 0:
             self._cache, out, self._next_tokens = self._prefill_jit(
                 self._params, self._cache, self._next_tokens, padded, *args)
@@ -687,18 +737,40 @@ class DecodeEngine:
             self._cache, out, self._next_tokens = self._prefill_next_jit(
                 self._params, self._cache, self._next_tokens, padded,
                 np.int32(start), *args)
-        if start + rows == n:
-            self.lengths[slot] = n
-            self.active[slot] = True
+        self._prefilled(slot, len(prompt_tokens), start + rows)
+        return out, rows, shape
+
+    def round_async(self, slot: int, prompt_tokens: list[int], start: int):
+        """``prefill_chunk_async`` with a decode step of the live slots
+        riding in the chunk's program (a decoder that ``rides`` only): one
+        program, one output -- the chunk's row, then the step's S rows
+        (``materialize_round``).  The slot being prefilled is not live in
+        this step: after its last chunk it joins the next one.  Dispatch
+        only, like ``step_async``."""
+        rows, shape, padded, args = self._chunk(slot, prompt_tokens, start)
+        tables = (self.page_table.copy(), self.lengths, self.active.copy())
+        if start == 0:
+            self._cache, out, self._next_tokens = self._round_first_jit(
+                self._params, self._cache, self._next_tokens, *tables, padded, *args)
+        else:
+            self._cache, out, self._next_tokens = self._round_next_jit(
+                self._params, self._cache, self._next_tokens, *tables, padded,
+                np.int32(start), *args)
+        self.lengths = self.lengths + self.active.astype(np.int32)
+        self._prefilled(slot, len(prompt_tokens), start + rows)
         return out, rows, shape
 
     def prefill(self, slot: int, prompt_tokens: list[int]):
         """Dispatch one prompt's prefill into ``slot``, every chunk of it
         back to back; returns the handle of the last chunk's packed output,
         whose greedy choice is the first token."""
+        return self._chunks(self.prefill_chunk_async, slot, prompt_tokens)
+
+    @staticmethod
+    def _chunks(dispatch, slot: int, prompt_tokens: list[int]):
         start = 0
         while True:
-            out, rows, _ = self.prefill_chunk_async(slot, prompt_tokens, start)
+            out, rows, _ = dispatch(slot, prompt_tokens, start)
             start += rows
             if start >= len(prompt_tokens):
                 return out
@@ -727,6 +799,14 @@ class DecodeEngine:
             top_logits=packed[n:2 * n].view(np.float32),
             counts=packed[2 * n, :N_COUNTS].astype(np.int64),
         )
+
+    def materialize_round(self, handle) -> tuple[StepOutput, StepOutput]:
+        """``materialize`` of a ``round_async`` output, split: (the chunk's,
+        S = 1; the step's, S = max_slots), each with its own counts."""
+        out = self.materialize(handle)
+        counts = np.asarray(handle)[-1, N_COUNTS:2 * N_COUNTS].astype(np.int64)
+        return (StepOutput(out.tokens[:1], out.top_ids[:1], out.top_logits[:1], out.counts),
+                StepOutput(out.tokens[1:], out.top_ids[1:], out.top_logits[1:], counts))
 
     # --- reference + warmup -------------------------------------------------
 
@@ -758,9 +838,16 @@ class DecodeEngine:
         chunk can take where prompts are chunked, plus the step program
         (the prompt-length x batch-slot grid is one step compile wide --
         the step runs at fixed width by construction).  Rungs above the
-        chunk size compile nothing.  Returns the per-program wall times for
-        kdlt-warm's report."""
+        chunk size compile nothing.  The chunks go through the programs the
+        scheduler dispatches them through: for a decoder that ``rides``,
+        the round programs in place of the prefill programs.  Returns the
+        per-program wall times for kdlt-warm's report."""
         report = {"model": self.model, "buckets": {}, "chunks": {}, "step_s": 0.0}
+
+        def prefill(slot: int, tokens: list[int]):
+            if self.rides:
+                return self._chunks(self.round_async, slot, tokens)
+            return self.prefill(slot, tokens)
 
         def run(n: int) -> float | None:
             t0 = time.perf_counter()
@@ -768,7 +855,7 @@ class DecodeEngine:
             if slot is None:
                 return None
             try:
-                self.materialize(self.prefill(slot, [0] * n))
+                self.materialize(prefill(slot, [0] * n))
             finally:
                 self.release_slot(slot)
             return round(time.perf_counter() - t0, 4)
@@ -791,7 +878,7 @@ class DecodeEngine:
         slot = self.acquire_slot(2)
         if slot is not None:
             try:
-                self.materialize(self.prefill(slot, [0]))
+                self.materialize(prefill(slot, [0]))
                 self.materialize(self.step_async())
             finally:
                 self.release_slot(slot)
@@ -877,6 +964,8 @@ class Chunk:
     shape: int      # rows computed: the compiled shape it was padded to
     last: bool      # the prompt's last chunk: its output is the first token
     kernel: bool    # its attention ran in the chunk kernel
+    riders: list | None = None  # the step rows that rode in its program (round_async)
+    context: int = 0            # the positions those rows read
 
 
 _LOOP_SPANS = {
@@ -1009,9 +1098,9 @@ class DecodeScheduler:
     iteration first slot-fills freed decode slots from the queue (by
     (priority rank, absolute deadline) order -- same shed order as the
     image tier), dispatches ONE chunk of the oldest admitted prompt and ONE
-    batched step behind what is already on the device, then reads the
-    oldest of what is dispatched and fans its tokens out to their
-    generations.
+    batched step behind what is already on the device (as one program
+    where the decoder ``rides``), then reads the oldest of what is
+    dispatched and fans its tokens out to their generations.
     """
 
     def __init__(
@@ -1253,8 +1342,9 @@ class DecodeScheduler:
         program to the next with no host in between, and the host has a
         whole step's time for the one before.  A round is one chunk of the
         oldest admitted prompt, where one waits, then one step, where a slot
-        is live: a prompt of many chunks holds the live slots for one
-        chunk's time at once.  What was dispatched for a stream that the
+        is live (for a decoder that ``rides``, one program and one read
+        where both are there): a prompt of many chunks holds the live slots
+        for one chunk's time at once.  What was dispatched for a stream that the
         read then ends (EOS, cancel, deadline) is computed into its own
         pages and dropped."""
         outbox: list = []
@@ -1308,41 +1398,55 @@ class DecodeScheduler:
 
             clock.enter("read")
             first, dispatched, handle, ends_round = inflight.popleft()
-            out = self.engine.materialize(handle)      # the one host sync
+            rode = isinstance(first, Chunk) and first.riders is not None
+            out = (self.engine.materialize_round if rode      # the one host sync
+                   else self.engine.materialize)(handle)
             now = time.perf_counter()
             clock.enter("book", now)
             clock.publish()                             # once a read
             t0, read_at = max(dispatched, read_at), now   # behind another: from its end
             rounds_ahead -= ends_round
-            if isinstance(first, Chunk):
-                self._read_chunk(first, out, dispatched, t0, now, outbox)
+            if not isinstance(first, Chunk):
+                self._read_step(*first, out, now - t0, now, outbox)
                 continue
-            rows, context = first
-            if self.metrics:
-                self.metrics["steps"].inc()
-                self.metrics["step_seconds"].observe(now - t0)
-                self.metrics["context_positions"].inc(context)
-                self._count_routing(out.counts)
-            for slot, gen in rows:
-                if gen.done:
-                    continue            # ended at an earlier read: dropped
-                tok = self._take(gen, out, slot, now, outbox)
-                if gen.cancelled:
-                    self._retire(gen, FINISH_CANCELLED, outbox)
-                elif self._stops(gen, tok):
-                    self._retire(gen, FINISH_STOP, outbox)
-                elif len(gen.tokens) >= gen.max_new_tokens:
-                    self._retire(gen, FINISH_LENGTH, outbox)
-                elif gen.deadline is not None and gen.deadline.expired:
-                    self._retire(gen, FINISH_DEADLINE, outbox)
+            chunk_out, step_out = out if rode else (out, None)
+            self._read_chunk(first, chunk_out, dispatched, t0, now, outbox)
+            if first.riders:
+                self._read_step(first.riders, first.context, step_out, None, now, outbox)
+
+    def _read_step(self, rows: list, context: int, out: StepOutput, seconds: float | None,
+                   now: float, outbox: list) -> None:
+        """One step's rows are read: ``seconds`` its time on the device, or
+        None where it rode in a chunk's program (timed as the chunk)."""
+        if self.metrics:
+            self.metrics["steps"].inc()
+            if seconds is None:
+                self.metrics["riding_steps"].inc()
+            else:
+                self.metrics["step_seconds"].observe(seconds)
+            self.metrics["context_positions"].inc(context)
+            self._count_routing(out.counts)
+        for slot, gen in rows:
+            if gen.done:
+                continue            # ended at an earlier read: dropped
+            tok = self._take(gen, out, slot, now, outbox)
+            if gen.cancelled:
+                self._retire(gen, FINISH_CANCELLED, outbox)
+            elif self._stops(gen, tok):
+                self._retire(gen, FINISH_STOP, outbox)
+            elif len(gen.tokens) >= gen.max_new_tokens:
+                self._retire(gen, FINISH_LENGTH, outbox)
+            elif gen.deadline is not None and gen.deadline.expired:
+                self._retire(gen, FINISH_DEADLINE, outbox)
 
     def _dispatch_round(self, outbox: list) -> list:
         """One chunk of the oldest admitted prompt that still has chunks to
         go (a stream cancelled or out of time between two of its chunks
         gives its slot and pages back instead), then one step over the live
-        slots.  Returns what was dispatched, in the device's order, as
-        (what, dispatch time, unmaterialized output); nothing where neither
-        was there to do."""
+        slots -- for a decoder that ``rides``, inside the chunk's program,
+        where there is a chunk.  Returns what was dispatched, in the
+        device's order, as (what, dispatch time, unmaterialized output);
+        nothing where neither was there to do."""
         items = []
         while self._prefilling:
             gen = self._prefilling[0]
@@ -1356,15 +1460,22 @@ class DecodeScheduler:
         if self._prefilling:
             gen = self._prefilling[0]
             start, t = gen.prefilled, time.perf_counter()
-            handle, rows, shape = self.engine.prefill_chunk_async(
-                gen.slot, gen.prompt_tokens, start)
+            riders, context = self._step_rows() if self.engine.rides else (None, 0)
+            dispatch = (self.engine.round_async if self.engine.rides
+                        else self.engine.prefill_chunk_async)
+            handle, rows, shape = dispatch(gen.slot, gen.prompt_tokens, start)
             gen.prefilled += rows
             last = gen.prefilled >= len(gen.prompt_tokens)
             if last:        # the first token is on the device
                 self._prefilling.popleft()
                 self._dispatched(gen)
+            for _, rider in riders or ():
+                self._dispatched(rider)
             kernel = self.engine.chunk_attention(shape, start == 0) == "kernel"
-            items.append((Chunk(gen, start, rows, shape, last, kernel), t, handle))
+            items.append((Chunk(gen, start, rows, shape, last, kernel, riders, context),
+                          t, handle))
+            if self.engine.rides:
+                return items        # the step rode in the chunk's program
         if self.engine.active.any():
             items.append(self._dispatch_step())
         return items
@@ -1400,6 +1511,7 @@ class DecodeScheduler:
             self.tracer.record(
                 gen.rid, trace_lib.SPAN_DECODE_PREFILL_CHUNK, t0, now - t0,
                 parent_id=gen.prefill_span, start=chunk.start, rows=chunk.rows,
+                riders=len(chunk.riders or ()),
             )
             if chunk.last:
                 self.tracer.record(
@@ -1426,13 +1538,17 @@ class DecodeScheduler:
         if gen.dispatched >= gen.max_new_tokens:
             self.engine.active[gen.slot] = False
 
-    def _dispatch_step(self):
-        """((the rows the step decodes, the positions it reads), dispatch
-        time, its unmaterialized output): every live slot's context, the
-        consumed token included."""
+    def _step_rows(self) -> tuple[list, int]:
+        """(the rows the next step decodes, the positions it reads): every
+        live slot's context, the consumed token included."""
         rows = [(slot, gen) for slot, gen in self._live.items()
                 if self.engine.active[slot]]
-        context = int(self.engine.lengths[self.engine.active].sum()) + len(rows)
+        return rows, int(self.engine.lengths[self.engine.active].sum()) + len(rows)
+
+    def _dispatch_step(self):
+        """((the rows the step decodes, the positions it reads), dispatch
+        time, its unmaterialized output)."""
+        rows, context = self._step_rows()
         item = (rows, context), time.perf_counter(), self.engine.step_async()
         for _, gen in rows:
             self._dispatched(gen)

@@ -1072,16 +1072,24 @@ def decode_metrics(registry: "Registry", model: str) -> dict:
                 "batched decode steps executed (each advances every "
                 "active slot by one token)",
             ),
+            "riding_steps": c.counter(
+                "kdlt_decode_riding_steps_total",
+                "decode steps whose rows ran inside a prefill chunk's "
+                "program (counted in kdlt_decode_steps_total too; timed "
+                "with the chunk, in kdlt_decode_prefill_seconds)",
+            ),
             "step_seconds": c.histogram(
                 "kdlt_decode_step_seconds",
-                "wall time of one batched decode step (dispatch + "
-                "materialize)",
+                "wall time of one batched decode step run as a program of "
+                "its own, read to read (a step that rode in a chunk's "
+                "program is timed with the chunk)",
                 buckets=PIPELINE_STAGE_BUCKETS,
             ),
             "prefill_seconds": c.histogram(
                 "kdlt_decode_prefill_seconds",
                 "wall time of one prefill program: one chunk of a prompt "
-                "(a prompt that fits one program is one chunk)",
+                "(a prompt that fits one program is one chunk), with the "
+                "decode step that rode in it where one did",
                 buckets=PIPELINE_STAGE_BUCKETS,
             ),
             "active_slots": c.gauge(

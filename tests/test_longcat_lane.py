@@ -499,3 +499,138 @@ def test_the_served_logits_are_bit_for_bit_what_they_were(tmp_path, compute_dtyp
     assert np.asarray(ids)[:, :8].tolist() == golden["top_ids"]
     bits = np.asarray(logits, np.float32)[:, :8].view(np.uint32)
     assert bits.tolist() == golden["top_logit_bits"]
+
+
+# --- a chunk and a step as one program, against the two programs ------------------------
+
+
+# (prompt tokens, the start of the chunk under test) at chunks of 16 rows
+ROUND_CASES = [(10, 0), (40, 0), (40, 16), (40, 32)]
+ROUND_IDS = ["first-and-last", "first-of-three", "later", "later-and-last"]
+# |one program - two| over the largest |logit| of the two; over the largest
+# |value| a written cache row holds.  float32: rounding alone (the head's
+# product runs at 1 + S rows in place of 1; on the CPU the widest reading is
+# 4e-7); bfloat16: a value rounded to its 8 bits may land one step (2^-8)
+# the other way.
+ROUND_TOLERANCE = {"float32": 2e-6, "bfloat16": 2 ** -8}
+
+
+def round_against_chunk_then_step(engine, n, start, live):
+    """``live`` streams a few steps into their decode and a prompt of ``n``
+    tokens prefilled up to ``start``; from that state the chunk at ``start``
+    and the live slots' step run once as two programs (the chunk, then the
+    step without the prompt's slot) and once as one (``round_async``).
+    Returns each side's (chunk output, step output, cache, next tokens,
+    lengths, active), the prompt's slot and the (page, offset) rows the
+    programs wrote."""
+    rng = np.random.default_rng(n + 7 * start + 31 * live)
+    slots = []
+    for size in (11, 5)[:live]:
+        slot = engine.acquire_slot(size + 8)
+        engine.materialize(engine.prefill(slot, rng.integers(0, 64, size).tolist()))
+        slots.append(slot)
+    for _ in range(2):
+        engine.materialize(engine.step_async())
+    prompt = rng.integers(0, 64, n).tolist()
+    slot = engine.acquire_slot(n + 4)
+    at = 0
+    while at < start:
+        out, rows, _ = engine.prefill_chunk_async(slot, prompt, at)
+        engine.materialize(out)
+        at += rows
+    rows = engine.chunk_at(n, start)[0]
+    pages = engine.page_table
+    written = {(int(pages[slot, p // engine.page_size]), p % engine.page_size)
+               for p in range(start, start + rows)}
+    written |= {(int(pages[s, engine.lengths[s] // engine.page_size]),
+                 int(engine.lengths[s] % engine.page_size)) for s in slots}
+    state = (engine._cache, engine._next_tokens, engine.lengths.copy(), engine.active.copy())
+    sides = []
+    try:
+        # two programs: the chunk, then the step of the slots live before it
+        handle, _, _ = engine.prefill_chunk_async(slot, prompt, start)
+        chunk_out = engine.materialize(handle)
+        joined, engine.active[slot] = bool(engine.active[slot]), False
+        step_out = engine.materialize(engine.step_async())
+        engine.active[slot] = joined
+        sides.append((chunk_out, step_out, np.asarray(engine._cache),
+                      np.asarray(engine._next_tokens), engine.lengths.copy(),
+                      engine.active.copy()))
+        engine._cache, engine._next_tokens = state[:2]
+        engine.lengths, engine.active = state[2].copy(), state[3].copy()
+        # one program
+        handle, _, _ = engine.round_async(slot, prompt, start)
+        chunk_out, step_out = engine.materialize_round(handle)
+        sides.append((chunk_out, step_out, np.asarray(engine._cache),
+                      np.asarray(engine._next_tokens), engine.lengths.copy(),
+                      engine.active.copy()))
+    finally:
+        for s in (*slots, slot):
+            engine.release_slot(s)
+    return sides, slot, slots, written
+
+
+def assert_one_program_is_the_two(engine, dtype, n, start, live, step_form_differs=False):
+    """``step_form_differs``: the step alone computes its experts' products
+    in another form than the round does, so that the one count that says
+    what the form computed (the fifth) is left to the caller."""
+    (two, one), slot, slots, written = round_against_chunk_then_step(engine, n, start, live)
+    tol = ROUND_TOLERANCE[dtype]
+    (chunk_2, step_2, cache_2, next_2, lengths_2, active_2) = two
+    (chunk_1, step_1, cache_1, next_1, lengths_1, active_1) = one
+    # the chunk's logits and the live slots' (its greedy token, then its top logits)
+    scale = float(np.abs(chunk_2.top_logits[0]).max())
+    assert chunk_1.top_ids[0, 0] == chunk_2.top_ids[0, 0]
+    assert float(np.abs(chunk_1.top_logits[0] - chunk_2.top_logits[0]).max()) <= tol * scale
+    for s in slots:
+        scale = float(np.abs(step_2.top_logits[s]).max())
+        assert step_1.top_ids[s, 0] == step_2.top_ids[s, 0]
+        assert float(np.abs(step_1.top_logits[s] - step_2.top_logits[s]).max()) <= tol * scale
+    # every part's counts are the counts of its own program
+    assert chunk_1.counts.tolist() == chunk_2.counts.tolist()
+    same = [i for i in range(decode_lib.N_COUNTS) if not (step_form_differs and i == 4)]
+    assert step_1.counts[same].tolist() == step_2.counts[same].tolist()
+    # the token each slot consumes next, and the host's tables
+    assert next_1.tolist() == next_2.tolist()
+    assert lengths_1.tolist() == lengths_2.tolist() and active_1.tolist() == active_2.tolist()
+    # the cache: the rows the programs wrote within the tolerance; every other
+    # row of every page but the trash page (padding and idle slots) bit for bit
+    mask = np.zeros(cache_1.shape[1:3], bool)
+    for page, offset in written:
+        mask[page, offset] = True
+    f1, f2 = cache_1.astype(np.float32), cache_2.astype(np.float32)
+    rows_1, rows_2 = f1[:, mask], f2[:, mask]
+    assert float(np.abs(rows_1 - rows_2).max()) <= tol * float(np.abs(rows_2).max())
+    mask[0] = True
+    assert np.array_equal(cache_1[:, ~mask], cache_2[:, ~mask])
+    return two, one
+
+
+@pytest.fixture(scope="module")
+def round_engines(tmp_path_factory):
+    """The decoder at both precisions at chunks of 16 rows, buffers not
+    donated (each test runs two sides from one state)."""
+    saved, decode_lib.PREFILL_CHUNK = decode_lib.PREFILL_CHUNK, 16
+    try:
+        engines = {}
+        for dtype in ROUND_TOLERANCE:
+            root = str(tmp_path_factory.mktemp("lc-" + dtype))
+            write_artifact(root, compute_dtype=dtype)
+            engines[dtype] = make_engine(root, "gather", max_pages_per_seq=10,
+                                         prompt_buckets=(8, 16, 64), donate=False)
+        return engines
+    finally:
+        decode_lib.PREFILL_CHUNK = saved
+
+
+@pytest.mark.parametrize("live", [0, 1, 2], ids=lambda k: f"{k}-live")
+@pytest.mark.parametrize("n, start", ROUND_CASES, ids=ROUND_IDS)
+@pytest.mark.parametrize("dtype", list(ROUND_TOLERANCE))
+def test_a_chunk_and_a_step_as_one_program_are_the_two_programs(round_engines, dtype, n,
+                                                                start, live):
+    """First and later chunks, a prompt's last and not, beside no, one and
+    two live slots: the round's chunk logits, step logits, next tokens,
+    counts and cache are those of the chunk's program and then the step's."""
+    engine = round_engines[dtype]
+    assert engine.rides
+    assert_one_program_is_the_two(engine, dtype, n, start, live)

@@ -45,6 +45,8 @@ HOT_PATH_ROOTS = (
     # async -- materialization happens once per iteration in the scheduler
     # loop (emit_tokens), never inside the step dispatch itself.
     (f"{PACKAGE}/runtime/decode.py", "DecodeEngine", "step_async"),
+    # ... and the round that carries a step inside a prefill chunk's program.
+    (f"{PACKAGE}/runtime/decode.py", "DecodeEngine", "round_async"),
     # Raw-bytes ingest (GUIDE 10q): the model tier's decode-stage entry
     # and the engine's fused-ingest dispatch surface.  decode_batch runs
     # pre-dispatch by design -- its intentional host materializations
